@@ -96,7 +96,7 @@ def _routes_bit_identical(tn, steps, segments, block_tokens) -> bool:
 
 def _bench_one(phase: str, tokens: int, name: str, tn) -> dict:
     steps = tuple(tuple(s) for s in find_topk_paths(tn, k=4)[0].steps)
-    bt = ops.clamp_block(256, tokens)
+    bt = ops.token_block(256, tokens)
     segs = fusion.segment_path(tn, steps, block_tokens=bt,
                                budget_bytes=VMEM_BUDGET)
     per_step_s = measure_per_step(tn, steps, interpret=True)

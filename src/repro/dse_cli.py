@@ -703,9 +703,9 @@ def _apply_fused_cost(tables, named, layer_paths, hw_cfg, tokens, tuner):
     """
     from repro.core import fusion
     from repro.core.cost_table import fused_cost_tables
-    from repro.plan.compiler import _pow2_le, _streaming_budget
+    from repro.plan.compiler import _streaming_budget, default_block_tokens
 
-    block_tokens = max(8, _pow2_le(min(256, tokens)))
+    block_tokens = default_block_tokens(tokens)
     budget_bytes = _streaming_budget(None)
     base_seconds = dict(tables.seconds)
     t0 = time.perf_counter()

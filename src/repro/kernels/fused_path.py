@@ -12,20 +12,27 @@ intermediate lives in an fp32 VMEM scratch buffer — zero HBM bytes, one
 kernel-launch overhead for the whole run.
 
 Dataflow note: inside a segment every step is lowered as an OS-style
-fp32 contraction with the *same* sequential k-block accumulation order
-as the per-step kernels — the per-step WS/IS grid orders cannot be
-preserved across a shared 1-d token grid, which is the "falling back to
-OS inside a segment" rule the plan compiler and cost model assume.
-Each chained step replays the per-step kernel's *exact* blocked GEMM:
-the same clamped ``(block_m, block_k, block_n)`` tiles (clamped against
-the full step dims, not the token-blocked kernel-local dims), the same
-sequential k-block partial-sum grouping, and the dot operands are
-materialized behind ``optimization_barrier`` so XLA cannot refuse the
-per-step lowering by folding the surrounding transposes into the dot
-(see ``_chain_step``).  fp32 fused execution is therefore bit-identical
-to the per-step ``tt_gemm`` route (property-tested); with bf16 operands
-it is *more* precise, because interior intermediates skip the per-step
-cast back to bf16.
+fp32 contraction — the per-step WS/IS grid orders cannot be preserved
+across a shared 1-d token grid, which is the "falling back to OS inside
+a segment" rule the plan compiler and cost model assume.
+
+Two lowerings of the same segment:
+
+* on the TPU (``interpret=False``), :func:`token_minor_segment` runs the
+  chain through ``streaming_tt.stream_chain`` — tokens on the lane axis,
+  one 2-d dot per step, the only form of these contractions Mosaic
+  lowers.  It agrees with the per-step route to fp32 rounding.
+* in interpret mode, each chained step replays the per-step kernel's
+  *exact* blocked GEMM: the same ``gemm_blocks`` tiles (resolved against
+  the full step dims, not the token-blocked kernel-local dims), the same
+  sequential k-block partial-sum grouping, and the dot operands are
+  materialized behind ``optimization_barrier`` (which Mosaic does not
+  lower) so XLA cannot refuse the per-step lowering by folding the
+  surrounding transposes into the dot (see ``_chain_step``).  fp32 fused
+  execution on the CPU is therefore bit-identical to the per-step
+  ``tt_gemm`` route (property-tested); with bf16 operands it is *more*
+  precise, because interior intermediates skip the per-step cast back
+  to bf16.
 """
 
 from __future__ import annotations
@@ -38,7 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tt_gemm import _pad_to_block, pltpu_accumulator
+from .streaming_tt import stream_chain, token_block
+from .tt_gemm import _pad_to_block, gemm_blocks, pltpu_accumulator
 
 #: default batch (streamed-token) edge label
 BATCH_EDGE = "b"
@@ -116,14 +124,6 @@ def _compile_segment(entries, steps, batch_edge):
     return input_positions, ops, mids
 
 
-def _clamp_block(block: int, dim: int) -> int:
-    # local copy of ops.clamp_block (ops imports this module)
-    p = 1
-    while p < dim:
-        p *= 2
-    return max(8, min(block, p))
-
-
 def _chain_step(a, b, ax_a, ax_b, full_dims, block_m, block_k, block_n):
     """One pairwise contraction, mirroring the per-step GEMM route exactly.
 
@@ -135,9 +135,9 @@ def _chain_step(a, b, ax_a, ax_b, full_dims, block_m, block_k, block_n):
     its k-blocks *sequentially* from a zero fp32 accumulator (the
     per-step OS grouping; WS/IS agree after the fp32 output fix), and
     every dot sees an ``optimization_barrier``-materialized block of
-    exactly the per-step kernel's shape, so XLA lowers the same GEMM in
-    both routes and the fused result is bit-identical to the
-    spill-per-step route.
+    exactly the per-step kernel's shape (and the result is materialized
+    the same way), so XLA lowers the same GEMM in both routes and the
+    fused result is bit-identical to the spill-per-step route.
     """
     m_full, k_full, n_full = full_dims
     a_free = [i for i in range(a.ndim) if i not in ax_a]
@@ -149,9 +149,8 @@ def _chain_step(a, b, ax_a, ax_b, full_dims, block_m, block_k, block_n):
     k = math.prod(a.shape[i] for i in ax_a)
     a2 = jnp.transpose(a, a_free + list(ax_a)).reshape(m, k)
     b2 = jnp.transpose(b, list(ax_b) + b_free).reshape(k, n)
-    bm = _clamp_block(block_m, m_full)
-    bk = _clamp_block(block_k, k_full)
-    bn = _clamp_block(block_n, n_full)
+    bm, bk, bn = gemm_blocks(m_full, k_full, n_full, block_m, block_k,
+                             block_n)
     a2 = _pad_to_block(_pad_to_block(a2, 0, bm), 1, bk)
     b2 = _pad_to_block(_pad_to_block(b2, 0, bk), 1, bn)
     n_m, n_k, n_n = a2.shape[0] // bm, a2.shape[1] // bk, b2.shape[1] // bn
@@ -171,7 +170,10 @@ def _chain_step(a, b, ax_a, ax_b, full_dims, block_m, block_k, block_n):
         rows.append(jnp.concatenate(cols, axis=1) if len(cols) > 1
                     else cols[0])
     c = jnp.concatenate(rows, axis=0) if len(rows) > 1 else rows[0]
-    return c[:m, :n].reshape(tuple(a_dims) + tuple(b_dims))
+    # materialized too: else XLA folds the next step's transpose of this
+    # result into these dots (transpose(A @ B) -> B^T @ A^T)
+    return jax.lax.optimization_barrier(
+        c[:m, :n].reshape(tuple(a_dims) + tuple(b_dims)))
 
 
 def _kernel(*refs, ops, n_in, block_m, block_k, block_n):
@@ -212,22 +214,16 @@ def fused_segment_contract(
     route would append, so the caller's bookkeeping is unchanged.  The
     token dim is padded to the block multiple and sliced back (padding
     rows are zeros and the batch edge is never contracted inside a
-    segment, so kept rows are exact).
+    segment, so kept rows are exact).  On the TPU the segment runs as
+    :func:`token_minor_segment`.
     """
-    if len(steps) < 2:
-        raise ValueError("fused segments need at least two steps")
-    entries = [(edges, tuple(t.shape)) for edges, t in work]
-    input_positions, ops, mids = _compile_segment(entries, steps, batch_edge)
+    if not interpret:
+        return token_minor_segment(work, steps, block_tokens=block_tokens,
+                                   batch_edge=batch_edge,
+                                   out_dtype=out_dtype)
+    input_positions, ops, mids, stream_slot = _segment_inputs(
+        work, steps, batch_edge)
     arrays = [work[p][1] for p in input_positions]
-
-    stream_slot = None
-    for slot, p in enumerate(input_positions):
-        if batch_edge in work[p][0]:
-            if stream_slot is not None:
-                raise ValueError("multiple batch-carrying segment inputs")
-            stream_slot = slot
-    if stream_slot is None:
-        raise ValueError("segment has no batch-carrying input")
     stream_edges = work[input_positions[stream_slot]][0]
     bpos = stream_edges.index(batch_edge)
     tokens = arrays[stream_slot].shape[bpos]
@@ -276,13 +272,6 @@ def fused_segment_contract(
     scratch_shapes = [
         pltpu_accumulator(block_dims(ec, dc)) for ec, dc in mids[:-1]
     ]
-    kwargs = {}
-    if not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        )
     y = pl.pallas_call(
         functools.partial(_kernel, ops=ops, n_in=len(arrays),
                           block_m=block_m, block_k=block_k,
@@ -292,12 +281,66 @@ def fused_segment_contract(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_padded, out_dtype),
         scratch_shapes=scratch_shapes,
-        interpret=interpret,
-        **kwargs,
+        interpret=True,
     )(*arrays)
     if pt != tokens:
         y = jax.lax.slice_in_dim(y, 0, tokens, axis=opos)
     return out_edges, y
+
+
+def _segment_inputs(work, steps, batch_edge):
+    """``_compile_segment`` over ``work`` plus the streamed input's slot."""
+    if len(steps) < 2:
+        raise ValueError("fused segments need at least two steps")
+    entries = [(edges, tuple(t.shape)) for edges, t in work]
+    input_positions, ops, mids = _compile_segment(entries, steps, batch_edge)
+    slots = [slot for slot, p in enumerate(input_positions)
+             if batch_edge in work[p][0]]
+    if len(slots) != 1:
+        raise ValueError(
+            f"segment needs one batch-carrying input, found {len(slots)}")
+    return input_positions, ops, mids, slots[0]
+
+
+def token_minor_segment(
+    work: Sequence[tuple[tuple[str, ...], jax.Array]],
+    steps: Sequence[tuple[int, int]],
+    *,
+    block_tokens: int = 256,
+    batch_edge: str = BATCH_EDGE,
+    out_dtype=None,
+    interpret: bool = False,
+) -> tuple[tuple[str, ...], jax.Array]:
+    """The segment as a token-minor chain (``streaming_tt.stream_chain``).
+
+    The form Mosaic lowers: every step contracts the streamed value with
+    one pinned operand as a single 2-d dot, tokens on the lane axis.  Its
+    blocking differs from the per-step GEMMs, so results agree with the
+    per-step route to fp32 rounding, not bit for bit.  Same arguments
+    and result as :func:`fused_segment_contract`.
+    """
+    input_positions, ops, mids, stream_slot = _segment_inputs(
+        work, steps, batch_edge)
+    stream_edges, x = work[input_positions[stream_slot]]
+    bpos = stream_edges.index(batch_edge)
+    tokens = x.shape[bpos]
+    x_edges = tuple(e for e in stream_edges if e != batch_edge)
+    x_dims = tuple(d for e, d in zip(stream_edges, x.shape)
+                   if e != batch_edge)
+    bt = token_block(block_tokens, tokens)
+    x2 = _pad_to_block(jnp.moveaxis(x, bpos, 0).reshape(tokens, -1), 0, bt)
+    pinned = []
+    for t, (a_src, b_src, *_rest) in enumerate(ops):
+        src = next(s for s in (a_src, b_src)
+                   if s[0] == "in" and (t > 0 or s[1] != stream_slot))
+        pinned.append(work[input_positions[src[1]]])
+    out_edges, out_dims = mids[-1]
+    out_nb = tuple(e for e in out_edges if e != batch_edge)
+    y = stream_chain(x2, x_edges, x_dims, pinned, out_nb, block_tokens=bt,
+                     out_dtype=out_dtype or x.dtype, interpret=interpret)
+    y = y[:tokens].reshape((tokens,) + tuple(
+        d for e, d in zip(out_edges, out_dims) if e != batch_edge))
+    return out_edges, jnp.moveaxis(y, 0, out_edges.index(batch_edge))
 
 
 def segment_vmem_bytes(

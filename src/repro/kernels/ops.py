@@ -48,7 +48,9 @@ def gemm(
 ) -> jax.Array:
     """Dataflow-configurable GEMM for arbitrary (non-block-multiple) dims.
 
-    Padding to block multiples (and slicing back) happens inside
+    The blocks are caps, resolved against ``a @ b``'s dims under
+    Mosaic's (8, 128) rule (:func:`tt_gemm.gemm_blocks`).  Padding to
+    block multiples (and slicing back) happens inside
     :func:`tt_gemm.tt_gemm` itself — zero rows/columns contribute
     nothing to a matmul.  ``differentiable=True`` routes through
     :func:`tt_gemm.tt_gemm_vjp` (custom-VJP kernel whose backward GEMMs
@@ -57,6 +59,8 @@ def gemm(
     """
     interpret = _default_interpret() if interpret is None else interpret
     kernel = _tt_gemm.tt_gemm_vjp if differentiable else _tt_gemm.tt_gemm
+    block_m, block_k, block_n = _tt_gemm.gemm_blocks(
+        a.shape[0], a.shape[1], b.shape[1], block_m, block_k, block_n)
     return kernel(
         a, b,
         dataflow=dataflow,  # type: ignore[arg-type]
@@ -125,21 +129,19 @@ def fused_segment(
         out_dtype=out_dtype, interpret=interpret)
 
 
-def _next_pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
-
-
-def clamp_block(block: int, dim: int) -> int:
-    """Shrink a compile-time block to the runtime dim (power of two, >= 8).
+def clamp_block(block: int, dim: int, align: int = _tt_gemm.SUBLANE) -> int:
+    """Shrink a compile-time block to the runtime dim, legal for Mosaic.
 
     The DSE tiles for its search-time shapes; a runtime call may carry
     fewer tokens (decode) or contract a smaller intermediate, and padding
-    up to the full plan block would compute mostly zeros.
+    up to the full plan block would compute mostly zeros.  See
+    :func:`tt_gemm.legal_block` for the (8, 128) rule.
     """
-    return max(8, min(block, _next_pow2(dim)))
+    return _tt_gemm.legal_block(block, dim, align)
+
+
+#: legal token (lane) block of the streaming and fused kernels
+token_block = _streaming.token_block
 
 
 def gemm_contract(
@@ -157,7 +159,8 @@ def gemm_contract(
     Operands are transposed to (free..., shared...) / (shared..., free...)
     and flattened to (M, K) @ (K, N); the result keeps tensordot's axis
     order (A's free axes then B's), so all edge bookkeeping stays in the
-    path executor.  Blocks are clamped to the runtime dims.
+    path executor.  :func:`gemm` resolves the blocks against each step's
+    dims.
     """
 
     def contract(ta: jax.Array, tb: jax.Array, axes) -> jax.Array:
@@ -171,11 +174,8 @@ def gemm_contract(
         k = math.prod(ta.shape[i] for i in ax_a)
         a2 = jnp.transpose(ta, a_free + list(ax_a)).reshape(m, k)
         b2 = jnp.transpose(tb, list(ax_b) + b_free).reshape(k, n)
-        c2 = gemm(a2, b2, dataflow=dataflow,
-                  block_m=clamp_block(block_m, m),
-                  block_k=clamp_block(block_k, k),
-                  block_n=clamp_block(block_n, n),
-                  interpret=interpret,
+        c2 = gemm(a2, b2, dataflow=dataflow, block_m=block_m,
+                  block_k=block_k, block_n=block_n, interpret=interpret,
                   differentiable=differentiable)
         return c2.reshape(tuple(a_dims) + tuple(b_dims))
 

@@ -3,14 +3,20 @@
 TT cores are tiny (KBs) — they are pinned whole in VMEM for the entire
 kernel (BlockSpec index_map constant in the grid), while activations
 stream through in token blocks.  Each grid step contracts one token block
-against the full core chain along a DSE-searched path, entirely in VMEM:
+against the pinned operands along a DSE-searched path, entirely in VMEM:
 one HBM read of X, one HBM write of Y, zero intermediate spills.  This is
 the streaming data-reuse property of the paper's FPGA kernel, re-expressed
 as a Pallas pipeline.
 
-The contraction path is a *static* argument: the searched pairwise order
-is unrolled at trace time inside the kernel body (the same executor as the
-pure-jnp reference, applied to VMEM block values).
+The contraction path is a *static* argument.  Steps that join two cores
+do not depend on the tokens, so they run once outside the kernel and
+their results are pinned like cores; the steps that touch the streamed
+value are unrolled at trace time inside the kernel body.  Inside, the
+token dim is the minor (lane) axis of every value, so each step is one
+2-d ``jnp.dot`` of a pinned ``(free, shared)`` matrix with the streamed
+value viewed as ``(shared, free * tokens)`` — the only reshapes and
+transposes are of leading axes, which Mosaic lowers when the token block
+is a multiple of 128 (:func:`token_block`).
 
 The streamed operand is whichever node has ``kind == "input"`` — the
 forward activations ``X``, or the output gradient ``dY`` of a
@@ -35,6 +41,8 @@ from repro.core.contraction import execute_path
 from repro.core.paths import CandidatePath, find_topk_paths
 from repro.core.tensor_network import Node, TensorNetwork, tt_linear_network
 
+from .tt_gemm import LANE
+
 
 def _stream_node(tn: TensorNetwork) -> Node:
     """The single streamed (kind == "input") node of the network."""
@@ -47,7 +55,7 @@ def _stream_node(tn: TensorNetwork) -> Node:
 
 
 def _stream_layout(tn: TensorNetwork):
-    """(stream_name, batch_edge, in_modes, out_edges, out_dim).
+    """(stream_name, batch_edge, in_modes, out_edges).
 
     The streamed node must carry exactly one free (batch) edge, leading;
     its remaining (shared) edges are the flattened inner dim of the 2-d
@@ -64,37 +72,135 @@ def _stream_layout(tn: TensorNetwork):
             f"got edges {x.edges} (free: {batch_edges})")
     batch = batch_edges[0]
     in_modes = tuple(d for e, d in zip(x.edges, x.dims) if e != batch)
-    out = [
-        (e, d)
-        for n in tn.nodes if n.kind != "input"
-        for e, d in zip(n.edges, n.dims) if e in free
-    ]
-    out_edges = (batch,) + tuple(e for e, _ in out)
-    out_dim = math.prod((d for _, d in out), start=1)
-    return x.name, batch, in_modes, out_edges, out_dim
+    out_edges = (batch,) + tuple(
+        e for n in tn.nodes if n.kind != "input" for e in n.edges if e in free)
+    return x.name, batch, in_modes, out_edges
 
 
-def _kernel(
-    *refs,
-    tn: TensorNetwork,
-    path: CandidatePath,
-    stream_name: str,
-    in_modes: tuple[int, ...],
-    out_edges: tuple[str, ...],
-    out_dim: int,
+def token_block(block_tokens: int, tokens: int) -> int:
+    """The token block the kernel runs: a multiple of ``LANE``.
+
+    Inside the kernel the token dim is the minor (lane) axis of every
+    intermediate, and Mosaic only reshapes and transposes such values
+    when that axis is a whole number of 128-lane vregs.  The block is the
+    plan's ``block_tokens`` rounded up to that, but never more than the
+    lane-rounded token count (a decode step of 4 tokens runs one
+    128-token block).
+    """
+    def up(n: int) -> int:
+        return -(-max(1, n) // LANE) * LANE
+
+    return min(up(block_tokens), up(tokens))
+
+
+def _hoisted_contract(ea, ta, eb, tb):
+    """A batch-free (core x core) step, evaluated once outside the kernel."""
+    shared = [e for e in ea if e in eb]
+    t = jnp.tensordot(ta, tb, axes=([ea.index(e) for e in shared],
+                                    [eb.index(e) for e in shared]),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    edges = tuple(e for e in ea if e not in shared) + tuple(
+        e for e in eb if e not in shared)
+    return edges, t
+
+
+def _chain_layout(t_edges, t_dims, pinned):
+    """Static program of a token-minor chain: one entry per pinned operand.
+
+    The streamed value has axes ``(*t_edges, tokens)``.  Contracting it
+    with pinned ``(edges, array)`` over their shared edges ``S`` is one
+    2-d matmul ``P (F_P, S) @ T (S, F_T * tokens)``: the streamed value
+    is permuted to ``(S, F_T, tokens)`` — leading axes only, the lane
+    axis stays put — and the result has axes ``(*F_P, *F_T, tokens)``.
+    Returns ``(program, mats, edges, dims)``: per step
+    ``(perm, n_s, out_dims)``, the pinned operands as ``(F_P, S)``
+    matrices, and the final axes.
+    """
+    program, mats = [], []
+    edges, dims = tuple(t_edges), tuple(t_dims)
+    for p_edges, p in pinned:
+        s = [e for e in edges if e in p_edges]
+        f_t = [e for e in edges if e not in s]
+        f_p = [e for e in p_edges if e not in s]
+        perm = tuple(edges.index(e) for e in s + f_t)
+        p_perm = [p_edges.index(e) for e in f_p + s]
+        n_s = math.prod(dims[edges.index(e)] for e in s)
+        mats.append(jnp.transpose(p, p_perm).reshape(-1, n_s))
+        size = dict(zip(edges, dims)) | dict(zip(p_edges, p.shape))
+        edges = tuple(f_p + f_t)
+        dims = tuple(size[e] for e in edges)
+        program.append((perm, n_s, dims))
+    return tuple(program), mats, edges, dims
+
+
+def _chain_kernel(x_ref, *refs, in_dims, program, out_perm, n_out, bt):
+    """Token-minor chain body: every contraction is a 2-d ``jnp.dot``."""
+    p_refs, o_ref = refs[:-1], refs[-1]
+    t = x_ref[...].T.reshape(in_dims + (bt,))
+    for p_ref, (perm, n_s, out_dims) in zip(p_refs, program):
+        if perm != tuple(range(len(perm))):
+            t = jnp.transpose(t, perm + (len(perm),))
+        p = p_ref[...]
+        if p.dtype != t.dtype:
+            p, t = p.astype(jnp.float32), t.astype(jnp.float32)
+        y = jnp.dot(p, t.reshape(n_s, -1), preferred_element_type=jnp.float32)
+        t = y.reshape(out_dims + (bt,))
+    if out_perm != tuple(range(len(out_perm))):
+        t = jnp.transpose(t, out_perm + (len(out_perm),))
+    o_ref[...] = t.reshape(n_out, bt).T.astype(o_ref.dtype)
+
+
+def stream_chain(
+    x: jax.Array,
+    x_edges: Sequence[str],
+    x_dims: Sequence[int],
+    pinned: Sequence[tuple[tuple[str, ...], jax.Array]],
+    out_edges: Sequence[str],
+    *,
     block_tokens: int,
-):
-    x_ref = refs[0]
-    core_refs = refs[1:-1]
-    o_ref = refs[-1]
-    x = x_ref[...].reshape((block_tokens,) + in_modes)
-    tensors = {stream_name: x}
-    core_names = [n.name for n in tn.nodes if n.kind != "input"]
-    for name, ref in zip(core_names, core_refs):
-        tensors[name] = ref[...]
-    y = execute_path(tn, path, tensors, out_edges=out_edges,
-                     preferred_dtype=jnp.float32)
-    o_ref[...] = y.reshape(block_tokens, out_dim).astype(o_ref.dtype)
+    out_dtype,
+    interpret: bool,
+) -> jax.Array:
+    """Contract streamed ``x`` (tokens, prod(x_dims)) with ``pinned`` in order.
+
+    ``x``'s columns are the row-major flattening of axes ``x_edges``
+    (sizes ``x_dims``); each pinned ``(edges, array)`` is contracted with
+    the running value over all their shared edges.  Returns
+    ``(tokens, prod(out dims))`` with columns in ``out_edges`` order.
+    The pinned operands are whole-array VMEM blocks; ``x`` streams in
+    ``block_tokens`` rows (a ``LANE`` multiple dividing ``tokens``).
+    """
+    tokens = x.shape[0]
+    if tokens % block_tokens:
+        raise ValueError(f"tokens {tokens} not a multiple of {block_tokens}")
+    program, mats, edges, dims = _chain_layout(x_edges, x_dims, pinned)
+    if sorted(edges) != sorted(out_edges):
+        raise ValueError(f"chain leaves edges {edges}, not {tuple(out_edges)}")
+    out_perm = tuple(edges.index(e) for e in out_edges)
+    n_out = math.prod(dims)
+    kernel = functools.partial(
+        _chain_kernel, in_dims=tuple(x_dims), program=program,
+        out_perm=out_perm, n_out=n_out, bt=block_tokens)
+    x_spec = pl.BlockSpec((block_tokens, x.shape[1]), lambda i: (i, 0))
+    mat_specs = [pl.BlockSpec(m.shape, lambda i: (0, 0)) for m in mats]
+    o_spec = pl.BlockSpec((block_tokens, n_out), lambda i: (i, 0))
+    kwargs = {}
+    if not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        )
+    return pl.pallas_call(
+        kernel,
+        grid=(tokens // block_tokens,),
+        in_specs=[x_spec] + mat_specs,
+        out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((tokens, n_out), out_dtype),
+        interpret=interpret,
+        **kwargs,
+    )(x, *mats)
 
 
 def streaming_tt_linear(
@@ -109,52 +215,38 @@ def streaming_tt_linear(
 ) -> jax.Array:
     """Apply a streaming TT contraction to ``x`` (tokens, N_in).
 
-    ``tn``/``path`` must describe a batch equal to ``block_tokens``
-    (builders below handle this); ``x`` is the network's streamed node
-    flattened to 2-d.  tokens must divide by ``block_tokens``.
+    ``x`` is the network's streamed node flattened to 2-d; tokens must
+    divide by ``block_tokens``, a multiple of ``LANE`` on the TPU (see
+    :func:`token_block`).  Path steps that join two cores do not depend
+    on the tokens: they run once, outside the kernel, and their results
+    are pinned like cores.  Every step that touches the streamed value
+    runs inside the kernel in path order.
     """
-    tokens, n_in = x.shape
-    if tokens % block_tokens:
-        raise ValueError(f"tokens {tokens} not a multiple of {block_tokens}")
-    stream_name, _, in_modes, out_edges, out_dim = _stream_layout(tn)
-    if math.prod(in_modes) != n_in:
+    stream_name, batch, in_modes, out_edges = _stream_layout(tn)
+    if math.prod(in_modes) != x.shape[1]:
         raise ValueError("x inner dim does not match network input modes")
-    out_dtype = out_dtype or x.dtype
-    grid = (tokens // block_tokens,)
-
-    x_spec = pl.BlockSpec((block_tokens, n_in), lambda i: (i, 0))
-    core_specs = [
-        pl.BlockSpec(c.shape, functools.partial(lambda i, nd=c.ndim: (0,) * nd))
-        for c in cores
-    ]
-    o_spec = pl.BlockSpec((block_tokens, out_dim), lambda i: (i, 0))
-
-    kernel = functools.partial(
-        _kernel,
-        tn=tn,
-        path=path,
-        stream_name=stream_name,
-        in_modes=in_modes,
-        out_edges=out_edges,
-        out_dim=out_dim,
-        block_tokens=block_tokens,
-    )
-    kwargs = {}
-    if not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[x_spec] + core_specs,
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((tokens, out_dim), out_dtype),
-        interpret=interpret,
-        **kwargs,
-    )(x, *cores)
+    stream = object()  # placeholder for the streamed value in the work list
+    x_edges = tuple(e for e in _stream_node(tn).edges if e != batch)
+    it = iter(cores)
+    work = [(x_edges, stream) if n.name == stream_name else (n.edges, next(it))
+            for n in tn.nodes]
+    pinned = []
+    for (i, j) in path.steps:
+        (ea, ta), (eb, tb) = work[i], work[j]
+        if ta is stream or tb is stream:
+            other = (eb, tb) if ta is stream else (ea, ta)
+            s_edges = ea if ta is stream else eb
+            pinned.append(other)
+            merged = (tuple(e for e in other[0] if e not in s_edges)
+                      + tuple(e for e in s_edges if e not in other[0]), stream)
+        else:
+            merged = _hoisted_contract(ea, ta, eb, tb)
+        work = [w for k, w in enumerate(work) if k not in (i, j)]
+        work.append(merged)
+    return stream_chain(
+        x, x_edges, in_modes, pinned, out_edges[1:],
+        block_tokens=block_tokens, out_dtype=out_dtype or x.dtype,
+        interpret=interpret)
 
 
 def build_block_network(

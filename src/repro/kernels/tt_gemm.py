@@ -11,12 +11,21 @@ simulator models:
   OS  grid=(m, n, k), k innermost  -> C block resident (classic matmul);
                                       A, B stream; C written once.
   WS  grid=(k, n, m), m innermost  -> B (weight) block resident; A streams;
-                                      C partials spill/refill per k-fold.
+                                      one fp32 C partial per k-fold.
   IS  grid=(m, k, n), n innermost  -> A (input) block resident; B streams;
-                                      C partials spill/refill per k-fold.
+                                      one fp32 C partial per k-fold.
 
-Block shapes are the DSE's tiling decision <T_M, T_K, T_N>; MXU-aligned
-multiples of 128 (8 on the sublane dim) are preferred.
+WS and IS revisit an output block once per k-fold, non-consecutively.  A
+TPU Pallas pipeline writes an output block back to HBM when its index
+changes and never reads it back, so those kernels write each k-fold's
+partial to its own slice of a ``(n_k, M, N)`` fp32 buffer; the folds are
+summed afterwards in k order (the spill/refill traffic the simulator
+charges as ``C * (2*k_folds - 1)``).
+
+Block shapes are the DSE's tiling decision <T_M, T_K, T_N>.  Mosaic
+requires the last two dims of every block to be multiples of (8, 128) or
+to span the whole (padded) array dim; :func:`legal_block` resolves a
+plan block against one concrete dim under that rule.
 
 Grids must tile the operands exactly; dims that are not block multiples
 are zero-padded up and the result sliced back automatically (zero rows
@@ -34,6 +43,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DataflowName = Literal["IS", "OS", "WS"]
+
+#: Mosaic's vreg tiling of the last two dims of a block: (sublane, lane)
+SUBLANE = 8
+LANE = 128
+
+
+def _next_pow2(n: int) -> int:
+    p = 1
+    while p < n:
+        p *= 2
+    return p
+
+
+def legal_block(block: int, dim: int, align: int = SUBLANE) -> int:
+    """Resolve a plan block against one runtime dim (power of two, >= 8).
+
+    The result is either >= ``dim`` (one block spans the whole padded
+    dim) or a multiple of ``align`` (``SUBLANE`` for a second-minor block
+    dim, ``LANE`` for a minor one) — the two block shapes Mosaic lowers.
+    A block is never larger than the next power of two above ``dim``:
+    padding further would compute only zeros.
+    """
+    p = max(SUBLANE, _next_pow2(dim))
+    b = max(SUBLANE, min(block, p))
+    if b >= dim:
+        return b
+    return max(min(align, p), b - b % align)
+
+
+def gemm_blocks(m: int, k: int, n: int,
+                block_m: int, block_k: int, block_n: int
+                ) -> tuple[int, int, int]:
+    """Legal ``(block_m, block_k, block_n)`` for an ``(m, k) @ (k, n)`` GEMM.
+
+    ``block_k`` is the lane dim of A's block and ``block_n`` the lane dim
+    of B's and C's; ``block_m`` is only ever a sublane dim.
+    """
+    return (legal_block(block_m, m, SUBLANE), legal_block(block_k, k, LANE),
+            legal_block(block_n, n, LANE))
 
 
 def _os_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -53,43 +101,17 @@ def _os_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _ws_kernel(a_ref, b_ref, o_ref, *, n_k: int):
-    """Weight-stationary: grid (k, n, m), m innermost; B block pinned.
-
-    The output block is revisited once per k step (non-consecutive, so a
-    VMEM scratch accumulator cannot carry it); partial sums round-trip
-    through the fp32 output buffer — the WS traffic cost the simulator
-    charges as ``C * (2*k_folds - 1)``.  ``o_ref`` is always fp32
-    (``tt_gemm`` casts to the requested dtype after the call), so cross-k
-    accumulation never loses precision to a narrow output dtype.
-    """
-    k = pl.program_id(0)
-    part = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
-
-    @pl.when(k == 0)
-    def _first():
-        o_ref[...] = part
-
-    @pl.when(k > 0)
-    def _acc():
-        o_ref[...] = o_ref[...] + part
+def _partial_kernel(a_ref, b_ref, o_ref):
+    """WS / IS body: this k-fold's fp32 partial into its own output slice."""
+    o_ref[...] = jnp.dot(a_ref[...], b_ref[...],
+                         preferred_element_type=jnp.float32)
 
 
-def _is_kernel(a_ref, b_ref, o_ref, *, n_k: int):
-    """Input-stationary: grid (m, k, n), n innermost; A block pinned.
-
-    Same fp32 partial-sum contract as :func:`_ws_kernel`.
-    """
-    k = pl.program_id(1)
-    part = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
-
-    @pl.when(k == 0)
-    def _first():
-        o_ref[...] = part
-
-    @pl.when(k > 0)
-    def _acc():
-        o_ref[...] = o_ref[...] + part
+def _sum_folds(parts: jax.Array) -> jax.Array:
+    """Sum ``(n_k, M, N)`` partials in k order (the OS accumulation order)."""
+    n_k = parts.shape[0]
+    return jax.lax.fori_loop(1, n_k, lambda t, acc: acc + parts[t], parts[0],
+                             unroll=max(1, min(n_k - 1, 8)))
 
 
 def _pad_to_block(x: jax.Array, axis: int, block: int) -> jax.Array:
@@ -133,11 +155,6 @@ def tt_gemm(
         return out[:m, :n]
     out_dtype = out_dtype or a.dtype
     n_m, n_k, n_n = m // block_m, k // block_k, n // block_n
-    # WS/IS revisit output blocks non-consecutively per k-fold, so their
-    # cross-k partials accumulate in an fp32 output buffer (cast once
-    # below) — matching the OS kernel's fp32 scratch precision.
-    inner_dtype = out_dtype if dataflow == "OS" else jnp.float32
-    out_shape = jax.ShapeDtypeStruct((m, n), inner_dtype)
 
     if dataflow == "OS":
         grid = (n_m, n_n, n_k)
@@ -147,22 +164,24 @@ def tt_gemm(
         kernel = functools.partial(_os_kernel, n_k=n_k)
         scratch = [pltpu_accumulator((block_m, block_n))]
         dims = ("parallel", "parallel", "arbitrary")
-    elif dataflow == "WS":
-        grid = (n_k, n_n, n_m)
-        a_spec = pl.BlockSpec((block_m, block_k), lambda kk, j, i: (i, kk))
-        b_spec = pl.BlockSpec((block_k, block_n), lambda kk, j, i: (kk, j))
-        o_spec = pl.BlockSpec((block_m, block_n), lambda kk, j, i: (i, j))
-        kernel = functools.partial(_ws_kernel, n_k=n_k)
+        out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
+    elif dataflow in ("WS", "IS"):
+        if dataflow == "WS":
+            grid = (n_k, n_n, n_m)
+            a_spec = pl.BlockSpec((block_m, block_k), lambda kk, j, i: (i, kk))
+            b_spec = pl.BlockSpec((block_k, block_n), lambda kk, j, i: (kk, j))
+            o_spec = pl.BlockSpec((None, block_m, block_n),
+                                  lambda kk, j, i: (kk, i, j))
+        else:
+            grid = (n_m, n_k, n_n)
+            a_spec = pl.BlockSpec((block_m, block_k), lambda i, kk, j: (i, kk))
+            b_spec = pl.BlockSpec((block_k, block_n), lambda i, kk, j: (kk, j))
+            o_spec = pl.BlockSpec((None, block_m, block_n),
+                                  lambda i, kk, j: (kk, i, j))
+        kernel = _partial_kernel
         scratch = []
-        dims = ("arbitrary", "parallel", "parallel")
-    elif dataflow == "IS":
-        grid = (n_m, n_k, n_n)
-        a_spec = pl.BlockSpec((block_m, block_k), lambda i, kk, j: (i, kk))
-        b_spec = pl.BlockSpec((block_k, block_n), lambda i, kk, j: (kk, j))
-        o_spec = pl.BlockSpec((block_m, block_n), lambda i, kk, j: (i, j))
-        kernel = functools.partial(_is_kernel, n_k=n_k)
-        scratch = []
-        dims = ("parallel", "arbitrary", "parallel")
+        dims = ("parallel", "parallel", "parallel")
+        out_shape = jax.ShapeDtypeStruct((n_k, m, n), jnp.float32)
     else:
         raise ValueError(f"unknown dataflow {dataflow!r}")
 
@@ -185,6 +204,8 @@ def tt_gemm(
         interpret=interpret,
         **kwargs,
     )(a, b)
+    if dataflow != "OS":
+        out = _sum_folds(out)
     return out.astype(out_dtype)
 
 
@@ -216,9 +237,9 @@ def tt_gemm_vjp(
     cross :func:`tt_gemm`.  The VJP of ``C = A @ B`` is itself two GEMMs
     — ``dA = dC @ B^T`` and ``dB = A^T @ dC`` — and both are issued
     through the *same* dataflow-configurable Pallas kernel, with the
-    block shapes permuted to follow the transposed operands (so the
-    dimension/block divisibility contract of :func:`tt_gemm` carries
-    over to the backward shapes unchanged).
+    block shapes permuted to follow the transposed operands and resolved
+    by :func:`gemm_blocks` (a forward sublane block can become a backward
+    lane block).
     """
 
     @jax.custom_vjp
@@ -232,12 +253,15 @@ def tt_gemm_vjp(
 
     def bwd(res, g):
         a, b = res
+        (m, k), n = a.shape, b.shape[1]
         # dA (m, k) = g (m, n) @ B^T (n, k): K axis is n -> block_n
-        da = tt_gemm(g, b.T, dataflow=dataflow, block_m=block_m,
-                     block_k=block_n, block_n=block_k, interpret=interpret)
+        bm, bk, bn = gemm_blocks(m, n, k, block_m, block_n, block_k)
+        da = tt_gemm(g, b.T, dataflow=dataflow, block_m=bm, block_k=bk,
+                     block_n=bn, interpret=interpret)
         # dB (k, n) = A^T (k, m) @ g (m, n): M axis is k -> block_k
-        db = tt_gemm(a.T, g, dataflow=dataflow, block_m=block_k,
-                     block_k=block_m, block_n=block_n, interpret=interpret)
+        bm, bk, bn = gemm_blocks(k, m, n, block_k, block_m, block_n)
+        db = tt_gemm(a.T, g, dataflow=dataflow, block_m=bm, block_k=bk,
+                     block_n=bn, interpret=interpret)
         return da.astype(a.dtype), db.astype(b.dtype)
 
     f.defvjp(fwd, bwd)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + os.environ.get("REPRO_DRYRUN_DEVICES", "512")
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves the distribution config is coherent (sharding
@@ -29,6 +26,7 @@ Usage:
 import argparse
 import json
 import math
+import os
 import re
 import time
 import traceback
@@ -225,15 +223,7 @@ def extrapolate(cfg: ModelConfig, values: dict[str, float]) -> float:
 # ---------------------------------------------------------------------------
 
 def _cost_analysis(compiled) -> dict:
-    """Normalize Compiled.cost_analysis() across jax versions.
-
-    jax < 0.5 returns a list with one properties-dict per computation;
-    newer jax returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
+    return compiled.cost_analysis() or {}
 
 
 def run_cell(
@@ -334,6 +324,11 @@ def _write(path: str, result: dict) -> None:
 
 
 def main() -> None:
+    # a forced host-device count: set before the first device query,
+    # which initializes the CPU backend
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -31,12 +31,8 @@ from repro.sharding import ShardingRules
 
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    # older jax (< 0.5): meshes are Auto-mode only; no axis_types kwarg
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -21,12 +21,15 @@ under it) a non-zero exit.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
+from typing import Optional
 
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_rules, make_test_mesh
 from repro.models import api
 from repro.models.config import ShapeConfig
@@ -62,7 +65,28 @@ def _load_and_describe(path: str, label: str):
     return plan
 
 
+@dataclasses.dataclass
+class ServeRun:
+    """What one serving run did: exit code, engine, requests, results.
+
+    ``demoted`` holds the execution records of layers planned for a
+    Pallas backend that ran on the jnp executor instead.
+    """
+
+    code: int
+    engine: Optional[ServeEngine] = None
+    requests: tuple = ()
+    result: object = None
+    log: tuple = ()
+    demoted: tuple = ()
+
+
 def main(argv=None) -> int:
+    return run(argv).code
+
+
+def run(argv=None) -> ServeRun:
+    """The serving driver: parse ``argv``, serve, print a summary."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tt-lm-100m")
     ap.add_argument("--smoke", action="store_true")
@@ -115,7 +139,7 @@ def main(argv=None) -> int:
     if cfg.family == "encdec":
         print("error: the serve scheduler is causal-LM only; encdec runs "
               "its own scalar-position decoder", file=sys.stderr)
-        return 2
+        return ServeRun(2)
 
     any_plan = bool(args.plan or args.plan_prefill or args.plan_decode)
     if args.plan:
@@ -150,6 +174,7 @@ def main(argv=None) -> int:
         reset_execution_log,
     )
 
+    enable_compile_cache()
     reset_execution_log()
     t0 = time.perf_counter()
     with use_rules(rules):
@@ -165,7 +190,7 @@ def main(argv=None) -> int:
                 decode_plan=decode_plan, arch=args.arch)
         except ValueError as e:
             print(f"error: plan/model mismatch: {e}", file=sys.stderr)
-            return 2
+            return ServeRun(2)
         sched = Scheduler(
             engine,
             ServePolicy(schedule=args.schedule,
@@ -191,6 +216,8 @@ def main(argv=None) -> int:
         print(f"generated token ids (rid {c0.rid}): "
               f"{list(c0.tokens)[:16]}")
 
+    log, demoted = (), ()
+    code = 0
     if any_plan:
         log = execution_log()
         by_stream: dict[str, dict[str, int]] = {}
@@ -240,12 +267,12 @@ def main(argv=None) -> int:
                 if p is not None else {})
             for s, p in (("prefill", prefill_plan), ("decode", decode_plan))
         }
-        demoted = [
+        demoted = tuple(
             r for r in log
             if r["backend"] == "jnp"
             and backends_by_stream.get(
                 r["stream"], {}).get(r["name"], "jnp") != "jnp"
-        ]
+        )
         if demoted:
             names = sorted({r["name"] for r in demoted})
             print(f"WARNING: {len(demoted)} planned-Pallas executions were "
@@ -253,7 +280,7 @@ def main(argv=None) -> int:
                   f"{names[:4]}{'...' if len(names) > 4 else ''})",
                   file=sys.stderr)
             if args.strict_plan:
-                return EXIT_DEMOTED
+                code = EXIT_DEMOTED
         if not log:
             print(
                 "WARNING: a plan was given but the run executed no planned "
@@ -263,8 +290,8 @@ def main(argv=None) -> int:
                 file=sys.stderr,
             )
             if args.strict_plan:
-                return EXIT_UNPLANNED
-    return 0
+                code = EXIT_UNPLANNED
+    return ServeRun(code, engine, tuple(requests), result, log, demoted)
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ monitoring and preemption-safe shutdown.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -22,6 +23,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import make_pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_rules, make_test_mesh, param_shardings
 from repro.launch.steps import make_train_step
 from repro.models import api
@@ -31,7 +33,30 @@ from repro.runtime import FaultTolerantLoop, StragglerMonitor
 from repro.sharding import use_rules
 
 
-def main() -> None:
+@dataclasses.dataclass
+class TrainRun:
+    """What one training run did.
+
+    ``losses`` maps each logged step to its loss; ``recoveries`` counts
+    steps the fault-tolerant loop retried after an exception;
+    ``jit_step`` with ``example_args`` (abstract params, optimizer state
+    and batch) re-lowers the step program for inspection.
+    """
+
+    losses: dict
+    recoveries: int
+    jit_step: object
+    example_args: tuple
+    log: tuple = ()
+
+
+def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> TrainRun:
+    """The training driver: parse ``argv``, train, print progress."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tt-lm-100m")
     ap.add_argument("--smoke", action="store_true")
@@ -57,7 +82,7 @@ def main() -> None:
                     choices=("jnp", "tt_gemm", "streaming_tt"),
                     help="force one kernel backend for every plan layer "
                          "(jnp = the pre-v2 reference behaviour)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, tt=not args.dense, smoke=args.smoke)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
@@ -84,6 +109,8 @@ def main() -> None:
     lr = linear_warmup_cosine(args.lr, args.warmup, args.steps)
     step_fn = make_train_step(cfg, lr=lr, grad_compress=args.grad_compress)
 
+    enable_compile_cache()
+    losses: dict[int, float] = {}
     with use_rules(rules):
         p_shapes = jax.eval_shape(m.init_params, jax.random.PRNGKey(0))
         p_sh = param_shardings(p_shapes, mesh)
@@ -100,6 +127,11 @@ def main() -> None:
             start, state = mgr.restore(state)
             print(f"resumed from step {start}")
 
+        example_args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            (state["params"], state["opt"],
+             {k: jnp.asarray(v) for k, v in pipe.batch(start).items()}))
         monitor = StragglerMonitor()
         t_start = time.time()
 
@@ -108,7 +140,7 @@ def main() -> None:
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             params, opt, metrics = jit_step(state["params"], state["opt"], batch)
             if step % args.log_every == 0:
-                loss = float(metrics["loss"])
+                loss = losses[step] = float(metrics["loss"])
                 gn = float(metrics["grad_norm"])
                 dt = time.time() - t_start
                 print(f"step {step:5d}  loss {loss:.4f}  gnorm {gn:.3f}  "
@@ -119,6 +151,7 @@ def main() -> None:
                                  straggler=monitor)
         state, done = loop.run(state, start, args.steps - start)
         mgr.save(done, state)
+        log = ()
         if args.plan:
             from repro.plan import execution_log
 
@@ -139,7 +172,8 @@ def main() -> None:
                 print(f"sharded planned execution: mesh {' '.join(meshes)} "
                       f"(per-shard kernels via shard_map)")
         print(f"finished at step {done}; stragglers flagged: {monitor.flagged}")
+    return TrainRun(losses, loop.recoveries, jit_step, example_args, log)
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -43,6 +43,7 @@ from repro.core.dse import DSEResult, LayerChoice
 from repro.core.paths import CandidatePath
 from repro.core.simulator import HardwareConfig
 from repro.core.tensor_network import Node, TensorNetwork
+from repro.kernels.streaming_tt import token_block
 
 from .schema import (
     BACKENDS,
@@ -156,14 +157,24 @@ def default_blocks(
 ) -> tuple[int, int, int]:
     """The heuristic ``(block_m, block_k, block_n)`` for one GEMM shape.
 
+    These are caps (a co-searched architecture's array shape bounds
+    them): every kernel call resolves them against its own step's dims
+    under Mosaic's (8, 128) rule (``kernels.tt_gemm.gemm_blocks``).
     Shared with the autotuner (``repro.tune.heuristic_blocks``), which
-    measures its calibration at exactly this operating point — the
-    tiling the analytic argmin would deploy.
+    measures its calibration at this operating point, resolved the same
+    way.
     """
     cap_k = max(cap_m, cap_n) if cap_k is None else cap_k
     return (max(8, _pow2_le(min(cap_m, M))),
             max(8, _pow2_le(min(cap_k, K))),
             max(8, _pow2_le(min(cap_n, N))))
+
+
+def default_block_tokens(tokens: int) -> int:
+    """Token block of the streaming/fused kernels: 256, or 128 for short
+    calls (the kernels keep tokens on the 128-lane axis; see
+    ``kernels.streaming_tt.token_block``)."""
+    return token_block(256, tokens)
 
 
 def _tiling_for_path(
@@ -180,7 +191,7 @@ def _tiling_for_path(
         block_m=bm,
         block_k=bk,
         block_n=bn,
-        block_tokens=max(8, _pow2_le(min(256, tokens))),
+        block_tokens=default_block_tokens(tokens),
     )
 
 

@@ -309,12 +309,12 @@ def _execute_segmented(
     steps = tuple(tuple(s) for s in lp.path_steps)
     contract = _gemm_contract(lp, lp.tiling, interpret)
     work: list = [(n.edges, tensors[n.name]) for n in tn.nodes]
-    bt = ops.clamp_block(lp.tiling.block_tokens, tokens)
     for (s, e) in lp.segments:
         record_execution(lp, tokens, path_steps=steps[s:e], segment=(s, e))
         if e - s >= 2:
+            # each lowering resolves the plan's token block itself
             ec, val = ops.fused_segment(
-                work, steps[s:e], block_tokens=bt,
+                work, steps[s:e], block_tokens=lp.tiling.block_tokens,
                 block_m=lp.tiling.block_m, block_k=lp.tiling.block_k,
                 block_n=lp.tiling.block_n, interpret=interpret)
             # replay the per-step removals; interior placeholders are all
@@ -356,7 +356,7 @@ def _forward_planned(
     """The plan's forward contraction: ``(tokens, d_in) -> (tokens, d_out)``."""
     tokens = x2d.shape[0]
     if lp.backend == "streaming_tt":
-        bt = ops.clamp_block(lp.tiling.block_tokens, tokens)
+        bt = ops.token_block(lp.tiling.block_tokens, tokens)
         tn_block = tt_linear_network(bt, in_modes, out_modes, ranks)
         path = as_candidate_path(tn_block, lp.path_steps)
         return ops.tt_linear(x2d, list(cores), tn_block, path,
@@ -412,7 +412,7 @@ def _backward_planned(
                          wrt=wrt, path_steps=op.path_steps,
                          tiling=op.tiling)
         if wrt == "dx" and op.backend == "streaming_tt":
-            bt = ops.clamp_block(op.tiling.block_tokens, tokens)
+            bt = ops.token_block(op.tiling.block_tokens, tokens)
             net_block = grad_input_network(
                 tt_linear_network(bt, in_modes, out_modes, ranks))
             path = as_candidate_path(net_block, op.path_steps)

@@ -15,17 +15,18 @@ This module lifts that gate by making the sharding *explicit* instead:
   (``repro.dse --shards``);
 * TT cores are tiny by construction and replicate (``in_specs=P()``);
   their gradient cotangents are psummed across shards by the shard_map
-  transpose, so training stays correct (``check_rep=False`` because the
+  transpose, so training stays correct (``check_vma=False`` because the
   Pallas ``custom_vjp`` body defeats replication checking);
 * an optional model-axis output reduction
   (``ShardingRules.tt_model_reduce``) splits the leading input mode and
   its TT core over the model axis and reduces partial outputs with an
   explicit ``jax.lax.psum`` *inside* the body — classic row-parallel TP
-  with no forced relayout.  This changes float summation order, so
-  outputs are numerically equivalent (~1e-6 rtol for f32), not
-  bit-identical; pure token-DP sharding *is* bit-identical to the
-  single-device planned path because TT contractions are row-independent
-  and per-shard K-blocking is unchanged.
+  with no forced relayout.
+
+Either way the outputs agree with the single-device planned path to fp32
+rounding, not bit for bit: a per-shard call contracts fewer rows, and
+both XLA and Mosaic tile a matmul by its shape, so the summation order
+can differ (the model-axis psum re-associates the sum as well).
 """
 
 from __future__ import annotations
@@ -40,19 +41,9 @@ from jax.sharding import PartitionSpec as P
 from .executor import planned_tt_linear, shard_execution
 from .schema import LayerPlan
 
-try:  # jax >= 0.5 promotes shard_map to the top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def _smap(f, mesh, in_specs, out_specs):
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax renamed the replication-check knob
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -101,6 +101,7 @@ class FaultTolerantLoop:
                     retries += 1
                     self.recoveries += 1
                     if retries > self.max_retries:
+                        self.manager.wait()  # no save left writing
                         raise
                     latest = self.manager.latest_step()
                     if latest is not None:

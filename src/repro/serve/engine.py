@@ -196,3 +196,25 @@ class ServeEngine:
                     caches,
                     jnp.asarray(pos, jnp.int32))
         return np.asarray(logits), caches
+
+    def lowered_text(self, prompt_len: int) -> dict[str, str]:
+        """StableHLO of the prefill and decode programs, each traced under
+        its phase's plan — what a caller greps to see which kernels the
+        jitted steps call (e.g. ``tpu_custom_call`` for Mosaic kernels)."""
+        i32 = jnp.int32
+        with plan_context(self.prefill_plan,
+                          force_backend=self._plan_backend):
+            with execution_stream("prefill"):
+                pre = self._prefill_fn.lower(
+                    self.params,
+                    jax.ShapeDtypeStruct((1, self.padded_len(prompt_len)), i32),
+                    jax.ShapeDtypeStruct((), i32))
+        with plan_context(self.decode_plan,
+                          force_backend=self._plan_backend):
+            with execution_stream("decode"):
+                dec = self._decode_fn.lower(
+                    self.params,
+                    jax.ShapeDtypeStruct((self.n_slots, 1), i32),
+                    jax.eval_shape(self.fresh_caches),
+                    jax.ShapeDtypeStruct((self.n_slots,), i32))
+        return {"prefill": pre.as_text(), "decode": dec.as_text()}

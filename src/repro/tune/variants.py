@@ -6,13 +6,15 @@ dominant GEMM (``plan/compiler._tiling_for_path``); the autotuner instead
 space mirrors what the runtime can actually execute:
 
 - GEMM blocks come from the same power-of-two ladder ``ops.clamp_block``
-  resolves against at trace time, clamped per dimension — a variant
-  never exceeds the next power of two above the dimension (larger blocks
-  only pad with zeros, see ``kernels/tt_gemm``'s automatic padding);
-- streaming token blocks are power-of-two sweeps additionally filtered
-  by the VMEM feasibility predicate (``plan.compiler.streaming_fits``) —
-  a measured ``block_tokens`` never violates the budget the backend
-  choice assumed.
+  resolves against at trace time, clamped per dimension under Mosaic's
+  (8, 128) block rule — a variant never exceeds the next power of two
+  above the dimension (larger blocks only pad with zeros, see
+  ``kernels/tt_gemm``'s automatic padding);
+- streaming and fused token blocks are the lane-legal blocks the kernels
+  run (``ops.token_block``), additionally filtered by the VMEM
+  feasibility predicate (``plan.compiler.streaming_fits``) — a measured
+  ``block_tokens`` never violates the budget the backend choice
+  assumed.
 
 The heuristic default is always injected into the sweep, so a measured
 tiling can tie the heuristic but never lose to it (up to measurement
@@ -23,7 +25,8 @@ from __future__ import annotations
 
 from typing import Sequence
 
-from repro.kernels.ops import clamp_block
+from repro.kernels.ops import clamp_block, token_block
+from repro.kernels.tt_gemm import LANE, SUBLANE
 from repro.plan.compiler import VMEM_BUDGET_BYTES, streaming_fits
 from repro.core.tensor_network import TensorNetwork
 
@@ -37,9 +40,10 @@ STREAM_BLOCK_CAPS = (32, 64, 128, 256, 512, 1024)
 
 
 def block_candidates(dim: int,
-                     caps: Sequence[int] = GEMM_BLOCK_CAPS) -> list[int]:
-    """Deduped feasible blocks for one dimension (pow2, >= 8, <= ~dim)."""
-    return sorted({clamp_block(c, dim) for c in caps})
+                     caps: Sequence[int] = GEMM_BLOCK_CAPS,
+                     align: int = SUBLANE) -> list[int]:
+    """Deduped legal blocks for one dimension (see ``ops.clamp_block``)."""
+    return sorted({clamp_block(c, dim, align) for c in caps})
 
 
 def gemm_variants(
@@ -56,13 +60,14 @@ def gemm_variants(
     """
     out = {
         (bm, bk, bn)
-        for bm in block_candidates(M, caps)
-        for bk in block_candidates(K, caps)
-        for bn in block_candidates(N, caps)
+        for bm in block_candidates(M, caps, SUBLANE)
+        for bk in block_candidates(K, caps, LANE)
+        for bn in block_candidates(N, caps, LANE)
     }
     for bm, bk, bn in include:
-        out.add((clamp_block(int(bm), M), clamp_block(int(bk), K),
-                 clamp_block(int(bn), N)))
+        out.add((clamp_block(int(bm), M, SUBLANE),
+                 clamp_block(int(bk), K, LANE),
+                 clamp_block(int(bn), N, LANE)))
     return sorted(out)
 
 
@@ -77,15 +82,16 @@ def streaming_variants(
 ) -> list[int]:
     """Feasible ``block_tokens`` sweep for one streaming-layer problem.
 
-    Candidates are clamped to the streamed token count, then filtered by
+    Candidates are resolved to lane-legal blocks for the streamed token
+    count (``ops.token_block``), then filtered by
     the same VMEM-fit predicate the plan compiler's backend choice uses —
     every returned value can actually execute as a fused in-VMEM block.
     ``include`` injects the heuristic default (kept even if the dominant
     sweep dedups it away).
     """
-    cands = {clamp_block(c, tokens) for c in caps}
+    cands = {token_block(c, tokens) for c in caps}
     for bt in include:
-        cands.add(clamp_block(int(bt), tokens))
+        cands.add(token_block(int(bt), tokens))
     return sorted(
         bt for bt in cands
         if streaming_fits(tn, steps, bt, budget_bytes=budget_bytes)
@@ -104,7 +110,7 @@ def fused_token_variants(
 ) -> list[int]:
     """Feasible ``block_tokens`` sweep for one fused-segment problem.
 
-    Candidates are clamped to the streamed token count and filtered to
+    Candidates are resolved like :func:`streaming_variants` and filtered to
     the blocks at which the greedy segmentation *reproduces exactly the
     given segments* — a measured variant always executes the same fused
     chain runs the cost model priced, never a re-segmented layout.
@@ -114,9 +120,9 @@ def fused_token_variants(
 
     steps = tuple(tuple(s) for s in steps)
     segments = tuple((int(s), int(e)) for s, e in segments)
-    cands = {clamp_block(c, tokens) for c in caps}
+    cands = {token_block(c, tokens) for c in caps}
     for bt in include:
-        cands.add(clamp_block(int(bt), tokens))
+        cands.add(token_block(int(bt), tokens))
     return sorted(
         bt for bt in cands
         if fusion.segment_path(tn, steps, block_tokens=bt,

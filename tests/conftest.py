@@ -1,24 +1,19 @@
 """Shared test config.  NOTE: no global XLA device-count flags here —
 smoke tests and benches must see the real single CPU device; only the
-dry-run subprocess tests use forced host platform device counts."""
+dry-run subprocess tests use forced host platform device counts.
+
+The persistent compilation cache stays off, in this process and in the
+subprocesses tests start (JAX reads the variable at import)."""
 
 import os
-import sys
 
-import jax
-import pytest
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    # offline containers: register the minimal deterministic fallback so the
-    # property-test modules collect and run (see _hypothesis_fallback.py)
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install()
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(scope="session")

@@ -434,10 +434,8 @@ def test_tiling_clamped_to_runtime_shapes():
 def test_emit_plan_then_serve_subprocess(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    # an earlier in-process import of repro.launch.dryrun exports
-    # XLA_FLAGS=--xla_force_host_platform_device_count=N; serve would then
-    # build a multi-device mesh and (correctly) fall back to the jnp
-    # executor — this test wants the single-device kernel route
+    # a forced host-device count would make serve build a multi-device
+    # mesh — this test wants the single-device kernel route
     env.pop("XLA_FLAGS", None)
     plan_path = str(tmp_path / "plan.json")
     res = subprocess.run(

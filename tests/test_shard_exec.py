@@ -215,11 +215,13 @@ solo_tokens, solo_log = run("oneshot", None)
 # the property: per-request tokens bit-identical across mesh widths
 assert sharded_tokens == solo_tokens, (sharded_tokens, solo_tokens)
 
-# both runs executed planned Pallas on both streams — no silent jnp
+# both runs executed planned Pallas on every stream that ran — no
+# silent jnp (a decode step runs only if some request wants > 1 token)
+ran = {"prefill"} | ({"decode"} if any(g > 1 for _, g in spec) else set())
 for tag, log in (("sharded", sharded_log), ("solo", solo_log)):
     assert log, tag
     streams = {r["stream"] for r in log}
-    assert streams == {"prefill", "decode"}, (tag, streams)
+    assert streams == ran, (tag, streams, ran)
     backends = {r["backend"] for r in log}
     assert backends == {"streaming_tt"}, (tag, backends)
 

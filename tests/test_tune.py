@@ -290,7 +290,8 @@ def test_cached_tiling_lookups_never_measure():
                      measure_streaming_fn=_fail_streaming)
     assert warm.cached_gemm_blocks(96, 160, 512, "OS") is not None
     assert warm.cached_gemm_blocks(97, 160, 512, "OS") is None  # miss: None
-    assert warm.cached_streaming_tokens(tn, paths[0].steps, 32) == 32
+    # 32 tokens run as one lane-legal 128-token block
+    assert warm.cached_streaming_tokens(tn, paths[0].steps, 32) == 128
     assert warm.cached_streaming_tokens(rebatch(tn, 64), paths[0].steps,
                                         64) is None
 
