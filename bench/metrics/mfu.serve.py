@@ -1,0 +1,9 @@
+"""Model step: FLOPs the serve window's real tokens need (benchlib.work)
+over the window's seconds times the chip's peak."""
+
+
+def read(run):
+    if run.kind != "serve" or run.counter.flops <= 0:
+        return None
+    return 100.0 * run.counter.flops / (run.window["window_s"]
+                                        * run.peak["flops"])
