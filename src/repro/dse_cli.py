@@ -45,6 +45,7 @@ from repro.hw import HW_TARGETS  # noqa: F401  (re-export; registry is repro.hw)
 from repro.models.config import ModelConfig
 from repro.nn.linear import LinearSpec
 from repro.rank import RANK_SEARCH_MODES
+from repro.runtime.spans import span
 
 OBJECTIVES = ("latency", "edp", "throughput")
 MODES = ("infer", "train", "both")
@@ -393,104 +394,105 @@ def run_dse_plan(
     """
     from repro.plan import BACKENDS, compile_plan
 
-    if plan_backend != "auto" and plan_backend not in BACKENDS:
-        raise ValueError(
-            f"unknown plan backend {plan_backend!r}; have "
-            f"{('auto',) + BACKENDS}")
-    if mode not in MODES:
-        raise KeyError(f"unknown mode {mode!r}; have {MODES}")
-    infer_report = None
-    if mode == "both":
-        _check_train_compatible(objective, engine)  # fail before any search
-        _check_tune_compatible(tune, "both", objective, hw_search)
-        _check_rank_compatible(rank_search, "both", objective, engine, tune)
-        _check_fused_compatible(fused_cost, "both", objective, engine,
-                                hw_search, search, rank_search)
-        infer_report, _, _, _, _, _ = _run_dse(
-            arch, hw, top_k, objective, tokens, smoke, engine, "infer",
-            hw_search, hw_budget, search=search, search_budget=search_budget,
-            search_seed=search_seed, shards=shards)
-    plan_mode = "train" if mode in ("train", "both") else "infer"
-    report, named, res, plan_hw, tuner, calibration = _run_dse(
-        arch, hw, top_k, objective, tokens, smoke, engine, plan_mode,
-        hw_search, hw_budget, tune, tune_cache,
-        serve_gen, serve_slots, decode_tokens,
-        search, search_budget, search_seed, rank_search, accuracy_budget,
-        shards, fused_cost)
-    factorizations = None
-    rank_report = report.get("rank_search")
-    if rank_report is not None and rank_report.get("plan_embeddable"):
-        from repro.plan import Factorization
+    with span("dse.plan_search", phase=phase, mode=mode):
+        if plan_backend != "auto" and plan_backend not in BACKENDS:
+            raise ValueError(
+                f"unknown plan backend {plan_backend!r}; have "
+                f"{('auto',) + BACKENDS}")
+        if mode not in MODES:
+            raise KeyError(f"unknown mode {mode!r}; have {MODES}")
+        infer_report = None
+        if mode == "both":
+            _check_train_compatible(objective, engine)  # fail before any search
+            _check_tune_compatible(tune, "both", objective, hw_search)
+            _check_rank_compatible(rank_search, "both", objective, engine, tune)
+            _check_fused_compatible(fused_cost, "both", objective, engine,
+                                    hw_search, search, rank_search)
+            infer_report, _, _, _, _, _ = _run_dse(
+                arch, hw, top_k, objective, tokens, smoke, engine, "infer",
+                hw_search, hw_budget, search=search, search_budget=search_budget,
+                search_seed=search_seed, shards=shards)
+        plan_mode = "train" if mode in ("train", "both") else "infer"
+        report, named, res, plan_hw, tuner, calibration = _run_dse(
+            arch, hw, top_k, objective, tokens, smoke, engine, plan_mode,
+            hw_search, hw_budget, tune, tune_cache,
+            serve_gen, serve_slots, decode_tokens,
+            search, search_budget, search_seed, rank_search, accuracy_budget,
+            shards, fused_cost)
+        factorizations = None
+        rank_report = report.get("rank_search")
+        if rank_report is not None and rank_report.get("plan_embeddable"):
+            from repro.plan import Factorization
 
-        factorizations = {
-            f["name"]: Factorization(
-                out_modes=tuple(f["out_modes"]),
-                in_modes=tuple(f["in_modes"]),
-                ranks=tuple(f["ranks"]),
-                accuracy_proxy=float(f["accuracy_proxy"]))
-            for f in rank_report["chosen"]["families"]
-        }
-    plan_sharding = None
-    shard_rep = report.get("sharding")
-    if shard_rep is not None:
-        from repro.plan import PlanSharding
+            factorizations = {
+                f["name"]: Factorization(
+                    out_modes=tuple(f["out_modes"]),
+                    in_modes=tuple(f["in_modes"]),
+                    ranks=tuple(f["ranks"]),
+                    accuracy_proxy=float(f["accuracy_proxy"]))
+                for f in rank_report["chosen"]["families"]
+            }
+        plan_sharding = None
+        shard_rep = report.get("sharding")
+        if shard_rep is not None:
+            from repro.plan import PlanSharding
 
-        plan_sharding = PlanSharding(
-            n_shards=int(shard_rep["n_shards"]),
-            axes=tuple((str(a), int(s)) for a, s in shard_rep["axes"]),
-            tokens_per_shard=int(shard_rep["tokens_per_shard"]))
-    plan = compile_plan(
-        named, res, plan_hw,
-        arch=arch,
-        objective=report["objective"],
-        tokens=report["tokens"],
-        backend=plan_backend,
-        total_latency_s=report["total_latency_s"],
-        tilings="heuristic" if tuner is None else "measured",
-        tuner=tuner,
-        phase=phase,
-        factorizations=factorizations,
-        sharding=plan_sharding,
-    )
-    if tuner is not None:
-        if calibration is not None and report["objective"] == "latency":
-            # the argmin ran over the calibrated table, so each choice's
-            # latency landed in measured-rescaled units; divide the scale
-            # back out so the plan's per-layer provenance stays in the
-            # same analytic seconds as its total_latency_s (up to float
-            # rounding — (analytic * cal) / cal can differ from analytic
-            # by an ulp).  The correction model scales per (shape bucket,
-            # dataflow), so each family's scale comes from its own
-            # choice's dominant GEMM.  Train-mode searches run analytic
-            # (calibration is None) — their latencies need no unscaling.
-            # Throughput objectives keep calibrated units: the combined
-            # value mixes two phases' scales, so no single factor
-            # recovers analytic seconds.
-            from repro.plan.compiler import base_name
-            from repro.tune.variants import dominant_gemm
+            plan_sharding = PlanSharding(
+                n_shards=int(shard_rep["n_shards"]),
+                axes=tuple((str(a), int(s)) for a, s in shard_rep["axes"]),
+                tokens_per_shard=int(shard_rep["tokens_per_shard"]))
+        plan = compile_plan(
+            named, res, plan_hw,
+            arch=arch,
+            objective=report["objective"],
+            tokens=report["tokens"],
+            backend=plan_backend,
+            total_latency_s=report["total_latency_s"],
+            tilings="heuristic" if tuner is None else "measured",
+            tuner=tuner,
+            phase=phase,
+            factorizations=factorizations,
+            sharding=plan_sharding,
+        )
+        if tuner is not None:
+            if calibration is not None and report["objective"] == "latency":
+                # the argmin ran over the calibrated table, so each choice's
+                # latency landed in measured-rescaled units; divide the scale
+                # back out so the plan's per-layer provenance stays in the
+                # same analytic seconds as its total_latency_s (up to float
+                # rounding — (analytic * cal) / cal can differ from analytic
+                # by an ulp).  The correction model scales per (shape bucket,
+                # dataflow), so each family's scale comes from its own
+                # choice's dominant GEMM.  Train-mode searches run analytic
+                # (calibration is None) — their latencies need no unscaling.
+                # Throughput objectives keep calibrated units: the combined
+                # value mixes two phases' scales, so no single factor
+                # recovers analytic seconds.
+                from repro.plan.compiler import base_name
+                from repro.tune.variants import dominant_gemm
 
-            fam_choice = {}
-            for (inst_name, _), choice in zip(named, res.choices):
-                fam_choice.setdefault(base_name(inst_name), choice)
+                fam_choice = {}
+                for (inst_name, _), choice in zip(named, res.choices):
+                    fam_choice.setdefault(base_name(inst_name), choice)
 
-            def _unscale(lp):
-                c = fam_choice[lp.name]
-                M, K, N = dominant_gemm(c.path)
-                return dataclasses.replace(
-                    lp, latency_s=lp.latency_s / calibration.scale(
-                        M, K, N, lp.dataflow))
+                def _unscale(lp):
+                    c = fam_choice[lp.name]
+                    M, K, N = dominant_gemm(c.path)
+                    return dataclasses.replace(
+                        lp, latency_s=lp.latency_s / calibration.scale(
+                            M, K, N, lp.dataflow))
 
-            plan = dataclasses.replace(
-                plan, layers=tuple(_unscale(lp) for lp in plan.layers))
-        # compilation may have measured additional (per-family) sweeps;
-        # refresh the report's counters and persist the cache
-        report["tune"]["n_measured"] = tuner.n_measured
-        report["tune"]["n_cache_hits"] = tuner.n_cache_hits
-        report["tune"]["n_cache_entries"] = len(tuner.cache)
-        _save_tuner(tuner)
-    if mode == "both":
-        report = _both_report(infer_report, report)
-    return report, plan
+                plan = dataclasses.replace(
+                    plan, layers=tuple(_unscale(lp) for lp in plan.layers))
+            # compilation may have measured additional (per-family) sweeps;
+            # refresh the report's counters and persist the cache
+            report["tune"]["n_measured"] = tuner.n_measured
+            report["tune"]["n_cache_hits"] = tuner.n_cache_hits
+            report["tune"]["n_cache_entries"] = len(tuner.cache)
+            _save_tuner(tuner)
+        if mode == "both":
+            report = _both_report(infer_report, report)
+        return report, plan
 
 
 def _hw_search_report(space: ArchSpace, res, base_cfg,

@@ -282,6 +282,7 @@ def fused_segment_contract(
         out_shape=jax.ShapeDtypeStruct(out_padded, out_dtype),
         scratch_shapes=scratch_shapes,
         interpret=True,
+        name="fused_path",
     )(*arrays)
     if pt != tokens:
         y = jax.lax.slice_in_dim(y, 0, tokens, axis=opos)
@@ -337,7 +338,8 @@ def token_minor_segment(
     out_edges, out_dims = mids[-1]
     out_nb = tuple(e for e in out_edges if e != batch_edge)
     y = stream_chain(x2, x_edges, x_dims, pinned, out_nb, block_tokens=bt,
-                     out_dtype=out_dtype or x.dtype, interpret=interpret)
+                     out_dtype=out_dtype or x.dtype, interpret=interpret,
+                     name="fused_path")
     y = y[:tokens].reshape((tokens,) + tuple(
         d for e, d in zip(out_edges, out_dims) if e != batch_edge))
     return out_edges, jnp.moveaxis(y, 0, out_edges.index(batch_edge))

@@ -161,6 +161,7 @@ def stream_chain(
     block_tokens: int,
     out_dtype,
     interpret: bool,
+    name: str,
 ) -> jax.Array:
     """Contract streamed ``x`` (tokens, prod(x_dims)) with ``pinned`` in order.
 
@@ -170,6 +171,7 @@ def stream_chain(
     ``(tokens, prod(out dims))`` with columns in ``out_edges`` order.
     The pinned operands are whole-array VMEM blocks; ``x`` streams in
     ``block_tokens`` rows (a ``LANE`` multiple dividing ``tokens``).
+    ``name`` names the kernel in the compiled program (``%<name>.N``).
     """
     tokens = x.shape[0]
     if tokens % block_tokens:
@@ -199,6 +201,7 @@ def stream_chain(
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, n_out), out_dtype),
         interpret=interpret,
+        name=name,
         **kwargs,
     )(x, *mats)
 
@@ -246,7 +249,7 @@ def streaming_tt_linear(
     return stream_chain(
         x, x_edges, in_modes, pinned, out_edges[1:],
         block_tokens=block_tokens, out_dtype=out_dtype or x.dtype,
-        interpret=interpret)
+        interpret=interpret, name="streaming_tt")
 
 
 def build_block_network(

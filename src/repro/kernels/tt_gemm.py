@@ -202,6 +202,7 @@ def tt_gemm(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="tt_gemm",
         **kwargs,
     )(a, b)
     if dataflow != "OS":

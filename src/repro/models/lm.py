@@ -272,10 +272,11 @@ def forward(
 
 
 def apply_head(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
-    if cfg.tie_embeddings:
-        logits = head_apply(embed_spec(cfg), params["embed"], x)
-    else:
-        logits = linear_apply(head_spec(cfg), params["head"], x)
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            logits = head_apply(embed_spec(cfg), params["embed"], x)
+        else:
+            logits = linear_apply(head_spec(cfg), params["head"], x)
     if logits.ndim == 2:        # chunked-loss path: (tokens, V)
         return shard(logits, "tokens", "model")
     return shard(logits, "batch", None, "model")
@@ -345,15 +346,18 @@ def train_loss(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
             cfg, params, batch["tokens"], frontend=batch.get("frontend"),
             return_hidden=True,
         )
-        ce = chunked_cross_entropy(
-            lambda h: apply_head(cfg, params, h), hidden, batch["labels"],
-            cfg.loss_chunk,
-        )
+        with jax.named_scope("loss"):
+            ce = chunked_cross_entropy(
+                lambda h: apply_head(cfg, params, h), hidden, batch["labels"],
+                cfg.loss_chunk,
+            )
         return ce + cfg.aux_loss_weight * aux
     logits, _, aux = forward(
         cfg, params, batch["tokens"], frontend=batch.get("frontend")
     )
-    return cross_entropy(logits, batch["labels"]) + cfg.aux_loss_weight * aux
+    with jax.named_scope("loss"):
+        ce = cross_entropy(logits, batch["labels"])
+    return ce + cfg.aux_loss_weight * aux
 
 
 def prefill_full(cfg: ModelConfig, params: dict, batch: dict, max_seq: int):

@@ -212,67 +212,68 @@ def attention_apply(
     k = linear_apply(spec.k_spec, params["wk"], x).reshape(b, s, spec.n_kv_heads, spec.head_dim)
     v = linear_apply(spec.v_spec, params["wv"], x).reshape(b, s, spec.n_kv_heads, spec.head_dim)
 
-    rp = rope_for(spec.rope)
-    if rp is not None:
-        frac, base_f = rp
-        q = apply_rope(q, positions, base=base_f, rotary_fraction=frac)
-        k = apply_rope(k, positions, base=base_f, rotary_fraction=frac)
+    with jax.named_scope("attention"):
+        rp = rope_for(spec.rope)
+        if rp is not None:
+            frac, base_f = rp
+            q = apply_rope(q, positions, base=base_f, rotary_fraction=frac)
+            k = apply_rope(k, positions, base=base_f, rotary_fraction=frac)
 
-    q = _shard_heads(q, spec.n_heads)
-    k = _shard_heads(k, spec.n_kv_heads)
-    v = _shard_heads(v, spec.n_kv_heads)
+        q = _shard_heads(q, spec.n_heads)
+        k = _shard_heads(k, spec.n_kv_heads)
+        v = _shard_heads(v, spec.n_kv_heads)
 
-    if cache is None:
-        out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
-        new_cache = None
-    elif s > 1:
-        # prefill-with-cache: write the whole prompt's K/V at cache_pos and
-        # attend over the local (just-computed) K/V — identical numerics,
-        # no per-token cache round-trips
-        idx = cache_pos if cache_pos is not None else 0
-        if jnp.asarray(idx).ndim == 1:
-            raise ValueError(
-                "per-lane (B,) cache_pos is decode-only; prefill writes "
-                "one contiguous prompt per call (the serve scheduler "
-                "prefills each request at batch 1)")
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, k.astype(cache.k.dtype), idx, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, v.astype(cache.v.dtype), idx, axis=1)
-        new_cache = KVCache(ck, cv)
-        out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
-    else:
-        idx = cache_pos if cache_pos is not None else 0
-        idx = jnp.asarray(idx)
-        kv_pos = jnp.arange(cache.k.shape[1])
-        if idx.ndim == 1:
-            # per-lane positions: one-hot write (bit-exact equivalent of
-            # a per-lane dynamic_update_slice) + per-lane valid horizon
-            sel = kv_pos[None, :] == idx[:, None]                # (B, S)
-            ck = jnp.where(sel[:, :, None, None], k.astype(cache.k.dtype),
-                           cache.k)
-            cv = jnp.where(sel[:, :, None, None], v.astype(cache.v.dtype),
-                           cache.v)
-            vmask = (kv_pos[None, :] <= idx[:, None])[:, None, None, None, :]
-        else:
+        if cache is None:
+            out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
+            new_cache = None
+        elif s > 1:
+            # prefill-with-cache: write the whole prompt's K/V at cache_pos and
+            # attend over the local (just-computed) K/V — identical numerics,
+            # no per-token cache round-trips
+            idx = cache_pos if cache_pos is not None else 0
+            if jnp.asarray(idx).ndim == 1:
+                raise ValueError(
+                    "per-lane (B,) cache_pos is decode-only; prefill writes "
+                    "one contiguous prompt per call (the serve scheduler "
+                    "prefills each request at batch 1)")
             ck = jax.lax.dynamic_update_slice_in_dim(
                 cache.k, k.astype(cache.k.dtype), idx, axis=1)
             cv = jax.lax.dynamic_update_slice_in_dim(
                 cache.v, v.astype(cache.v.dtype), idx, axis=1)
-            vmask = (kv_pos <= idx)[None, None, None, None, :]
-        new_cache = KVCache(ck, cv)
-        hkv = spec.n_kv_heads
-        g = spec.n_heads // hkv
-        scale = 1.0 / math.sqrt(spec.head_dim)
-        qg = q.reshape(b, 1, g, hkv, spec.head_dim)   # interleaved grouping
-        # grouped decode: raw cache contracted directly (no repeat, no
-        # fp32 cache cast — fp32 lives only in the score accumulator)
-        scores = jnp.einsum("bqghd,bkhd->bghqk", qg, ck,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(vmask, scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bghqk,bkhd->bqghd", probs.astype(cv.dtype), cv)
-        out = out.reshape(b, 1, spec.n_heads, spec.head_dim)
+            new_cache = KVCache(ck, cv)
+            out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
+        else:
+            idx = cache_pos if cache_pos is not None else 0
+            idx = jnp.asarray(idx)
+            kv_pos = jnp.arange(cache.k.shape[1])
+            if idx.ndim == 1:
+                # per-lane positions: one-hot write (bit-exact equivalent of
+                # a per-lane dynamic_update_slice) + per-lane valid horizon
+                sel = kv_pos[None, :] == idx[:, None]                # (B, S)
+                ck = jnp.where(sel[:, :, None, None], k.astype(cache.k.dtype),
+                               cache.k)
+                cv = jnp.where(sel[:, :, None, None], v.astype(cache.v.dtype),
+                               cache.v)
+                vmask = (kv_pos[None, :] <= idx[:, None])[:, None, None, None, :]
+            else:
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    cache.k, k.astype(cache.k.dtype), idx, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cache.v, v.astype(cache.v.dtype), idx, axis=1)
+                vmask = (kv_pos <= idx)[None, None, None, None, :]
+            new_cache = KVCache(ck, cv)
+            hkv = spec.n_kv_heads
+            g = spec.n_heads // hkv
+            scale = 1.0 / math.sqrt(spec.head_dim)
+            qg = q.reshape(b, 1, g, hkv, spec.head_dim)   # interleaved grouping
+            # grouped decode: raw cache contracted directly (no repeat, no
+            # fp32 cache cast — fp32 lives only in the score accumulator)
+            scores = jnp.einsum("bqghd,bkhd->bghqk", qg, ck,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(vmask, scores, _NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bghqk,bkhd->bqghd", probs.astype(cv.dtype), cv)
+            out = out.reshape(b, 1, spec.n_heads, spec.head_dim)
 
     out = out.reshape(b, s, spec.n_heads * spec.head_dim)
     y = linear_apply(spec.o_spec, params["wo"], out)

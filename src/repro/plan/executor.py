@@ -458,32 +458,34 @@ def planned_tt_linear(
     Returns ``(tokens, d_out)``.  The plan's ``path_steps`` are replayed
     verbatim; the backend decides *how* each step runs.  Pallas backends
     are differentiable: the custom VJP contracts the plan's backward
-    networks (see module docstring).
+    networks (see module docstring).  Its ops, forward and backward,
+    sit under the name scope ``lp.name``.
     """
-    in_modes = tuple(in_modes)
-    out_modes = tuple(out_modes)
-    ranks = tuple(ranks)
-    tokens = x2d.shape[0]
-    record_execution(lp, tokens)
+    with jax.named_scope(lp.name):
+        in_modes = tuple(in_modes)
+        out_modes = tuple(out_modes)
+        ranks = tuple(ranks)
+        tokens = x2d.shape[0]
+        record_execution(lp, tokens)
 
-    if lp.backend == "jnp" and not has_pallas_backward(lp):
-        # pure-reference layer (jnp forward, no Pallas backward ops):
-        # plain jnp is natively differentiable, keep native autodiff
-        return _forward_planned(lp, x2d, cores, in_modes, out_modes, ranks,
-                                interpret)
+        if lp.backend == "jnp" and not has_pallas_backward(lp):
+            # pure-reference layer (jnp forward, no Pallas backward ops):
+            # plain jnp is natively differentiable, keep native autodiff
+            return _forward_planned(lp, x2d, cores, in_modes, out_modes, ranks,
+                                    interpret)
 
-    @jax.custom_vjp
-    def f(x2d, cores):
-        return _forward_planned(lp, x2d, cores, in_modes, out_modes, ranks,
-                                interpret)
+        @jax.custom_vjp
+        def f(x2d, cores):
+            return _forward_planned(lp, x2d, cores, in_modes, out_modes, ranks,
+                                    interpret)
 
-    def fwd(x2d, cores):
-        return f(x2d, cores), (x2d, cores)
+        def fwd(x2d, cores):
+            return f(x2d, cores), (x2d, cores)
 
-    def bwd(res, dy2d):
-        x2d, cores = res
-        return _backward_planned(lp, x2d, cores, dy2d, in_modes, out_modes,
-                                 ranks, interpret)
+        def bwd(res, dy2d):
+            x2d, cores = res
+            return _backward_planned(lp, x2d, cores, dy2d, in_modes, out_modes,
+                                     ranks, interpret)
 
-    f.defvjp(fwd, bwd)
-    return f(x2d, tuple(cores))
+        f.defvjp(fwd, bwd)
+        return f(x2d, tuple(cores))

@@ -1,4 +1,5 @@
-"""Runtime: fault tolerance, stragglers, elastic scaling."""
+"""Runtime: fault tolerance, stragglers, elastic scaling, and the
+program's span recorder (``repro.runtime.spans``)."""
 
 from .fault import FaultTolerantLoop, PreemptionGuard, StragglerMonitor
 

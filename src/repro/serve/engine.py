@@ -41,6 +41,7 @@ from repro.models import ModelConfig, api
 from repro.nn import plan_context
 from repro.plan import ExecutionPlan, execution_stream
 from repro.plan.compiler import check_plan_for_config
+from repro.runtime.spans import span
 
 
 class ServeEngine:
@@ -161,13 +162,16 @@ class ServeEngine:
                 f"padded prompt length {pp} exceeds max_seq {self.max_seq}")
         toks = np.zeros((1, pp), np.int32)
         toks[0, :p] = np.asarray(prompt, np.int32)
-        with plan_context(self.prefill_plan,
-                          force_backend=self._plan_backend):
-            with execution_stream("prefill"):
-                last, caches = self._prefill_fn(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray(p - 1, jnp.int32))
-        return np.asarray(last[0]), caches
+        with span("serve.prefill", tokens=pp):
+            with span("serve.prefill.dispatch"), plan_context(
+                    self.prefill_plan, force_backend=self._plan_backend):
+                with execution_stream("prefill"):
+                    last, caches = self._prefill_fn(
+                        self.params, jnp.asarray(toks),
+                        jnp.asarray(p - 1, jnp.int32))
+            with span("serve.prefill.fetch"):
+                row = np.asarray(last[0])
+        return row, caches
 
     def fresh_caches(self):
         """A zeroed width-``n_slots`` decode cache tree."""
@@ -178,24 +182,32 @@ class ServeEngine:
 
         Donates ``caches`` — the caller must use the returned tree.
         """
-        return self._admit_fn(caches, small, jnp.asarray(slot, jnp.int32))
+        with span("serve.admit"):
+            return self._admit_fn(caches, small, jnp.asarray(slot, jnp.int32))
 
     def decode(self, tok: np.ndarray, pos: np.ndarray, caches):
         """One fixed-width decode step under the decode plan.
 
         ``tok``/``pos`` are ``(n_slots,)`` host arrays (free lanes pass
         0).  Returns ``(logits (n_slots, V) np.ndarray, new caches)``;
-        donates ``caches``.
+        donates ``caches``.  Its spans split the call into the jitted
+        call's return (``dispatch``), the device finishing the step
+        (``wait``) and the copy of the logits to the host (``fetch``).
         """
-        with plan_context(self.decode_plan,
-                          force_backend=self._plan_backend):
-            with execution_stream("decode"):
-                logits, caches = self._decode_fn(
-                    self.params,
-                    jnp.asarray(tok, jnp.int32)[:, None],
-                    caches,
-                    jnp.asarray(pos, jnp.int32))
-        return np.asarray(logits), caches
+        with span("serve.decode"):
+            with span("serve.decode.dispatch"), plan_context(
+                    self.decode_plan, force_backend=self._plan_backend):
+                with execution_stream("decode"):
+                    logits, caches = self._decode_fn(
+                        self.params,
+                        jnp.asarray(tok, jnp.int32)[:, None],
+                        caches,
+                        jnp.asarray(pos, jnp.int32))
+            with span("serve.decode.wait"):
+                jax.block_until_ready(logits)
+            with span("serve.decode.fetch"):
+                rows = np.asarray(logits)
+        return rows, caches
 
     def lowered_text(self, prompt_len: int) -> dict[str, str]:
         """StableHLO of the prefill and decode programs, each traced under

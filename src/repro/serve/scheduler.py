@@ -14,6 +14,11 @@ Token selection is host-side and per-request deterministic: greedy
 ``np.argmax`` (first-max tie-break) at temperature 0, Gumbel-max
 sampling from a per-``(seed, rid)`` generator otherwise — a request's
 tokens never depend on which lanes its neighbours occupy.
+
+Spans (``repro.runtime.spans``): ``serve.step`` per tick, with the
+decode call's occupied ``lanes`` and ``slots``; ``serve.admission`` per
+request (``rid``), around its prefill, first-token pick and admit;
+``serve.sample`` around each host-side token pick.
 """
 
 from __future__ import annotations
@@ -25,6 +30,8 @@ import time
 from typing import Optional, Sequence
 
 import numpy as np
+
+from repro.runtime.spans import span
 
 from .engine import ServeEngine
 from .request import Completion, Request
@@ -136,63 +143,70 @@ class Scheduler:
         t0 = time.perf_counter()
 
         while future or ready or active:
-            while future and future[0].arrival <= step:
-                ready.append((future.popleft(), time.perf_counter()))
+            with span("serve.step", slots=ns) as tick:
+                while future and future[0].arrival <= step:
+                    ready.append((future.popleft(), time.perf_counter()))
 
-            admitted = 0
-            cap = self.policy.max_admissions_per_step
-            while (ready and active < concurrency
-                   and (cap is None or admitted < cap)):
-                free = next((i for i, s in enumerate(slots) if s is None),
-                            None)
-                if free is None:
-                    break
-                req, t_ready = ready.popleft()
-                rng = np.random.default_rng((self.seed, req.rid))
-                rngs[req.rid] = rng
-                row, small = eng.prefill_request(req.prompt)
-                first = self._select(row, rng)
-                st = _Active(req, first, t_ready, time.perf_counter(), step)
-                admitted += 1
-                if req.max_new_tokens == 1:
-                    # done at admission — never occupies a decode lane
-                    done.append(self._complete(st, step))
+                admitted = 0
+                cap = self.policy.max_admissions_per_step
+                while (ready and active < concurrency
+                       and (cap is None or admitted < cap)):
+                    free = next((i for i, s in enumerate(slots) if s is None),
+                                None)
+                    if free is None:
+                        break
+                    req, t_ready = ready.popleft()
+                    rng = np.random.default_rng((self.seed, req.rid))
+                    rngs[req.rid] = rng
+                    with span("serve.admission", rid=req.rid):
+                        row, small = eng.prefill_request(req.prompt)
+                        with span("serve.sample"):
+                            first = self._select(row, rng)
+                        st = _Active(req, first, t_ready, time.perf_counter(),
+                                     step)
+                        admitted += 1
+                        if req.max_new_tokens == 1:
+                            # done at admission — never occupies a decode
+                            # lane
+                            done.append(self._complete(st, step))
+                            continue
+                        caches = eng.admit(caches, small, free)
+                    slots[free] = st
+                    tok[free] = first
+                    pos[free] = len(req.prompt)
+                    active += 1
+
+                if active == 0:
+                    if not future and not ready:
+                        break  # drained
+                    if ready:
+                        # admission cap hit on single-token requests — next
+                        # tick's fresh cap admits the rest
+                        step += 1
+                    else:
+                        # idle: jump the clock to the next arrival
+                        step = max(step + 1, math.ceil(future[0].arrival))
                     continue
-                caches = eng.admit(caches, small, free)
-                slots[free] = st
-                tok[free] = first
-                pos[free] = len(req.prompt)
-                active += 1
 
-            if active == 0:
-                if not future and not ready:
-                    break  # drained
-                if ready:
-                    # admission cap hit on single-token requests — next
-                    # tick's fresh cap admits the rest
-                    step += 1
-                else:
-                    # idle: jump the clock to the next arrival
-                    step = max(step + 1, math.ceil(future[0].arrival))
-                continue
-
-            rows, caches = eng.decode(tok, pos, caches)
-            decode_steps += 1
-            lane_steps += active
-            step += 1
-            for i, st in enumerate(slots):
-                if st is None:
-                    continue
-                nxt = self._select(rows[i], rngs[st.req.rid])
-                st.tokens.append(nxt)
-                tok[i] = nxt
-                pos[i] += 1
-                if len(st.tokens) >= st.req.max_new_tokens:
-                    done.append(self._complete(st, step))
-                    slots[i] = None
-                    tok[i] = 0
-                    pos[i] = 0
-                    active -= 1
+                tick.set(lanes=active)
+                rows, caches = eng.decode(tok, pos, caches)
+                decode_steps += 1
+                lane_steps += active
+                step += 1
+                with span("serve.sample"):
+                    for i, st in enumerate(slots):
+                        if st is None:
+                            continue
+                        nxt = self._select(rows[i], rngs[st.req.rid])
+                        st.tokens.append(nxt)
+                        tok[i] = nxt
+                        pos[i] += 1
+                        if len(st.tokens) >= st.req.max_new_tokens:
+                            done.append(self._complete(st, step))
+                            slots[i] = None
+                            tok[i] = 0
+                            pos[i] = 0
+                            active -= 1
 
         wall = time.perf_counter() - t0
         occ = lane_steps / (decode_steps * ns) if decode_steps else 0.0

@@ -11,11 +11,14 @@ loads the TPU compiler.
 Covered: ``tt_gemm`` in every dataflow with several k-folds; every layer
 of the tt-lm-100m ``tpu_v5e`` prefill plan at 512 tokens and decode plan
 at 4 tokens, through ``planned_tt_linear``; one train-plan backward; one
-fused path segment.
+fused path segment.  Each Mosaic call must carry its kernel's name
+(``%tt_gemm.N``, ``%streaming_tt.N``, ``%fused_path.N``): the profiler
+trace keys device ops by these instruction names.
 """
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +90,13 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernels(compiled) -> set:
+    """Names of the Mosaic calls in a compiled program's HLO text."""
+    return set(re.findall(r"%([a-z_]+)(?:\.[\w.]+)? = [^\n]*"
+                          r'custom_call_target="tpu_custom_call"',
+                          compiled.as_text()))
+
+
 def _layer_args(geom, tokens, sharding):
     in_modes, out_modes, ranks, core_dims = geom
     x = jax.ShapeDtypeStruct((tokens, math.prod(in_modes)), jnp.float32,
@@ -103,7 +113,7 @@ def test_tt_gemm_compiles_with_k_folds(one_chip, dataflow):
     compiled = _compile(
         lambda a, b: ops.gemm(a, b, dataflow=dataflow, interpret=False),
         a, b)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {"tt_gemm"}
 
 
 @pytest.mark.parametrize("phase,tokens", [("prefill", 512), ("decode", 4)])
@@ -117,7 +127,9 @@ def test_serving_plan_layer_compiles(one_chip, plans, geometry, phase,
         lambda x, cs: planned_tt_linear(lp, x, cs, in_modes, out_modes,
                                         ranks, interpret=False), x, cores)
     if lp.backend != "jnp":
-        assert "tpu_custom_call" in compiled.as_text()
+        names = _kernels(compiled)
+        fused = {"fused_path"} if fusion.has_fused(lp.segments) else set()
+        assert names - fused == {lp.backend} and names >= fused
 
 
 def test_train_plan_backward_compiles(one_chip, plans, geometry):
@@ -132,7 +144,8 @@ def test_train_plan_backward_compiles(one_chip, plans, geometry):
         return jnp.sum(y * y)
 
     compiled = _compile(jax.grad(loss, argnums=(0, 1)), x, cores)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {lp.backend} | {op.backend
+                                                for op in lp.backward}
 
 
 def test_fused_segment_compiles(one_chip):
@@ -153,4 +166,4 @@ def test_fused_segment_compiles(one_chip):
     compiled = _compile(
         lambda x, cs: planned_tt_linear(lp, x, cs, in_modes, out_modes,
                                         ranks, interpret=False), x, cores)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "fused_path" in _kernels(compiled)

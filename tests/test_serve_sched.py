@@ -276,3 +276,45 @@ def test_arrival_gating_idles_until_next_request():
     res = _run("continuous", reqs)
     (c,) = res.completions
     assert c.admitted_step >= 5
+
+
+def test_spans_count_what_the_scheduler_did(monkeypatch):
+    """The serving spans agree with the scheduler's own counts: one
+    ``serve.decode`` per decode step, the steps' mean ``lanes`` equal to
+    the result's occupancy, one ``*.fetch`` per decode step and prefill,
+    every pick inside a step, and no name the benchmark reserves."""
+    import time
+
+    from repro.runtime import spans
+
+    eng = _engine()
+    calls = {"decode": 0, "prefill_request": 0}
+    for name in calls:
+        def count(*a, _name=name, _fn=getattr(eng, name)):
+            calls[_name] += 1
+            return _fn(*a)
+        monkeypatch.setattr(eng, name, count)
+    # an idle jump, a single-token request and a full queue
+    reqs = [Request(rid=i, prompt=_prompt(i), max_new_tokens=g, arrival=a)
+            for i, (g, a) in enumerate([(4, 0.0), (1, 0.0), (3, 0.0),
+                                        (2, 0.0), (3, 9.0)])]
+    t0 = time.perf_counter()
+    res = _run("continuous", reqs)
+    got = spans.spans(t0, time.perf_counter())
+    by_id = {s.id: s for s in got}
+
+    def named(name):
+        return [s for s in got if s.name == name]
+
+    assert len(named("serve.decode")) == calls["decode"] > 0
+    decoded = [by_id[s.parent] for s in named("serve.decode")]
+    assert all(s.name == "serve.step" for s in decoded)
+    assert np.mean([s.attrs["lanes"] for s in decoded]) == pytest.approx(
+        res.occupancy * res.n_slots)
+    assert sum(s.name.endswith(".fetch") for s in got) == (
+        calls["decode"] + calls["prefill_request"])
+    assert len(named("serve.admission")) == calls["prefill_request"] == 5
+    for s in named("serve.sample"):
+        while s.name != "serve.step":
+            s = by_id[s.parent]
+    assert not [s for s in got if s.name.startswith("bench.")]
