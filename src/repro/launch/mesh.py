@@ -146,8 +146,10 @@ def _cache_pspec(path: str, shape: tuple[int, ...], cfg: ModelConfig,
                  rules: ShardingRules) -> P:
     """Decode-cache shardings by leaf name.
 
-    KV-style (L, B, S, H, D): batch -> DP; heads -> model when divisible,
-    else sequence -> model (SP cache).  State-style: batch -> DP, the
+    KV-style, lane-dense (L, B, S, H*D) self-attention or (L, B, S, H, D)
+    cross-attention: batch -> DP; heads -> model when divisible (on the
+    flattened H*D, whose contiguous chunks are then whole heads), else
+    sequence -> model (SP cache).  State-style: batch -> DP, the
     channel/head dim -> model when divisible.
     """
     ax = rules.axis_sizes
@@ -158,7 +160,15 @@ def _cache_pspec(path: str, shape: tuple[int, ...], cfg: ModelConfig,
     def batch_spec(b):
         return rules.batch_axes if (dp > 1 and b % dp == 0) else None
 
-    if name in ("k", "v", "cross_k", "cross_v") and len(shape) == 5:
+    if name in ("k", "v") and len(shape) == 4:
+        L, b, s, hd = shape
+        bspec = batch_spec(b)
+        if tp > 1 and cfg.n_kv_heads % tp == 0:
+            return P(None, bspec, None, "model")
+        if rules.seq_axis and tp > 1 and s % tp == 0:
+            return P(None, bspec, "model", None)
+        return P(None, bspec, None, None)
+    if name in ("cross_k", "cross_v") and len(shape) == 5:
         L, b, s, h, d = shape
         bspec = batch_spec(b)
         if tp > 1 and h % tp == 0:

@@ -151,13 +151,18 @@ def block_apply(
     positions: Optional[jax.Array],
     cache,
     cache_pos,
+    layer=None,
 ):
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).
+
+    ``layer``: attention families take the stacked KV cache of every
+    layer and write this block's rows into layer ``layer`` of it
+    (``attention_apply``)."""
     zero = jnp.zeros((), jnp.float32)
     if cfg.family in ("dense", "vlm", "encdec", "moe"):
         h, new_cache = attention_apply(
             attn_spec(cfg), params["attn"], rmsnorm(params["ln1"], x),
-            positions, cache, cache_pos,
+            positions, cache, cache_pos, layer,
         )
         x = x + h
         if cfg.family == "moe":
@@ -191,9 +196,10 @@ def shared_attn_apply(
     positions: Optional[jax.Array],
     cache,
     cache_pos,
+    layer=None,
 ):
     h, new_cache = attention_apply(
         attn_spec(cfg, name="shared_attn"), params["attn"],
-        rmsnorm(params["ln"], x), positions, cache, cache_pos,
+        rmsnorm(params["ln"], x), positions, cache, cache_pos, layer,
     )
     return x + h, new_cache

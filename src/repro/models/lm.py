@@ -123,7 +123,10 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 
 def _run_blocks(cfg, params, x, positions, caches, cache_pos):
-    """Scan/unroll the stacked blocks.  Returns (x, new_caches, aux)."""
+    """Scan/unroll the stacked blocks.  Returns (x, new_caches, aux).
+
+    Recurrent state goes through the loop per layer (``xs`` in, ``ys``
+    out); a KV cache is carried whole (``_run_kv_blocks``)."""
     has_cache = caches is not None
 
     def body(carry, inp):
@@ -136,6 +139,8 @@ def _run_blocks(cfg, params, x, positions, caches, cache_pos):
 
     if cfg.family == "hybrid":
         return _run_hybrid(cfg, params, x, positions, caches, cache_pos, body)
+    if has_cache and cfg.family != "rwkv":
+        return _run_kv_blocks(cfg, params, x, positions, caches, cache_pos)
 
     blocks = params["blocks"]
     if cfg.scan_layers:
@@ -157,6 +162,30 @@ def _run_blocks(cfg, params, x, positions, caches, cache_pos):
     return x, (new_caches if has_cache else None), aux
 
 
+def _run_kv_blocks(cfg, params, x, positions, caches, cache_pos):
+    """The attention families' layer loop with a KV cache: the stacked
+    (L, B, S_max, H_kv * Dh) cache rides in the carry and layer ``l``
+    writes its rows into it in place — a scan's ``ys`` would stack every
+    layer's whole cache into a new buffer, copied back afterwards."""
+
+    def body(carry, inp):
+        x, aux, kv = carry
+        p_l, l = inp
+        x, kv, a = block_apply(cfg, p_l, x, positions, kv, cache_pos, l)
+        return (x, aux + a, kv), None
+
+    body = _remat(cfg, body)
+    blocks = params["blocks"]
+    carry = (x, jnp.zeros((), jnp.float32), caches)
+    if cfg.scan_layers:
+        carry, _ = jax.lax.scan(body, carry, (blocks, jnp.arange(cfg.n_layers)))
+    else:
+        for l in range(cfg.n_layers):
+            carry, _ = body(carry, (jax.tree.map(lambda a: a[l], blocks), l))
+    x, aux, caches = carry
+    return x, caches, aux
+
+
 def _run_hybrid(cfg, params, x, positions, caches, cache_pos, body):
     """Groups of ``attn_every`` Mamba layers + shared attention per group."""
     n_groups, rem = _hybrid_groups(cfg)
@@ -174,40 +203,40 @@ def _run_hybrid(cfg, params, x, positions, caches, cache_pos, body):
     )
     tail_c = jax.tree.map(lambda a: a[n_groups * g :], ssm_caches) if has_cache else None
 
+    # the shared attention's stacked KV cache (one layer per group) rides
+    # in the carry and group gi writes its rows in place, as in
+    # _run_kv_blocks; the Mamba state goes through per layer
     def group_body(carry, inp):
-        x, aux = carry
-        gp, gc_ssm, gc_attn = inp
+        x, aux, attn = carry
+        gp, gc_ssm, gi = inp
         (x, aux), new_ssm = jax.lax.scan(
             body, (x, aux), (gp, gc_ssm) if has_cache else (gp, None)
         )
-        x, new_attn = shared_attn_apply(
-            cfg, params["shared_attn"], x, positions, gc_attn, cache_pos
+        x, attn = shared_attn_apply(
+            cfg, params["shared_attn"], x, positions, attn, cache_pos, gi
         )
-        return (x, aux), (new_ssm, new_attn)
+        return (x, aux, attn), new_ssm
 
     group_body = _remat(cfg, group_body)
 
+    carry = (x, jnp.zeros((), jnp.float32), attn_caches)
     if cfg.scan_layers:
-        (x, aux), (new_main_ssm, new_attn) = jax.lax.scan(
-            group_body, (x, jnp.zeros((), jnp.float32)),
-            (main, main_c, attn_caches) if has_cache else (main, None, None),
+        carry, new_main_ssm = jax.lax.scan(
+            group_body, carry,
+            (main, main_c, jnp.arange(n_groups)) if has_cache
+            else (main, None, None),
         )
     else:
-        aux = jnp.zeros((), jnp.float32)
-        ssm_list, attn_list = [], []
+        ssm_list = []
         for gi in range(n_groups):
             gp = jax.tree.map(lambda a: a[gi], main)
             gc_s = jax.tree.map(lambda a: a[gi], main_c) if has_cache else None
-            gc_a = jax.tree.map(lambda a: a[gi], attn_caches) if has_cache else None
-            (x, aux), (ns, na) = group_body((x, aux), (gp, gc_s, gc_a))
+            carry, ns = group_body(carry, (gp, gc_s, gi if has_cache else None))
             ssm_list.append(ns)
-            attn_list.append(na)
         new_main_ssm = (
             jax.tree.map(lambda *xs: jnp.stack(xs), *ssm_list) if has_cache else None
         )
-        new_attn = (
-            jax.tree.map(lambda *xs: jnp.stack(xs), *attn_list) if has_cache else None
-        )
+    x, aux, new_attn = carry
 
     new_tail = None
     if rem:

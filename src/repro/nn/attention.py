@@ -80,7 +80,11 @@ class AttentionSpec:
 
 
 class KVCache(NamedTuple):
-    k: jax.Array     # (B, S_max, H_kv, Dh)
+    """Lane-dense K/V: (B, S_max, H_kv * Dh), or stacked over the layers
+    as (L, B, S_max, H_kv * Dh).  A position's row is one contiguous
+    H_kv * Dh vector, so a decode step writes each lane's row in place."""
+
+    k: jax.Array
     v: jax.Array
 
 
@@ -179,6 +183,62 @@ def _chunked_attention(
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dh)
 
 
+def _write_rows(stack: jax.Array, rows: jax.Array, layer, pos: jax.Array) -> jax.Array:
+    """``rows`` (B, s, H_kv, Dh) written into layer ``layer`` of ``stack``
+    (L, B, S_max, H_kv * Dh), nothing else touched.
+
+    A (B,) ``pos`` (decode, s == 1) is one scatter of B rows at
+    ``(layer, lane, pos[lane])``; a position past the cache is dropped.
+    A scalar ``pos`` is one dynamic_update_slice of the s rows there.
+    """
+    b, s = rows.shape[:2]
+    rows = rows.reshape(b, s, stack.shape[-1]).astype(stack.dtype)
+    if pos.ndim == 1:
+        return stack.at[layer, jnp.arange(b), pos].set(
+            rows[:, 0], mode="drop", indices_are_sorted=True,
+            unique_indices=True)
+    zero = jnp.zeros((), pos.dtype)
+    return jax.lax.dynamic_update_slice(stack, rows[None],
+                                        (layer, zero, pos, zero))
+
+
+def _decode_attention(spec: AttentionSpec, q: jax.Array, ks: jax.Array,
+                      vs: jax.Array, layer, pos: jax.Array) -> jax.Array:
+    """One new token per lane, q (B, 1, H, Dh), against layer ``layer`` of
+    the stacked cache over all S_max positions, masked to each lane's
+    valid prefix (``pos`` () or (B,), the new token's position).
+
+    The layer's K/V rows are contracted whole, never split into
+    (H_kv, Dh): on the TPU a head narrower than 128 lanes would take a
+    relayout copy of the layer's whole cache.  Each query head is placed
+    on its KV head's block of a row (zeros elsewhere) for the scores, and
+    each head's output is the diagonal block of probs @ V — exact, at
+    H_kv times a small multiply count, and the cache is read once.
+    """
+    b = q.shape[0]
+    hkv, dh = spec.n_kv_heads, spec.head_dim
+    g = spec.n_heads // hkv
+    ck, cv = (jax.lax.dynamic_index_in_dim(c, layer, axis=0, keepdims=False)
+              for c in (ks, vs))                              # (B, S, Hkv*Dh)
+    kv_pos = jnp.arange(ck.shape[1])
+    if pos.ndim == 1:    # per-lane valid horizon
+        vmask = (kv_pos[None, :] <= pos[:, None])[:, None, None, :]
+    else:
+        vmask = (kv_pos <= pos)[None, None, None, :]
+    scale = 1.0 / math.sqrt(dh)
+    own = jnp.eye(hkv, dtype=bool)[:, :, None]   # head h's block of a row
+    qg = q.reshape(b, g, hkv, 1, dh)             # interleaved grouping
+    qx = jnp.where(own, qg, 0).reshape(b, g, hkv, hkv * dh)
+    # fp32 lives only in the score accumulator (no fp32 cache cast)
+    scores = jnp.einsum("bghw,bkw->bghk", qx, ck,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(vmask, scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    full = jnp.einsum("bghk,bkw->bghw", probs.astype(cv.dtype), cv)
+    out = jnp.diagonal(full.reshape(b, g, hkv, hkv, dh), axis1=2, axis2=3)
+    return jnp.swapaxes(out, -1, -2).reshape(b, 1, spec.n_heads, dh)
+
+
 def attention_apply(
     spec: AttentionSpec,
     params: dict,
@@ -186,6 +246,7 @@ def attention_apply(
     positions: Optional[jax.Array] = None,
     cache: Optional[KVCache] = None,
     cache_pos: Optional[jax.Array] = None,   # () or (B,): #tokens cached
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, Optional[KVCache]]:
     """Returns (output, updated_cache).
 
@@ -197,6 +258,12 @@ def attention_apply(
     the fixed-width batch sits at a different sequence offset.  Each
     lane's output depends only on that lane's (cache, token, position),
     so slot contents never leak across requests.
+
+    With ``layer``, ``cache`` holds every layer's stacked (L, B, S_max,
+    H_kv * Dh) K/V; this call writes its rows into layer ``layer`` and
+    attends over that layer, and the whole stack is returned.  Carried
+    through the layer loop, the stack is updated in place: only the new
+    rows are written.
     """
     b, s, _ = x.shape
     if positions is None:
@@ -226,54 +293,26 @@ def attention_apply(
         if cache is None:
             out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
             new_cache = None
-        elif s > 1:
-            # prefill-with-cache: write the whole prompt's K/V at cache_pos and
-            # attend over the local (just-computed) K/V — identical numerics,
-            # no per-token cache round-trips
-            idx = cache_pos if cache_pos is not None else 0
-            if jnp.asarray(idx).ndim == 1:
+        else:
+            idx = jnp.asarray(cache_pos if cache_pos is not None else 0)
+            if s > 1 and idx.ndim == 1:
                 raise ValueError(
                     "per-lane (B,) cache_pos is decode-only; prefill writes "
                     "one contiguous prompt per call (the serve scheduler "
                     "prefills each request at batch 1)")
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache.k, k.astype(cache.k.dtype), idx, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache.v, v.astype(cache.v.dtype), idx, axis=1)
-            new_cache = KVCache(ck, cv)
-            out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
-        else:
-            idx = cache_pos if cache_pos is not None else 0
-            idx = jnp.asarray(idx)
-            kv_pos = jnp.arange(cache.k.shape[1])
-            if idx.ndim == 1:
-                # per-lane positions: one-hot write (bit-exact equivalent of
-                # a per-lane dynamic_update_slice) + per-lane valid horizon
-                sel = kv_pos[None, :] == idx[:, None]                # (B, S)
-                ck = jnp.where(sel[:, :, None, None], k.astype(cache.k.dtype),
-                               cache.k)
-                cv = jnp.where(sel[:, :, None, None], v.astype(cache.v.dtype),
-                               cache.v)
-                vmask = (kv_pos[None, :] <= idx[:, None])[:, None, None, None, :]
+            stacked = layer is not None
+            ks, vs = cache if stacked else (cache.k[None], cache.v[None])
+            layer = layer if stacked else 0
+            ks = _write_rows(ks, k, layer, idx)
+            vs = _write_rows(vs, v, layer, idx)
+            new_cache = KVCache(ks, vs) if stacked else KVCache(ks[0], vs[0])
+            if s > 1:
+                # prefill-with-cache: the prompt's K/V is written at
+                # cache_pos; attend over the local (just-computed) K/V —
+                # identical numerics, no per-token cache round-trips
+                out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
             else:
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    cache.k, k.astype(cache.k.dtype), idx, axis=1)
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cache.v, v.astype(cache.v.dtype), idx, axis=1)
-                vmask = (kv_pos <= idx)[None, None, None, None, :]
-            new_cache = KVCache(ck, cv)
-            hkv = spec.n_kv_heads
-            g = spec.n_heads // hkv
-            scale = 1.0 / math.sqrt(spec.head_dim)
-            qg = q.reshape(b, 1, g, hkv, spec.head_dim)   # interleaved grouping
-            # grouped decode: raw cache contracted directly (no repeat, no
-            # fp32 cache cast — fp32 lives only in the score accumulator)
-            scores = jnp.einsum("bqghd,bkhd->bghqk", qg, ck,
-                                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(vmask, scores, _NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bghqk,bkhd->bqghd", probs.astype(cv.dtype), cv)
-            out = out.reshape(b, 1, spec.n_heads, spec.head_dim)
+                out = _decode_attention(spec, q, ks, vs, layer, idx)
 
     out = out.reshape(b, s, spec.n_heads * spec.head_dim)
     y = linear_apply(spec.o_spec, params["wo"], out)
@@ -281,5 +320,5 @@ def attention_apply(
 
 
 def init_kv_cache(spec: AttentionSpec, batch: int, max_seq: int, dtype=jnp.bfloat16) -> KVCache:
-    shape = (batch, max_seq, spec.n_kv_heads, spec.head_dim)
+    shape = (batch, max_seq, spec.n_kv_heads * spec.head_dim)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))

@@ -11,11 +11,14 @@ loads the TPU compiler.
 Covered: ``tt_gemm`` in every dataflow with several k-folds; every layer
 of the tt-lm-100m ``tpu_v5e`` prefill plan at 512 tokens and decode plan
 at 4 tokens, through ``planned_tt_linear``; one train-plan backward; one
-fused path segment.  Each Mosaic call must carry its kernel's name
-(``%tt_gemm.N``, ``%streaming_tt.N``, ``%fused_path.N``): the profiler
-trace keys device ops by these instruction names.
+fused path segment; the serving engine's whole bfloat16 decode step at
+256 lanes and ``max_seq`` 1536, whose KV cache must be written in place.
+Each Mosaic call must carry its kernel's name (``%tt_gemm.N``,
+``%streaming_tt.N``, ``%fused_path.N``): the profiler trace keys device
+ops by these instruction names.
 """
 
+import dataclasses
 import math
 import os
 import re
@@ -167,3 +170,95 @@ def test_fused_segment_compiles(one_chip):
         lambda x, cs: planned_tt_linear(lp, x, cs, in_modes, out_modes,
                                         ranks, interpret=False), x, cores)
     assert "fused_path" in _kernels(compiled)
+
+
+def _instructions(hlo: str):
+    """``(computation, in_entry, result_type, opcode, line)`` of every
+    instruction in an HLO module's text."""
+    comp, entry = None, False
+    for line in hlo.splitlines():
+        if line[:1] not in ("", " ") and line.rstrip().endswith("{"):
+            entry = line.startswith("ENTRY ")
+            comp = line.split()[1 if entry else 0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = ", line)
+        if m is None:
+            continue
+        rest = line[m.end():]
+        if rest.startswith("("):          # a tuple type: up to its ")"
+            depth = 0
+            for i, c in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(c, 0)
+                if depth == 0:
+                    break
+            typ, rest = rest[:i + 1], rest[i + 1:]
+        else:
+            typ, _, rest = rest.partition(" ")
+        yield comp, entry, typ, rest.strip().split("(", 1)[0], line
+
+
+#: what may produce a whole stacked cache: plumbing, and in-place writes
+_IN_PLACE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "scatter", "dynamic-update-slice"}
+
+
+def test_serving_decode_step_writes_kv_in_place(topo, one_chip, monkeypatch):
+    """The engine's decode step for tt-lm-100m in bfloat16 at the serving
+    cell's 256 lanes and ``max_seq`` 1536, under the ``tpu_v5e`` decode
+    plan: each layer's new K/V rows go into the donated stacked cache in
+    place.  No copy, select or loop fusion may produce a whole stacked
+    cache leaf (only plumbing and scatter or dynamic-update-slice
+    fusions), no temporary may hold one layer's cache, and both leaves
+    are aliased input to output."""
+    from repro.configs import get_config
+    from repro.dse_cli import run_dse_plan
+    from repro.models import api
+    from repro.nn import plan_context
+    from repro.plan import execution_stream
+    from repro.serve import ServeEngine
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    lanes, max_seq = 256, 1536
+    cfg = dataclasses.replace(get_config(ARCH), dtype="bfloat16")
+    plan = run_dse_plan(ARCH, hw="tpu_v5e", tokens=lanes, phase="decode",
+                        serve_slots=lanes)[1]
+    m = api(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(m.init_params,
+                                                  jax.random.PRNGKey(0)))
+    eng = ServeEngine(cfg, params, n_slots=lanes, max_seq=max_seq,
+                      decode_plan=plan, arch=ARCH)
+    caches = jax.tree.map(on_chip, jax.eval_shape(eng.fresh_caches))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    with plan_context(plan), execution_stream("decode"):
+        compiled = eng._decode_fn.lower(params, i32(lanes, 1), caches,
+                                        i32(lanes)).compile()
+    hlo = compiled.as_text()
+
+    leaves = jax.tree.leaves(caches)
+    assert len(leaves) == 2 and {a.shape for a in leaves} == {leaves[0].shape}
+    stacked = "bf16[" + ",".join(map(str, leaves[0].shape)) + "]"
+    roots = {c: op for c, _, _, op, line in _instructions(hlo)
+             if line.lstrip().startswith("ROOT ")}
+    bad = []
+    for comp, _, typ, op, line in _instructions(hlo):
+        if stacked not in typ or op in _IN_PLACE:
+            continue
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        if op == "fusion" and called and roots.get(called.group(1)) in (
+                "scatter", "dynamic-update-slice"):
+            continue
+        bad.append(line.strip()[:160])
+    assert not bad, "whole-cache passes:\n" + "\n".join(bad)
+
+    one_layer = math.prod(leaves[0].shape[1:]) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+    aliased = {int(p) for p in re.findall(
+        r"\{\d*\}: \((\d+), \{\}", hlo.split("\n", 1)[0])}
+    cache_params = {int(re.search(r"parameter\((\d+)\)", line).group(1))
+                    for _, entry, typ, op, line in _instructions(hlo)
+                    if entry and op == "parameter" and typ.startswith(stacked)}
+    assert len(cache_params) == 2 and cache_params <= aliased

@@ -10,6 +10,7 @@ from repro.core import tt_svd
 from repro.nn import (
     AttentionSpec,
     EmbeddingSpec,
+    KVCache,
     LinearSpec,
     MoESpec,
     TTConfig,
@@ -25,6 +26,7 @@ from repro.nn import (
     moe_apply,
     moe_init,
 )
+from repro.nn.rope import apply_rope, rope_for
 from repro.nn.rwkv import _wkv_chunked
 from repro.nn.ssm import _ssd_chunked
 
@@ -159,6 +161,121 @@ def test_gqa_decode_matches_prefill_continuation(rng):
                              cache_pos=jnp.asarray(8, jnp.int32))
     np.testing.assert_allclose(np.asarray(dec[:, 0]), np.asarray(full[:, 8]),
                                rtol=1e-4, atol=1e-4)
+
+
+_KV_SPEC = AttentionSpec("kv", d_model=16, n_heads=6, n_kv_heads=2,
+                         head_dim=8, rope="full")
+
+
+def _qkv_reference(spec, p, x, positions):
+    """q, k, v of ``x`` (B, s, D) at ``positions`` (B, s), heads split."""
+    b, s, _ = x.shape
+    q, k, v = (linear_apply(ls, p[n], x).reshape(b, s, -1, spec.head_dim)
+               for ls, n in ((spec.q_spec, "wq"), (spec.k_spec, "wk"),
+                             (spec.v_spec, "wv")))
+    frac, base = rope_for(spec.rope)
+    return (apply_rope(q, positions, base=base, rotary_fraction=frac),
+            apply_rope(k, positions, base=base, rotary_fraction=frac), v)
+
+
+def _decode_reference(spec, p, x, k4, v4, pos):
+    """Decode as written before the cache went lane-dense: the new rows
+    put into a (B, S, H_kv, Dh) cache by a one-hot ``where`` over every
+    position (per lane for a (B,) ``pos``, else a dynamic_update_slice),
+    then grouped attention over the heads.  Returns (out, k4, v4)."""
+    b, hkv, dh = x.shape[0], spec.n_kv_heads, spec.head_dim
+    kv_pos = jnp.arange(k4.shape[1])
+    lane_pos = jnp.broadcast_to(pos, (b,))
+    q, k, v = _qkv_reference(spec, p, x, lane_pos[:, None])
+    if pos.ndim == 1:
+        sel = (kv_pos[None, :] == pos[:, None])[:, :, None, None]
+        k4 = jnp.where(sel, k.astype(k4.dtype), k4)
+        v4 = jnp.where(sel, v.astype(v4.dtype), v4)
+    else:
+        k4 = jax.lax.dynamic_update_slice_in_dim(k4, k.astype(k4.dtype), pos, 1)
+        v4 = jax.lax.dynamic_update_slice_in_dim(v4, v.astype(v4.dtype), pos, 1)
+    g = spec.n_heads // hkv
+    qg = q.reshape(b, 1, g, hkv, dh)
+    scores = jnp.einsum("bqghd,bkhd->bghqk", qg, k4,
+                        preferred_element_type=jnp.float32) * (1 / np.sqrt(dh))
+    vmask = (kv_pos[None, :] <= lane_pos[:, None])[:, None, None, None, :]
+    probs = jax.nn.softmax(jnp.where(vmask, scores, -1e30), axis=-1)
+    out = jnp.einsum("bghqk,bkhd->bqghd", probs.astype(v4.dtype), v4)
+    out = linear_apply(spec.o_spec, p["wo"], out.reshape(b, 1, -1))
+    return out, k4, v4
+
+
+def _filled_cache(rng, b, s, dtype):
+    """A (B, S, H_kv * Dh) cache of random rows, so that a row left
+    untouched is told from one written."""
+    w = _KV_SPEC.n_kv_heads * _KV_SPEC.head_dim
+    return KVCache(*(jnp.asarray(rng.normal(size=(b, s, w)), dtype)
+                     for _ in range(2)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("per_lane", [True, False])
+def test_decode_row_write_matches_one_hot_reference(per_lane, dtype, rng):
+    """Decode through ``attention_apply`` writes exactly each lane's new
+    row of the lane-dense cache (the others bit for bit as they were)
+    and returns, bit for bit, the output of the pre-lane-dense decode on
+    the same rows: per lane with lanes at position 0 and at
+    ``max_seq - 1``, and at one scalar position."""
+    spec, b, s = _KV_SPEC, 4, 8
+    p = attention_init(jax.random.PRNGKey(7), spec)
+    x = jnp.asarray(rng.normal(size=(b, 1, spec.d_model)), jnp.float32)
+    cache = _filled_cache(rng, b, s, dtype)
+    pos = jnp.asarray([0, 3, s - 1, 5] if per_lane else 5, jnp.int32)
+    out, new = attention_apply(spec, p, x, cache=cache, cache_pos=pos)
+
+    heads = (b, s, spec.n_kv_heads, spec.head_dim)
+    ref_out, ref_k, ref_v = _decode_reference(
+        spec, p, x, cache.k.reshape(heads), cache.v.reshape(heads), pos)
+    np.testing.assert_array_equal(np.asarray(new.k.reshape(heads)), ref_k)
+    np.testing.assert_array_equal(np.asarray(new.v.reshape(heads)), ref_v)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+    written = np.zeros((b, s), bool)
+    written[np.arange(b), np.broadcast_to(np.asarray(pos), (b,))] = True
+    for old, now in ((cache.k, new.k), (cache.v, new.v)):
+        np.testing.assert_array_equal(np.asarray(now)[~written],
+                                      np.asarray(old)[~written])
+
+
+def test_prefill_writes_the_prompt_rows(rng):
+    """A batch-1 prefill at a scalar position writes the prompt's K/V
+    rows there, as the (B, S, H_kv, Dh) dynamic_update_slice did, and
+    nothing else."""
+    spec, s, at, n = _KV_SPEC, 16, 3, 5
+    p = attention_init(jax.random.PRNGKey(8), spec)
+    x = jnp.asarray(rng.normal(size=(1, n, spec.d_model)), jnp.float32)
+    cache = _filled_cache(rng, 1, s, jnp.bfloat16)
+    _, new = attention_apply(spec, p, x, cache=cache,
+                             cache_pos=jnp.asarray(at, jnp.int32))
+    _, k, v = _qkv_reference(spec, p, x, at + jnp.arange(n)[None, :])
+    heads = (1, s, spec.n_kv_heads, spec.head_dim)
+    for old, now, rows in ((cache.k, new.k, k), (cache.v, new.v, v)):
+        expect = jax.lax.dynamic_update_slice_in_dim(
+            old.reshape(heads), rows.astype(old.dtype), at, 1)
+        np.testing.assert_array_equal(np.asarray(now.reshape(heads)), expect)
+
+
+def test_stacked_cache_writes_only_its_layer(rng):
+    """With ``layer``, decode writes into that layer of the stacked
+    (L, B, S, H_kv * Dh) cache and reads it back: its output and layer
+    equal the single-layer call's, the other layers stay as they were."""
+    spec, n_layers, b, s = _KV_SPEC, 3, 4, 8
+    p = attention_init(jax.random.PRNGKey(9), spec)
+    x = jnp.asarray(rng.normal(size=(b, 1, spec.d_model)), jnp.float32)
+    layers = [_filled_cache(rng, b, s, jnp.bfloat16) for _ in range(n_layers)]
+    stack = KVCache(*(jnp.stack(c) for c in zip(*layers)))
+    pos = jnp.asarray([2, 0, s - 1, 6], jnp.int32)
+    out, new = attention_apply(spec, p, x, cache=stack, cache_pos=pos,
+                               layer=jnp.asarray(1, jnp.int32))
+    one_out, one = attention_apply(spec, p, x, cache=layers[1], cache_pos=pos)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one_out))
+    for l in range(n_layers):
+        for leaf, now in zip(one if l == 1 else layers[l], new):
+            np.testing.assert_array_equal(np.asarray(now[l]), np.asarray(leaf))
 
 
 def test_chunked_attention_chunk_invariance(rng):
