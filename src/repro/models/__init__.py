@@ -28,10 +28,6 @@ class ModelAPI:
     prefill: Callable
     decode_step: Callable
     init_caches: Callable
-    #: full-logits prefill — (params, batch, max_seq) -> ((B, S, V), caches).
-    #: The serve scheduler slices the last *real* token of a bucket-padded
-    #: prompt from it.  ``None`` for families without one (encdec).
-    prefill_full: Optional[Callable] = None
 
 
 _PLAN_UNSET = object()  # sentinel: "plan argument not given"
@@ -85,11 +81,11 @@ def api(cfg: ModelConfig, plan=_PLAN_UNSET, *,
     return ModelAPI(
         init_params=lambda rng: _lm.init_params(rng, cfg),
         train_loss=lambda p, b: _lm.train_loss(cfg, p, b),
-        prefill=lambda p, b, max_seq: _lm.prefill(cfg, p, b, max_seq),
+        prefill=lambda p, b, max_seq, last=None: _lm.prefill(
+            cfg, p, b, max_seq, last),
         decode_step=lambda p, t, c, pos: _lm.decode_step(cfg, p, t, c, pos),
         init_caches=lambda batch, max_seq: _lm.init_caches(
             cfg, batch, max_seq, jnp.dtype(cfg.dtype)),
-        prefill_full=lambda p, b, max_seq: _lm.prefill_full(cfg, p, b, max_seq),
     )
 
 

@@ -389,31 +389,28 @@ def train_loss(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     return ce + cfg.aux_loss_weight * aux
 
 
-def prefill_full(cfg: ModelConfig, params: dict, batch: dict, max_seq: int):
-    """Like :func:`prefill`, but returns the *full* (B, S, V) logits.
+def prefill(cfg: ModelConfig, params: dict, batch: dict, max_seq: int,
+            last=None):
+    """Run the full prompt, returning (logits (B, V), primed caches).
 
-    The serve scheduler prefills bucket-padded prompts and needs the
-    logits at the last *real* token (position ``prompt_len - 1``), not
-    the last padded one — it slices the full logits at a traced index.
-    """
-    b, s = batch["tokens"].shape
-    caches = init_caches(cfg, b, max_seq, jnp.dtype(cfg.dtype))
-    logits, caches, _ = forward(
-        cfg, params, batch["tokens"], frontend=batch.get("frontend"),
-        caches=caches, cache_pos=jnp.zeros((), jnp.int32),
-    )
-    return logits, caches
-
-
-def prefill(cfg: ModelConfig, params: dict, batch: dict, max_seq: int):
-    """Run the full prompt, returning (last-token logits, primed caches).
+    The logits are those of position ``last`` (a traced index; the last
+    position if None): the serve engine prefills bucket-padded prompts
+    and needs the last *real* token's.  Only that row's hidden state goes
+    through the head, so a long prompt never makes (B, S, V) logits.
 
     Attention families write the whole prompt's K/V into the caches in one
     dynamic_update_slice (see ``attention_apply`` s>1-with-cache path);
     state families advance their recurrent state through the scan.
     """
-    logits, caches = prefill_full(cfg, params, batch, max_seq)
-    return logits[:, -1], caches
+    b, s = batch["tokens"].shape
+    caches = init_caches(cfg, b, max_seq, jnp.dtype(cfg.dtype))
+    hidden, caches, _ = forward(
+        cfg, params, batch["tokens"], frontend=batch.get("frontend"),
+        caches=caches, cache_pos=jnp.zeros((), jnp.int32), return_hidden=True,
+    )
+    idx = hidden.shape[1] - 1 if last is None else last
+    row = jax.lax.dynamic_slice_in_dim(hidden, idx, 1, axis=1)   # (B, 1, D)
+    return apply_head(cfg, params, row)[:, 0], caches
 
 
 def decode_step(

@@ -133,10 +133,12 @@ def _chunked_attention(
     sk, hkv = k.shape[1], k.shape[2]
     g = h // hkv
     scale = 1.0 / math.sqrt(dh)
-    chunk = min(q_chunk, sq)
-    if sq % chunk:
-        chunk = sq  # fall back to a single chunk for ragged sizes
-    n_chunks = sq // chunk
+    # chunks of at most q_chunk rows, as even as they can be; a length it
+    # does not divide pads the last chunk's queries (rows cut off below)
+    n_chunks = -(-sq // min(q_chunk, sq))
+    chunk = -(-sq // n_chunks)
+    if n_chunks * chunk > sq:
+        q = jnp.pad(q, ((0, 0), (0, n_chunks * chunk - sq), (0, 0), (0, 0)))
     kv_pos = jnp.arange(sk)
 
     # shardability decides the form: the grouped einsum's score tensor
@@ -179,8 +181,10 @@ def _chunked_attention(
 
     _, outs = jax.lax.scan(body, None, (qc, jnp.arange(n_chunks)))
     if grouped:
-        return outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, h, dh)
-    return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dh)
+        outs = outs.transpose(1, 0, 2, 3, 4, 5)
+    else:
+        outs = outs.transpose(1, 0, 2, 3, 4)
+    return outs.reshape(b, n_chunks * chunk, h, dh)[:, :sq]
 
 
 def _write_rows(stack: jax.Array, rows: jax.Array, layer, pos: jax.Array) -> jax.Array:

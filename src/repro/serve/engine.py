@@ -122,14 +122,9 @@ class ServeEngine:
         self._plan_backend = plan_backend
         self._m = api(cfg)  # leaves any globally installed plan untouched
 
-        def _prefill(params, toks, last_idx):
-            logits, caches = self._m.prefill_full(params, {"tokens": toks},
-                                                  self.max_seq)
-            last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1,
-                                                keepdims=False)
-            return last, caches
-
-        self._prefill_fn = jax.jit(_prefill)
+        self._prefill_fn = jax.jit(
+            lambda p, toks, last: self._m.prefill(p, {"tokens": toks},
+                                                  self.max_seq, last))
         self._decode_fn = jax.jit(
             lambda p, t, c, pos: self._m.decode_step(p, t, c, pos),
             donate_argnums=(2,))
@@ -162,7 +157,7 @@ class ServeEngine:
                 f"padded prompt length {pp} exceeds max_seq {self.max_seq}")
         toks = np.zeros((1, pp), np.int32)
         toks[0, :p] = np.asarray(prompt, np.int32)
-        with span("serve.prefill", tokens=pp):
+        with span("serve.prefill", tokens=pp, real=p):
             with span("serve.prefill.dispatch"), plan_context(
                     self.prefill_plan, force_backend=self._plan_backend):
                 with execution_stream("prefill"):

@@ -13,6 +13,12 @@ of the tt-lm-100m ``tpu_v5e`` prefill plan at 512 tokens and decode plan
 at 4 tokens, through ``planned_tt_linear``; one train-plan backward; one
 fused path segment; the serving engine's whole bfloat16 decode step at
 256 lanes and ``max_seq`` 1536, whose KV cache must be written in place.
+For chatglm3-6b at its published widths, whose TT modes hold the primes
+107 (d_ff 13696) and 127 (vocab 65024): the head of its ``tpu_v5e``
+prefill plan at 6144 tokens and decode plan at 48, and the engine's
+whole prefill of a 6144-token prompt and decode step at 48 lanes and
+``max_seq`` 6656 under those plans, which must fit one 16-GB chip
+beside each other.
 Each Mosaic call must carry its kernel's name (``%tt_gemm.N``,
 ``%streaming_tt.N``, ``%fused_path.N``): the profiler trace keys device
 ops by these instruction names.
@@ -70,14 +76,13 @@ def plans():
     }
 
 
-@pytest.fixture(scope="module")
-def geometry():
+def _geometry(arch: str) -> dict:
     """Layer family -> (in_modes, out_modes, ranks, core shapes)."""
     from repro.configs import get_config
     from repro.dse_cli import model_dse_layers
 
     out = {}
-    for name, tn in model_dse_layers(get_config(ARCH), tokens=8):
+    for name, tn in model_dse_layers(get_config(arch), tokens=8):
         cores = [n for n in tn.nodes if n.kind != "input"]
         out.setdefault(base_name(name), (
             tuple(c.dim_of(e) for c in cores for e in c.edges
@@ -87,6 +92,11 @@ def geometry():
             tuple(c.dims[-1] for c in cores[:-1]),
             [c.dims for c in cores]))
     return out
+
+
+@pytest.fixture(scope="module")
+def geometry():
+    return _geometry(ARCH)
 
 
 def _compile(fn, *args):
@@ -100,11 +110,11 @@ def _kernels(compiled) -> set:
                           compiled.as_text()))
 
 
-def _layer_args(geom, tokens, sharding):
+def _layer_args(geom, tokens, sharding, dtype=jnp.float32):
     in_modes, out_modes, ranks, core_dims = geom
-    x = jax.ShapeDtypeStruct((tokens, math.prod(in_modes)), jnp.float32,
+    x = jax.ShapeDtypeStruct((tokens, math.prod(in_modes)), dtype,
                              sharding=sharding)
-    cores = [jax.ShapeDtypeStruct(d, jnp.float32, sharding=sharding)
+    cores = [jax.ShapeDtypeStruct(d, dtype, sharding=sharding)
              for d in core_dims]
     return in_modes, out_modes, ranks, x, cores
 
@@ -119,13 +129,9 @@ def test_tt_gemm_compiles_with_k_folds(one_chip, dataflow):
     assert _kernels(compiled) == {"tt_gemm"}
 
 
-@pytest.mark.parametrize("phase,tokens", [("prefill", 512), ("decode", 4)])
-@pytest.mark.parametrize("layer", LAYERS)
-def test_serving_plan_layer_compiles(one_chip, plans, geometry, phase,
-                                     tokens, layer):
-    lp = next(lp for lp in plans[phase].layers if lp.name == layer)
-    in_modes, out_modes, ranks, x, cores = _layer_args(
-        geometry[layer], tokens, one_chip)
+def _check_layer_compiles(lp, geom, tokens, sharding, dtype=jnp.float32):
+    in_modes, out_modes, ranks, x, cores = _layer_args(geom, tokens, sharding,
+                                                       dtype)
     compiled = _compile(
         lambda x, cs: planned_tt_linear(lp, x, cs, in_modes, out_modes,
                                         ranks, interpret=False), x, cores)
@@ -133,6 +139,49 @@ def test_serving_plan_layer_compiles(one_chip, plans, geometry, phase,
         names = _kernels(compiled)
         fused = {"fused_path"} if fusion.has_fused(lp.segments) else set()
         assert names - fused == {lp.backend} and names >= fused
+
+
+@pytest.mark.parametrize("phase,tokens", [("prefill", 512), ("decode", 4)])
+@pytest.mark.parametrize("layer", LAYERS)
+def test_serving_plan_layer_compiles(one_chip, plans, geometry, phase,
+                                     tokens, layer):
+    lp = next(lp for lp in plans[phase].layers if lp.name == layer)
+    _check_layer_compiles(lp, geometry[layer], tokens, one_chip)
+
+
+GLM = "chatglm3-6b"
+#: the serving cell's largest prompt, lanes and cache positions per lane
+GLM_PROMPT, GLM_LANES, GLM_MAX_SEQ = 6144, 48, 6656
+
+
+@pytest.fixture(scope="module")
+def glm_geometry():
+    return _geometry(GLM)
+
+
+@pytest.fixture(scope="module")
+def glm_plans():
+    from repro.dse_cli import run_dse_plan
+
+    return {phase: run_dse_plan(GLM, hw="tpu_v5e", phase=phase,
+                                tokens=tokens, serve_slots=GLM_LANES,
+                                serve_gen=128)[1]
+            for phase, tokens in (("prefill", 2048), ("decode", GLM_LANES))}
+
+
+@pytest.mark.parametrize("phase,tokens", [("prefill", GLM_PROMPT),
+                                          ("decode", GLM_LANES)])
+def test_chatglm_plan_layer_compiles_with_prime_modes(one_chip, glm_plans,
+                                                      glm_geometry, phase,
+                                                      tokens):
+    """The plans' head, whose vocab modes hold 127.  The preset's head is
+    tied and runs the embedding's chain, so the engine compile below never
+    lowers the plan's head kernels; every other planned layer it does."""
+    geom = glm_geometry["head"]
+    assert 127 in geom[0] + geom[1]
+    lp = next(lp for lp in glm_plans[phase].layers if lp.name == "head")
+    # in the type the preset serves in
+    _check_layer_compiles(lp, geom, tokens, one_chip, jnp.bfloat16)
 
 
 def test_train_plan_backward_compiles(one_chip, plans, geometry):
@@ -262,3 +311,53 @@ def test_serving_decode_step_writes_kv_in_place(topo, one_chip, monkeypatch):
                     for _, entry, typ, op, line in _instructions(hlo)
                     if entry and op == "parameter" and typ.startswith(stacked)}
     assert len(cache_params) == 2 and cache_params <= aliased
+
+
+def test_chatglm_serving_fits_one_chip(one_chip, glm_plans, glm_geometry,
+                                      monkeypatch):
+    """chatglm3-6b's engine in bfloat16 under its ``tpu_v5e`` plans: the
+    prefill of a 6144-token prompt beside the 48-lane decode cache, and
+    the decode step, each within 16 GB; the prefill's head makes one row
+    of logits, never (1, 6144, 65024); every planned projection runs on
+    its plan's kernel, the MLP's with the mode 107 of d_ff 13696."""
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.nn import plan_context
+    from repro.plan import execution_stream
+    from repro.serve import ServeEngine
+
+    for layer in ("mlp.wg", "mlp.wu", "mlp.wd"):
+        assert 107 in sum(glm_geometry[layer][:2], ())
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = get_config(GLM)
+    assert cfg.dtype == "bfloat16" and cfg.tie_embeddings
+    m = api(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(m.init_params,
+                                                  jax.random.PRNGKey(0)))
+    eng = ServeEngine(cfg, params, n_slots=GLM_LANES, max_seq=GLM_MAX_SEQ,
+                      prompt_bucket=512, prefill_plan=glm_plans["prefill"],
+                      decode_plan=glm_plans["decode"], arch=GLM)
+    caches = jax.tree.map(on_chip, jax.eval_shape(eng.fresh_caches))
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(caches))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    with plan_context(glm_plans["prefill"]), execution_stream("prefill"):
+        pre = eng._prefill_fn.lower(params, i32(1, GLM_PROMPT),
+                                    i32()).compile()
+    with plan_context(glm_plans["decode"]), execution_stream("decode"):
+        dec = eng._decode_fn.lower(params, i32(GLM_LANES, 1), caches,
+                                   i32(GLM_LANES)).compile()
+    limit = 16e9
+    ma = pre.memory_analysis()
+    assert (cache_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < limit
+    ma = dec.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes) < limit
+    assert f"{GLM_PROMPT},{cfg.vocab}]" not in pre.as_text()
+    for plan, compiled in ((glm_plans["prefill"], pre),
+                           (glm_plans["decode"], dec)):
+        want = {lp.backend for lp in plan.layers
+                if lp.name != "head" and lp.backend != "jnp"}
+        assert want and _kernels(compiled) == want

@@ -74,6 +74,33 @@ def test_decode_equals_full_forward(arch):
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("arch", ["tt-lm-100m", "chatglm3-6b"])
+@pytest.mark.parametrize("real", [5, 8, 13])
+def test_prefill_last_real_row_matches_full_forward(arch, real):
+    """The engine's prefill of a bucket-padded prompt gives the logits of
+    its last real token: the full forward pass's row at ``real - 1``."""
+    from repro.serve import ServeEngine
+
+    cfg = get_config(arch, smoke=True)
+    m = api(cfg)
+    params = m.init_params(jax.random.PRNGKey(0))
+    prompt = np.random.default_rng(real).integers(0, cfg.vocab, real)
+    eng = ServeEngine(cfg, params, n_slots=1, max_seq=32, prompt_bucket=8)
+    padded = eng.padded_len(real)
+    assert padded % 8 == 0 and padded >= real
+    toks = np.zeros((1, padded), np.int32)
+    toks[0, :real] = prompt
+    full, _, _ = lm_mod.forward(cfg, params, jnp.asarray(toks))
+    row, _ = eng.prefill_request(prompt.tolist())
+    assert row.shape == (cfg.vocab,)
+    np.testing.assert_allclose(row, np.asarray(full[0, real - 1]),
+                               rtol=1e-5, atol=1e-5)
+    last, _ = m.prefill(params, {"tokens": jnp.asarray(toks)}, 32)
+    np.testing.assert_allclose(np.asarray(last[0]),
+                               np.asarray(full[0, padded - 1]),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_dense_vs_tt_param_count():
     """TT must actually compress: full-size configs, analytic param counts."""
     from repro.models.lm import count_params

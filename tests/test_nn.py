@@ -290,6 +290,29 @@ def test_chunked_attention_chunk_invariance(rng):
         np.testing.assert_allclose(o, outs[0], rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("sq,q_chunk", [(13, 4), (15, 8), (11, 10)])
+@pytest.mark.parametrize("n_kv", [1, 2])
+def test_chunked_attention_ragged_prompt_equals_unchunked(sq, q_chunk, n_kv,
+                                                          rng):
+    """A prompt length ``q_chunk`` does not divide is still cut into
+    chunks of at most ``q_chunk`` queries (the last one padded), and
+    gives what one chunk over the whole prompt gives."""
+    from repro.nn.attention import _chunked_attention
+
+    q = jnp.asarray(rng.normal(size=(2, sq, 4, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, sq, n_kv, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, sq, n_kv, 8)), jnp.float32)
+    whole = _chunked_attention(q, k, v, True, sq)
+    chunked = _chunked_attention(q, k, v, True, q_chunk)
+    assert chunked.shape == (2, sq, 4, 8)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    scans = [e for e in jax.make_jaxpr(
+        lambda q: _chunked_attention(q, k, v, True, q_chunk))(q).eqns
+        if e.primitive.name == "scan"]
+    assert scans and scans[0].params["length"] == -(-sq // q_chunk)
+
+
 # ---------------------------------------------------------------------------
 # MoE
 # ---------------------------------------------------------------------------
