@@ -91,13 +91,18 @@ class FaultTolerantLoop:
     def run(self, state: Any, start_step: int, num_steps: int) -> tuple[Any, int]:
         """Returns (final_state, last_completed_step + 1)."""
         step = start_step
-        retries = 0
+        # retries count failures of one step, `failed`, until it succeeds:
+        # the steps replayed after a restore must not reset the count, or a
+        # poison step behind a checkpoint would be retried forever
+        failed, retries = None, 0
         with PreemptionGuard() as guard:
             while step < start_step + num_steps:
                 t0 = time.monotonic()
                 try:
                     state = self.step_fn(state, step)
                 except Exception:
+                    if step != failed:
+                        failed, retries = step, 0
                     retries += 1
                     self.recoveries += 1
                     if retries > self.max_retries:
@@ -110,7 +115,8 @@ class FaultTolerantLoop:
                             state = self.on_restore(state)
                         step = latest
                     continue
-                retries = 0
+                if step == failed:
+                    failed, retries = None, 0
                 self.straggler.record(step, time.monotonic() - t0)
                 step += 1
                 if step % self.checkpoint_every == 0 or guard.preempted:

@@ -44,6 +44,52 @@ from repro.plan.compiler import check_plan_for_config
 from repro.runtime.spans import span
 
 
+class DeviceRows:
+    """A decode step's ``(n_slots, V)`` logits, left on the device.
+
+    It reads as a host array of logits and copies only what a caller
+    asks for: ``rows[i]`` is lane *i*'s row (a device array), while
+    ``rows.copy()`` and ``np.asarray(rows)`` copy the whole batch to the
+    host (``copy`` a writable array).  ``array`` is the device array
+    itself, which :func:`greedy_ids` reduces where it lies.
+    """
+
+    __slots__ = ("array",)
+
+    def __init__(self, array: jax.Array) -> None:
+        self.array = array
+
+    @property
+    def shape(self) -> tuple:
+        return self.array.shape
+
+    def __getitem__(self, key) -> jax.Array:
+        return self.array[key]
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        if copy:
+            return np.array(self.array, dtype=dtype)
+        return np.asarray(self.array, dtype=dtype)
+
+    def copy(self) -> np.ndarray:
+        return np.array(self.array)
+
+
+@jax.jit
+def _argmax_rows(logits: jax.Array) -> jax.Array:
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def greedy_ids(logits) -> jax.Array:
+    """Greedy pick of each row of ``(n, V)`` logits (a :class:`DeviceRows`
+    or any array), on the device: ``(n,)`` int32 ids.  Ties go to the
+    first maximum and a row holding a NaN to its first NaN, as
+    ``np.argmax`` picks."""
+    if isinstance(logits, DeviceRows):
+        logits = logits.array
+    return _argmax_rows(logits)
+
+
 class ServeEngine:
     """Model + plan pair + jitted phase kernels behind the scheduler.
 
@@ -136,6 +182,7 @@ class ServeEngine:
                     b, s[:, 0], slot, axis=1),
                 big, small),
             donate_argnums=(0,))
+        self._pick_compiled = False
 
     # -- phase kernels -------------------------------------------------
 
@@ -184,10 +231,13 @@ class ServeEngine:
         """One fixed-width decode step under the decode plan.
 
         ``tok``/``pos`` are ``(n_slots,)`` host arrays (free lanes pass
-        0).  Returns ``(logits (n_slots, V) np.ndarray, new caches)``;
-        donates ``caches``.  Its spans split the call into the jitted
-        call's return (``dispatch``), the device finishing the step
-        (``wait``) and the copy of the logits to the host (``fetch``).
+        0).  Returns ``(logits (n_slots, V) DeviceRows, new caches)``;
+        donates ``caches``.  The logits stay on the device, finished:
+        the call returns once the step has run.  Its spans split the call
+        into the jitted call's return (``dispatch``) and the device
+        finishing the step (``wait``).  The first call also compiles
+        :func:`greedy_ids` for the logits as the step lays them out
+        (sharded under a mesh), so a later pick compiles nothing.
         """
         with span("serve.decode"):
             with span("serve.decode.dispatch"), plan_context(
@@ -200,8 +250,10 @@ class ServeEngine:
                         jnp.asarray(pos, jnp.int32))
             with span("serve.decode.wait"):
                 jax.block_until_ready(logits)
-            with span("serve.decode.fetch"):
-                rows = np.asarray(logits)
+            rows = DeviceRows(logits)
+            if not self._pick_compiled:
+                greedy_ids(rows)
+                self._pick_compiled = True
         return rows, caches
 
     def lowered_text(self, prompt_len: int) -> dict[str, str]:

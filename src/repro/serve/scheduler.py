@@ -10,15 +10,21 @@ concurrency capped at 1: each request decodes alone (at the same fixed
 slot width), which is the bit-exact reference the continuous mode is
 tested against.
 
-Token selection is host-side and per-request deterministic: greedy
-``np.argmax`` (first-max tie-break) at temperature 0, Gumbel-max
-sampling from a per-``(seed, rid)`` generator otherwise — a request's
-tokens never depend on which lanes its neighbours occupy.
+Token selection is per-request deterministic: greedy argmax (first-max
+tie-break) at temperature 0, Gumbel-max sampling from a per-``(seed,
+rid)`` NumPy generator otherwise — a request's tokens never depend on
+which lanes its neighbours occupy.  After a greedy decode step the pick
+runs on the device (:func:`~repro.serve.engine.greedy_ids`) and only the
+``(n_slots,)`` ids cross to the host; sampled steps and each request's
+first token (one prefill row) are picked on the host.
 
 Spans (``repro.runtime.spans``): ``serve.step`` per tick, with the
 decode call's occupied ``lanes`` and ``slots``; ``serve.admission`` per
 request (``rid``), around its prefill, first-token pick and admit;
-``serve.sample`` around each host-side token pick.
+``serve.sample`` around each token pick, with ``device`` (1 where the
+device picked) and ``lanes`` (ids picked), and after a decode step its
+child ``serve.sample.fetch``, the copy of the ids (greedy) or of the
+logits (sampled) to the host.
 """
 
 from __future__ import annotations
@@ -33,7 +39,7 @@ import numpy as np
 
 from repro.runtime.spans import span
 
-from .engine import ServeEngine
+from .engine import ServeEngine, greedy_ids
 from .request import Completion, Request
 
 SCHEDULES = ("oneshot", "continuous")
@@ -135,6 +141,7 @@ class Scheduler:
         caches = eng.fresh_caches()
         rngs: dict[int, np.random.Generator] = {}
         done: list[Completion] = []
+        greedy = self.temperature <= 0
 
         step = 0
         active = 0
@@ -160,7 +167,7 @@ class Scheduler:
                     rngs[req.rid] = rng
                     with span("serve.admission", rid=req.rid):
                         row, small = eng.prefill_request(req.prompt)
-                        with span("serve.sample"):
+                        with span("serve.sample", device=0, lanes=1):
                             first = self._select(row, rng)
                         st = _Active(req, first, t_ready, time.perf_counter(),
                                      step)
@@ -193,11 +200,19 @@ class Scheduler:
                 decode_steps += 1
                 lane_steps += active
                 step += 1
-                with span("serve.sample"):
+                with span("serve.sample", device=int(greedy), lanes=active):
+                    if greedy:
+                        ids = greedy_ids(rows)
+                        with span("serve.sample.fetch"):
+                            picks = np.asarray(ids).tolist()
+                    else:
+                        with span("serve.sample.fetch"):
+                            rows = np.asarray(rows)
                     for i, st in enumerate(slots):
                         if st is None:
                             continue
-                        nxt = self._select(rows[i], rngs[st.req.rid])
+                        nxt = (picks[i] if greedy else
+                               self._select(rows[i], rngs[st.req.rid]))
                         st.tokens.append(nxt)
                         tok[i] = nxt
                         pos[i] += 1

@@ -278,11 +278,14 @@ def test_arrival_gating_idles_until_next_request():
     assert c.admitted_step >= 5
 
 
-def test_spans_count_what_the_scheduler_did(monkeypatch):
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_spans_count_what_the_scheduler_did(monkeypatch, temperature):
     """The serving spans agree with the scheduler's own counts: one
     ``serve.decode`` per decode step, the steps' mean ``lanes`` equal to
-    the result's occupancy, one ``*.fetch`` per decode step and prefill,
-    every pick inside a step, and no name the benchmark reserves."""
+    the result's occupancy, one ``*.fetch`` per decode step and prefill
+    (after a decode step it is ``serve.sample.fetch``, the engine copies
+    no logits), every pick inside a step, each post-decode pick on the
+    device exactly when greedy, and no name the benchmark reserves."""
     import time
 
     from repro.runtime import spans
@@ -299,7 +302,7 @@ def test_spans_count_what_the_scheduler_did(monkeypatch):
             for i, (g, a) in enumerate([(4, 0.0), (1, 0.0), (3, 0.0),
                                         (2, 0.0), (3, 9.0)])]
     t0 = time.perf_counter()
-    res = _run("continuous", reqs)
+    res = _run("continuous", reqs, temperature=temperature, seed=3)
     got = spans.spans(t0, time.perf_counter())
     by_id = {s.id: s for s in got}
 
@@ -314,7 +317,151 @@ def test_spans_count_what_the_scheduler_did(monkeypatch):
     assert sum(s.name.endswith(".fetch") for s in got) == (
         calls["decode"] + calls["prefill_request"])
     assert len(named("serve.admission")) == calls["prefill_request"] == 5
+    assert not named("serve.decode.fetch")
+    assert len(named("serve.sample.fetch")) == calls["decode"]
+    after_decode = [s for s in named("serve.sample")
+                    if by_id[s.parent].name == "serve.step"]
+    assert len(after_decode) == calls["decode"]
+    for s in after_decode:
+        assert s.attrs["device"] == int(temperature <= 0)
+        assert s.attrs["lanes"] == by_id[s.parent].attrs["lanes"]
+    for s in named("serve.sample.fetch"):
+        assert s in after_decode or by_id[s.parent] in after_decode
+    first_picks = [s for s in named("serve.sample") if s not in after_decode]
+    assert len(first_picks) == calls["prefill_request"]
+    assert all(s.attrs == {"device": 0, "lanes": 1} for s in first_picks)
     for s in named("serve.sample"):
         while s.name != "serve.step":
             s = by_id[s.parent]
     assert not [s for s in got if s.name.startswith("bench.")]
+
+
+# ---------------------------------------------------------------------------
+# the greedy pick on the device
+# ---------------------------------------------------------------------------
+
+def _rows(case: str, dtype) -> np.ndarray:
+    rng = np.random.default_rng(5)
+    rows = rng.standard_normal((6, 37)).astype(np.float32)
+    if case == "tied":
+        rows[:, [3, 20, 36]] = rows.max() + 1.0   # three equal maxima
+        rows[5, :] = 0.0                          # all equal
+    elif case == "neg_inf":
+        rows[0, :] = -np.inf
+        rows[1, :30] = -np.inf
+        rows[2, 7] = np.inf
+        rows[3, [4, 9]] = np.inf
+    elif case == "nan":
+        rows[0, 11] = np.nan
+        rows[1, [2, 30]] = np.nan
+        rows[2, :] = np.nan
+        rows[3, 36] = np.nan
+        rows[4, 0] = np.nan
+    return rows.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["random", "tied", "neg_inf", "nan"])
+def test_device_greedy_matches_host_argmax(case, dtype):
+    """``greedy_ids`` picks what ``np.argmax`` picks, row by row: the
+    first maximum on ties, the first NaN in a row that holds one."""
+    import jax.numpy as jnp
+
+    from repro.serve.engine import greedy_ids
+
+    rows = _rows(case, jnp.dtype(dtype))
+    got = greedy_ids(jnp.asarray(rows))
+    assert got.dtype == jnp.int32 and got.shape == (rows.shape[0],)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.argmax(np.asarray(rows), -1))
+
+
+def test_first_decode_compiles_the_greedy_pick(caplog):
+    """The engine's first decode step compiles the greedy pick for the
+    logits it returns: no later pick compiles."""
+    import logging
+
+    from repro.serve.engine import greedy_ids
+
+    cfg, params = _model()
+    n = N_SLOTS + 3             # a width no other test of this file serves
+    eng = ServeEngine(cfg, params, n_slots=n, max_seq=MAX_SEQ,
+                      prompt_bucket=BUCKET)
+    with jax.log_compiles(True), caplog.at_level(logging.DEBUG):
+        rows, caches = eng.decode(np.ones(n, np.int64),
+                                  np.zeros(n, np.int64), eng.fresh_caches())
+        first = [r.getMessage() for r in caplog.records]
+        caplog.clear()
+        rows, _ = eng.decode(np.ones(n, np.int64), np.ones(n, np.int64),
+                             caches)
+        np.asarray(greedy_ids(rows))
+        later = [r.getMessage() for r in caplog.records]
+    assert [m for m in first if "Compiling" in m and "_argmax_rows" in m]
+    assert not [m for m in later if "Compiling" in m]
+
+
+def test_decode_rows_read_as_a_host_array():
+    """A decode step's logits stay on the device and read as a host
+    array: ``copy()`` is a writable host copy, ``np.asarray`` the same
+    values, ``rows[i]`` lane *i*'s row, and the greedy pick takes the
+    rows or either copy alike."""
+    from repro.serve.engine import DeviceRows, greedy_ids
+
+    eng = _engine()
+    rows, _ = eng.decode(np.arange(N_SLOTS), np.zeros(N_SLOTS, np.int64),
+                         eng.fresh_caches())
+    assert isinstance(rows, DeviceRows)
+    assert isinstance(rows.array, jax.Array)
+    host = rows.copy()
+    assert isinstance(host, np.ndarray) and host.flags.writeable
+    assert host.shape == rows.shape == (N_SLOTS, eng.cfg.vocab)
+    assert host.dtype == rows.array.dtype
+    np.testing.assert_array_equal(np.asarray(rows), host)
+    for i in range(N_SLOTS):
+        np.testing.assert_array_equal(np.asarray(rows[i]), host[i])
+    want = np.argmax(host, -1)
+    for r in (rows, host, rows.array):
+        np.testing.assert_array_equal(np.asarray(greedy_ids(r)), want)
+    host[:, 0] = host.max() + 1.0        # the copy is the caller's own
+    assert np.asarray(greedy_ids(rows)).tolist() == want.tolist()
+    assert not np.asarray(greedy_ids(host)).any()
+
+
+class _Interposer:
+    """An engine seen through a wrapper that forwards only the names the
+    scheduler may use and reads each decode row by lane (copying it and
+    taking its argmax), as a timing wrapper between the two does."""
+
+    def __init__(self, engine: ServeEngine) -> None:
+        self._e = engine
+        self.n_slots, self.max_seq = engine.n_slots, engine.max_seq
+        self.padded_len = engine.padded_len
+        self.fresh_caches = engine.fresh_caches
+        self.decodes = 0
+
+    def prefill_request(self, prompt):
+        return self._e.prefill_request(prompt)
+
+    def admit(self, caches, small, slot):
+        return self._e.admit(caches, small, slot)
+
+    def decode(self, tok, pos, caches):
+        rows, caches = self._e.decode(tok, pos, caches)
+        self.decodes += 1
+        for i in range(self.n_slots):
+            assert 0 <= int(np.argmax(rows[i].copy())) < rows.shape[1]
+        return rows, caches
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_scheduler_through_interposed_engine_matches_direct(temperature):
+    """The scheduler reaches the device pick through nothing but the
+    engine's seven serving names: served through the wrapper, every
+    request's tokens equal those of a direct run."""
+    reqs = _requests([5, 2, 0, 1, 3, 1, 4, 1, 2, 2, 0, 4])
+    wrapped = _Interposer(_engine())
+    got = _run("continuous", reqs, temperature=temperature, seed=11,
+               engine=wrapped)
+    want = _run("continuous", reqs, temperature=temperature, seed=11)
+    assert got.tokens_by_rid() == want.tokens_by_rid()
+    assert wrapped.decodes > 0

@@ -191,12 +191,21 @@ def test_fault_tolerant_loop_recovers_from_failure():
         assert int(state["x"]) == 10  # deterministic recovery, no lost steps
 
 
-def test_fault_tolerant_loop_poison_step_aborts():
+@pytest.mark.parametrize("landed", [False, True])
+def test_fault_tolerant_loop_poison_step_aborts(landed):
+    """A step that always fails is tried 1 + max_retries times, also when
+    the step-2 checkpoint has landed and each restore replays step 2."""
     with tempfile.TemporaryDirectory() as d:
         mgr = CheckpointManager(d)
+        calls = [0]
 
         def step_fn(state, step):
             if step == 3:
+                calls[0] += 1
+                if landed:
+                    mgr.wait()
+                if calls[0] > 3:
+                    pytest.fail("poison step retried past max_retries_per_step")
                 raise RuntimeError("always fails")
             return state
 
@@ -204,6 +213,7 @@ def test_fault_tolerant_loop_poison_step_aborts():
                                  max_retries_per_step=2)
         with pytest.raises(RuntimeError):
             loop.run({"x": jnp.asarray(0)}, 0, 10)
+        assert calls[0] == 3
 
 
 def test_straggler_monitor():
