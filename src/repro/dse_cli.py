@@ -10,14 +10,18 @@ Runs the paper's Algorithm 1 for any named config in ``repro.configs``
     PYTHONPATH=src python -m repro.dse --arch vit_ti4/cifar10 \
         --hw-search budget --emit-plan plan.json   # joint arch co-search (v3)
 
-Pipeline: enumerate the model's tensorized projections as per-layer
-tensor networks -> MAC-guided top-K path search (memoised across the
-model's repeated layers) -> batched cost-table build
-(``repro.core.cost_table``) -> hierarchical global argmin.  Emits a JSON
-report (schema documented in the README) with the winning strategy,
-per-layer (path, partitioning, dataflow) choices and stage timings;
-``examples/dse_explore.py`` and ``benchmarks/table2_dse_choices.py``
-consume the same report via ``run_dse``.
+A search is described once, by a :class:`SearchSpec` whose fields are
+the CLI's search flags; building it checks every compatibility rule.
+``run_dse`` / ``run_dse_plan`` (the library entry points) and ``main``
+all build one and hand it to the same pipeline: enumerate the model's
+tensorized projections as per-layer tensor networks -> MAC-guided top-K
+path search (memoised across the model's repeated layers) -> batched
+cost-table build (``repro.core.cost_table``) -> hierarchical global
+argmin.  Emits a JSON report (schema documented in the README) with the
+winning strategy, per-layer (path, partitioning, dataflow) choices and
+stage timings; ``examples/dse_explore.py`` and
+``benchmarks/table2_dse_choices.py`` consume the same report via
+``run_dse``.
 """
 
 from __future__ import annotations
@@ -38,10 +42,9 @@ from repro.core import (
     find_topk_paths,
     global_search,
 )
+from repro.core.cost_table import shard_streamed_tokens
 from repro.core.cost_table import table_cells as _table_cells
-from repro.core.dse import build_cost_table
 from repro.hw import ArchSpace, get_target, list_targets
-from repro.hw import HW_TARGETS  # noqa: F401  (re-export; registry is repro.hw)
 from repro.models.config import ModelConfig
 from repro.nn.linear import LinearSpec
 from repro.rank import RANK_SEARCH_MODES
@@ -205,34 +208,12 @@ def model_layer_paths(
 
 
 # ---------------------------------------------------------------------------
-# end-to-end run
+# the search problem
 # ---------------------------------------------------------------------------
 
-def run_dse(
-    arch: str,
-    hw: str = "fpga_vu9p",
-    top_k: int = 4,
-    objective: str = "latency",
-    tokens: Optional[int] = None,
-    smoke: bool = False,
-    engine: str = "vectorized",
-    mode: str = "infer",
-    hw_search: str = "off",
-    hw_budget: Optional[int] = None,
-    tune: str = "off",
-    tune_cache: Optional[str] = None,
-    serve_gen: int = 128,
-    serve_slots: int = 8,
-    decode_tokens: Optional[int] = None,
-    search: str = "exhaustive",
-    search_budget: Optional[int] = None,
-    search_seed: int = 0,
-    rank_search: str = "off",
-    accuracy_budget: Optional[float] = None,
-    shards: Optional[int] = None,
-    fused_cost: bool = False,
-) -> dict:
-    """Run Algorithm 1 end-to-end; returns the JSON-serializable report.
+@dataclasses.dataclass(frozen=True)
+class SearchSpec:
+    """One DSE search problem, checked once when it is built.
 
     ``tokens`` is the streamed token count per projection (default 1024);
     for vision archs it is the im2col batch size (default 1).
@@ -242,8 +223,16 @@ def run_dse(
     path choices); ``"both"`` runs both searches and nests their reports
     under ``"infer"`` / ``"train"`` with the layers whose choices diverge.
 
+    ``objective="throughput"`` optimizes serving tokens/s under a
+    sustained continuous-batching load: each layer's cost becomes
+    ``T_prefill(tokens) + (serve_gen / serve_slots) * T_decode``, where
+    the decode table replays the same candidate paths at
+    ``decode_tokens`` streamed tokens (default ``serve_slots`` — one
+    fixed-width decode step).  The report gains a ``serving`` section
+    with the phase decomposition of the winning configuration.
+
     ``hw_search="budget"`` turns on the joint architecture co-search: the
-    ``--hw`` target becomes the *base* of a feasible architecture space
+    ``hw`` target becomes the *base* of a feasible architecture space
     (``repro.hw.ArchSpace``, PE shape x SRAM split x bandwidth tier under
     ``hw_budget`` MACs — default: the base target's own PE count), every
     candidate is evaluated through the hw-batched cost-table engine, and
@@ -253,14 +242,15 @@ def run_dse(
     model's dominant GEMM shapes are measured per dataflow on this
     machine (``"cache"`` = only cache misses, ``"measure"`` = re-measure)
     and the resulting calibration rescales the analytic table before the
-    argmin.  The report gains a ``tune`` section; with ``--emit-plan``
-    the plan additionally carries measured kernel tilings.
+    argmin.  The report gains a ``tune`` section; an emitted plan
+    additionally carries measured kernel tilings.  Train mode keeps an
+    analytic search and takes only the measured tilings.
 
     ``search="guided"`` replaces the exhaustive sweep with the budgeted
     explorer of ``repro.search`` (``search_budget`` cost-model
-    evaluations, ``search_seed`` for the proposal stream); the report's
-    ``search`` section records the provenance (evals, found-at-eval,
-    the exhaustive count it avoided).
+    evaluations, ``search_seed`` for the proposal stream) over the same
+    cost tables; the report's ``search`` section records the provenance
+    (evals, found-at-eval, the exhaustive count it avoided).
 
     ``rank_search="budget"`` adds the decomposition itself as a fourth
     searched axis (``repro.rank``): every TT factorization candidate is
@@ -272,30 +262,158 @@ def run_dse(
     ``shards=N`` searches at per-device shard shapes (``tokens / N``)
     so the emitted tilings match what the shard_map executor streams per
     device on an N-way data-parallel mesh; defaults to an installed
-    ``ShardingRules`` mesh, else unsharded.
+    ``ShardingRules`` mesh, else unsharded.  The report gains a
+    ``sharding`` section.
+
+    ``fused_cost`` re-costs fuseable (1,1)-partitioned paths with the
+    fused-segment accounting (``_apply_fused_cost``); the report gains a
+    ``fused_cost`` section.
     """
-    if mode == "both":
-        _check_train_compatible(objective, engine)  # fail before any search
-        _check_tune_compatible(tune, "both", objective, hw_search)
-        _check_rank_compatible(rank_search, "both", objective, engine, tune)
-        _check_fused_compatible(fused_cost, "both", objective, engine,
-                                hw_search, search, rank_search)
-        infer, _, _, _, _, _ = _run_dse(
-            arch, hw, top_k, objective, tokens, smoke, engine, "infer",
-            hw_search, hw_budget, search=search, search_budget=search_budget,
-            search_seed=search_seed, shards=shards)
-        train, _, _, _, _, _ = _run_dse(
-            arch, hw, top_k, objective, tokens, smoke, engine, "train",
-            hw_search, hw_budget, search=search, search_budget=search_budget,
-            search_seed=search_seed, shards=shards)
-        return _both_report(infer, train)
-    report, _, _, _, tuner, _ = _run_dse(
-        arch, hw, top_k, objective, tokens, smoke, engine, mode, hw_search,
-        hw_budget, tune, tune_cache, serve_gen, serve_slots, decode_tokens,
-        search, search_budget, search_seed, rank_search, accuracy_budget,
-        shards, fused_cost)
+
+    arch: str
+    hw: str = "fpga_vu9p"
+    top_k: int = 4
+    objective: str = "latency"
+    tokens: Optional[int] = None
+    smoke: bool = False
+    mode: str = "infer"
+    hw_search: str = "off"
+    hw_budget: Optional[int] = None
+    tune: str = "off"
+    tune_cache: Optional[str] = None
+    serve_gen: int = 128
+    serve_slots: int = 8
+    decode_tokens: Optional[int] = None
+    search: str = "exhaustive"
+    search_budget: Optional[int] = None
+    search_seed: int = 0
+    rank_search: str = "off"
+    accuracy_budget: Optional[float] = None
+    shards: Optional[int] = None
+    fused_cost: bool = False
+
+    def __post_init__(self) -> None:
+        """Check every compatibility rule, in order.  Under ``mode="both"``
+        the train leg's rules apply, and the measured calibration, the
+        rank search and the fused tables (each defined for one leg only)
+        are refused.  Most other refusals are open items of ROADMAP.md."""
+        get_target(self.hw)  # KeyError naming the registered targets
+        obj, mode, tuned = self.objective, self.mode, self.tune != "off"
+        fused, ranked = self.fused_cost, self.rank_search != "off"
+        rules = [  # (broken, exception type, message)
+            (obj not in OBJECTIVES, KeyError,
+             f"unknown objective {obj!r}; have {OBJECTIVES}"),
+            (mode not in MODES, KeyError,
+             f"unknown mode {mode!r}; have {MODES}"),
+            (obj == "throughput" and self.arch in VISION_ARCHS, ValueError,
+             "objective=throughput models the serving prefill/decode "
+             "split of causal LMs; vision archs have no decode phase"),
+            (obj == "throughput" and min(self.serve_gen, self.serve_slots) < 1,
+             ValueError, "serve_gen and serve_slots must be >= 1"),
+            (mode != "infer" and obj != "latency", ValueError,
+             "--mode train optimizes the train-latency objective; "
+             f"--objective {obj} is an inference objective"),
+            (self.hw_search not in HW_SEARCH_MODES, KeyError,
+             f"unknown hw_search {self.hw_search!r}; have {HW_SEARCH_MODES}"),
+            (self.hw_search != "off" and obj != "latency", ValueError,
+             "--hw-search optimizes the latency (or train-latency) "
+             f"objective; --objective {obj} is fixed-architecture only"),
+            (self.search not in SEARCH_MODES, KeyError,
+             f"unknown search {self.search!r}; have {SEARCH_MODES}"),
+            (self.search == "guided" and obj != "latency", ValueError,
+             "--search guided explores the latency (or train-latency) "
+             f"objective; the pre-combined --objective {obj} table stays "
+             "on the exhaustive path"),
+            (self.search_budget is not None and self.search != "guided",
+             ValueError, "search_budget requires search='guided'"),
+            (self.tune not in TUNE_MODES, KeyError,
+             f"unknown tune mode {self.tune!r}; have {TUNE_MODES}"),
+            (tuned and mode == "both", ValueError,
+             "--tune with --mode both is ambiguous (the infer leg searches "
+             "a calibrated table, the train leg an analytic one); run the "
+             "modes separately"),
+            (tuned and obj not in ("latency", "throughput"), ValueError,
+             "--tune calibrates the latency and throughput objectives; "
+             f"--objective {obj} is analytic-only for now"),
+            (fused and mode != "infer", ValueError,
+             "--fused-cost overrides the inference cost tables; "
+             f"--mode {mode} is spill-always only for now"),
+            (fused and obj not in ("latency", "edp"), ValueError,
+             "--fused-cost composes with the latency and EDP objectives; "
+             f"--objective {obj} would need fused per-phase tables "
+             "(open item)"),
+            (fused and self.hw_search != "off", ValueError,
+             "--fused-cost with --hw-search would need fused hw-batched "
+             "tables per candidate (open item)"),
+            (fused and self.search != "exhaustive", ValueError,
+             "--fused-cost requires --search exhaustive (the guided "
+             "explorer rebuilds its own tables)"),
+            (fused and ranked, ValueError,
+             "--fused-cost with --rank-search would need per-candidate "
+             "segmentation (open item)"),
+            (self.shards is not None and self.shards < 1, ValueError,
+             f"shards must be >= 1, got {self.shards}"),
+            (self.rank_search not in RANK_SEARCH_MODES, KeyError,
+             f"unknown rank_search {self.rank_search!r}; have "
+             f"{RANK_SEARCH_MODES}"),
+            (ranked and mode != "infer", ValueError,
+             "--rank-search explores the inference latency/accuracy "
+             f"frontier; --mode {mode} is frozen-decomposition only"),
+            (ranked and obj != "latency", ValueError,
+             "--rank-search trades latency against the accuracy proxy; "
+             f"--objective {obj} is frozen-decomposition only"),
+            (ranked and tuned, ValueError,
+             "--rank-search is analytic: the measured calibration would "
+             "need per-candidate GEMM coverage (open item)"),
+            (ranked and _shard_context(self.shards) is not None, ValueError,
+             "--rank-search re-derives networks per decomposition "
+             "candidate; composing it with the --shards context is not "
+             "supported yet"),
+            (self.accuracy_budget is not None and not ranked, ValueError,
+             "accuracy_budget requires rank_search='budget'"),
+            (self.hw_budget is not None and self.hw_search == "off",
+             ValueError, "hw_budget requires hw_search='budget'"),
+            (self.tune_cache is not None and not tuned, ValueError,
+             "tune_cache requires tune='cache' or 'measure'"),
+        ]
+        for broken, exc, message in rules:
+            if broken:
+                raise exc(message)
+
+
+def _spec(args: tuple, kw: dict) -> SearchSpec:
+    """``SearchSpec(*args, **kw)``, or ``replace(args[0], **kw)`` when
+    ``args[0]`` is already a spec."""
+    if args and isinstance(args[0], SearchSpec):
+        return dataclasses.replace(*args, **kw)
+    return SearchSpec(*args, **kw)
+
+
+# ---------------------------------------------------------------------------
+# end-to-end run
+# ---------------------------------------------------------------------------
+
+def run_dse(*args, **kw) -> dict:
+    """Run Algorithm 1 end-to-end; returns the JSON-serializable report.
+
+    The arguments are :class:`SearchSpec`'s fields (``arch`` first), or
+    a built spec followed by the fields to replace.
+    """
+    report, _, _, _, tuner, _ = _search(_spec(args, kw))
     _save_tuner(tuner)
     return report
+
+
+def _search(spec: SearchSpec):
+    """Run ``spec``; returns ``(report, named_layers, DSEResult, hw_cfg,
+    tuner, calibration)``.  Under ``mode="both"`` the report nests both
+    legs and the rest belongs to the train leg — plans are compiled from
+    it."""
+    if spec.mode == "both":
+        infer = _search(dataclasses.replace(spec, mode="infer"))[0]
+        train, *rest = _search(dataclasses.replace(spec, mode="train"))
+        return (_both_report(infer, train), *rest)
+    return (_run_dse if spec.rank_search == "off" else _run_rank_dse)(spec)
 
 
 def _both_report(infer: dict, train: dict) -> dict:
@@ -341,38 +459,14 @@ def _both_report(infer: dict, train: dict) -> dict:
     return out
 
 
-def run_dse_plan(
-    arch: str,
-    hw: str = "fpga_vu9p",
-    top_k: int = 4,
-    objective: str = "latency",
-    tokens: Optional[int] = None,
-    smoke: bool = False,
-    engine: str = "vectorized",
-    plan_backend: str = "auto",
-    mode: str = "infer",
-    hw_search: str = "off",
-    hw_budget: Optional[int] = None,
-    tune: str = "off",
-    tune_cache: Optional[str] = None,
-    serve_gen: int = 128,
-    serve_slots: int = 8,
-    decode_tokens: Optional[int] = None,
-    phase: str = "",
-    search: str = "exhaustive",
-    search_budget: Optional[int] = None,
-    search_seed: int = 0,
-    rank_search: str = "off",
-    accuracy_budget: Optional[float] = None,
-    shards: Optional[int] = None,
-    fused_cost: bool = False,
-):
+def run_dse_plan(*args, plan_backend: str = "auto", phase: str = "", **kw):
     """Run the DSE and compile its result into an ExecutionPlan.
 
-    ``phase`` stamps the emitted plan as one half of a serving plan pair
-    (``"prefill"`` / ``"decode"``); the serve driver then refuses to
-    install it as the other half.  ``--emit-plan-pair`` runs this twice
-    — once per phase, each at its own token count.
+    The arguments are :class:`SearchSpec`'s fields, as for
+    :func:`run_dse`.  ``phase`` stamps the emitted plan as one half of a
+    serving plan pair (``"prefill"`` / ``"decode"``); the serve driver
+    then refuses to install it as the other half.  ``--emit-plan-pair``
+    runs this twice — once per phase, each at its own token count.
 
     Returns ``(report, plan)`` — the same report as :func:`run_dse` plus
     the installable plan (``repro.plan.ExecutionPlan``).  This is the
@@ -394,33 +488,16 @@ def run_dse_plan(
     """
     from repro.plan import BACKENDS, compile_plan
 
-    with span("dse.plan_search", phase=phase, mode=mode):
-        if plan_backend != "auto" and plan_backend not in BACKENDS:
-            raise ValueError(
-                f"unknown plan backend {plan_backend!r}; have "
-                f"{('auto',) + BACKENDS}")
-        if mode not in MODES:
-            raise KeyError(f"unknown mode {mode!r}; have {MODES}")
-        infer_report = None
-        if mode == "both":
-            _check_train_compatible(objective, engine)  # fail before any search
-            _check_tune_compatible(tune, "both", objective, hw_search)
-            _check_rank_compatible(rank_search, "both", objective, engine, tune)
-            _check_fused_compatible(fused_cost, "both", objective, engine,
-                                    hw_search, search, rank_search)
-            infer_report, _, _, _, _, _ = _run_dse(
-                arch, hw, top_k, objective, tokens, smoke, engine, "infer",
-                hw_search, hw_budget, search=search, search_budget=search_budget,
-                search_seed=search_seed, shards=shards)
-        plan_mode = "train" if mode in ("train", "both") else "infer"
-        report, named, res, plan_hw, tuner, calibration = _run_dse(
-            arch, hw, top_k, objective, tokens, smoke, engine, plan_mode,
-            hw_search, hw_budget, tune, tune_cache,
-            serve_gen, serve_slots, decode_tokens,
-            search, search_budget, search_seed, rank_search, accuracy_budget,
-            shards, fused_cost)
+    if plan_backend != "auto" and plan_backend not in BACKENDS:
+        raise ValueError(
+            f"unknown plan backend {plan_backend!r}; have "
+            f"{('auto',) + BACKENDS}")
+    spec = _spec(args, kw)
+    with span("dse.plan_search", phase=phase, mode=spec.mode):
+        report, named, res, plan_hw, tuner, calibration = _search(spec)
+        leg = report["train"] if spec.mode == "both" else report
         factorizations = None
-        rank_report = report.get("rank_search")
+        rank_report = leg.get("rank_search")
         if rank_report is not None and rank_report.get("plan_embeddable"):
             from repro.plan import Factorization
 
@@ -433,7 +510,7 @@ def run_dse_plan(
                 for f in rank_report["chosen"]["families"]
             }
         plan_sharding = None
-        shard_rep = report.get("sharding")
+        shard_rep = leg["sharding"]
         if shard_rep is not None:
             from repro.plan import PlanSharding
 
@@ -443,11 +520,11 @@ def run_dse_plan(
                 tokens_per_shard=int(shard_rep["tokens_per_shard"]))
         plan = compile_plan(
             named, res, plan_hw,
-            arch=arch,
-            objective=report["objective"],
-            tokens=report["tokens"],
+            arch=spec.arch,
+            objective=leg["objective"],
+            tokens=leg["tokens"],
             backend=plan_backend,
-            total_latency_s=report["total_latency_s"],
+            total_latency_s=leg["total_latency_s"],
             tilings="heuristic" if tuner is None else "measured",
             tuner=tuner,
             phase=phase,
@@ -455,7 +532,7 @@ def run_dse_plan(
             sharding=plan_sharding,
         )
         if tuner is not None:
-            if calibration is not None and report["objective"] == "latency":
+            if calibration is not None and leg["objective"] == "latency":
                 # the argmin ran over the calibrated table, so each choice's
                 # latency landed in measured-rescaled units; divide the scale
                 # back out so the plan's per-layer provenance stays in the
@@ -486,12 +563,10 @@ def run_dse_plan(
                     plan, layers=tuple(_unscale(lp) for lp in plan.layers))
             # compilation may have measured additional (per-family) sweeps;
             # refresh the report's counters and persist the cache
-            report["tune"]["n_measured"] = tuner.n_measured
-            report["tune"]["n_cache_hits"] = tuner.n_cache_hits
-            report["tune"]["n_cache_entries"] = len(tuner.cache)
+            leg["tune"]["n_measured"] = tuner.n_measured
+            leg["tune"]["n_cache_hits"] = tuner.n_cache_hits
+            leg["tune"]["n_cache_entries"] = len(tuner.cache)
             _save_tuner(tuner)
-        if mode == "both":
-            report = _both_report(infer_report, report)
         return report, plan
 
 
@@ -531,129 +606,6 @@ def _hw_search_report(space: ArchSpace, res, base_cfg,
     }
 
 
-def _check_train_compatible(objective: str, engine: str) -> None:
-    """Reject mode/objective/engine combinations the train search cannot
-    honour — called up front so ``--mode both`` fails before the (valid)
-    inference leg burns any search time."""
-    if objective != "latency":
-        raise ValueError(
-            "--mode train optimizes the train-latency objective; "
-            f"--objective {objective} is an inference objective")
-    if engine == "scalar":
-        raise ValueError("--mode train requires the vectorized engine")
-
-
-def _check_tune_compatible(tune: str, mode: str, objective: str,
-                           hw_search: str) -> None:
-    """Reject combinations the measured-latency loop cannot honour yet.
-
-    The calibration rescales the inference latency table — per candidate
-    under an architecture co-search (ROADMAP gap c, closed).  Train mode
-    is allowed since the tiling lift (ROADMAP gap b): the train *search*
-    stays analytic, but train-mode plans serve measured forward tilings
-    and any backward-op tilings already in the cache.  The throughput
-    objective is calibrated per phase (ROADMAP serving follow-on (a),
-    closed): the correction rescales the prefill and decode tables at
-    their own GEMM shapes inside ``combine_phase_tables``.  Composing
-    the calibration with the fwd+bwd decomposition or the EDP objective
-    are still open items (ROADMAP.md)."""
-    if tune == "off":
-        return
-    if tune not in TUNE_MODES:
-        raise KeyError(f"unknown tune mode {tune!r}; have {TUNE_MODES}")
-    if mode == "both":
-        raise ValueError(
-            "--tune with --mode both is ambiguous (the infer leg searches "
-            "a calibrated table, the train leg an analytic one); run the "
-            "modes separately")
-    if objective not in ("latency", "throughput"):
-        raise ValueError(
-            "--tune calibrates the latency and throughput objectives; "
-            f"--objective {objective} is analytic-only for now")
-
-
-def _check_rank_compatible(rank_search: str, mode: str, objective: str,
-                           engine: str, tune: str) -> None:
-    """Reject combinations the rank search cannot honour.
-
-    The decomposition axis re-derives every layer's tensor network per
-    candidate, so it composes with the path/partitioning/dataflow axes,
-    the architecture co-search, and the guided explorer — but not (yet)
-    with the train decomposition, non-latency objectives, the scalar
-    engine, or the measured calibration (whose cache keys would have to
-    span every candidate's GEMM shapes)."""
-    if rank_search == "off":
-        return
-    if rank_search not in RANK_SEARCH_MODES:
-        raise KeyError(
-            f"unknown rank_search {rank_search!r}; have {RANK_SEARCH_MODES}")
-    if mode != "infer":
-        raise ValueError(
-            "--rank-search explores the inference latency/accuracy "
-            f"frontier; --mode {mode} is frozen-decomposition only")
-    if objective != "latency":
-        raise ValueError(
-            "--rank-search trades latency against the accuracy proxy; "
-            f"--objective {objective} is frozen-decomposition only")
-    if engine == "scalar":
-        raise ValueError("--rank-search requires the vectorized engine")
-    if tune != "off":
-        raise ValueError(
-            "--rank-search is analytic: the measured calibration would "
-            "need per-candidate GEMM coverage (open item)")
-
-
-def _check_fused_compatible(fused_cost: bool, mode: str, objective: str,
-                            engine: str, hw_search: str, search: str,
-                            rank_search: str) -> None:
-    """Reject combinations the fusion-aware cost tables cannot honour.
-
-    ``--fused-cost`` overrides the (1,1)-partitioning cells of the
-    inference seconds/traffic tables with the fused-segment accounting
-    (``repro.core.cost_table.fused_cost_tables``), so it composes with
-    the latency and EDP objectives on a fixed target under the
-    exhaustive vectorized search.  The throughput objective would need
-    both phase tables fused, the architecture co-search would need the
-    per-candidate hw-batched engine to know about segments, the guided
-    explorer reads raw tables rather than the provided objective table,
-    and the rank search re-derives networks per candidate — all open
-    items (ROADMAP.md)."""
-    if not fused_cost:
-        return
-    if mode != "infer":
-        raise ValueError(
-            "--fused-cost overrides the inference cost tables; "
-            f"--mode {mode} is spill-always only for now")
-    if objective not in ("latency", "edp"):
-        raise ValueError(
-            "--fused-cost composes with the latency and EDP objectives; "
-            f"--objective {objective} would need fused per-phase tables "
-            "(open item)")
-    if engine == "scalar":
-        raise ValueError("--fused-cost requires the vectorized engine")
-    if hw_search != "off":
-        raise ValueError(
-            "--fused-cost with --hw-search would need fused hw-batched "
-            "tables per candidate (open item)")
-    if search != "exhaustive":
-        raise ValueError(
-            "--fused-cost requires --search exhaustive (the guided "
-            "explorer rebuilds its own tables)")
-    if rank_search != "off":
-        raise ValueError(
-            "--fused-cost with --rank-search would need per-candidate "
-            "segmentation (open item)")
-
-
-def _make_tuner(tune: str, tune_cache: Optional[str], shards: int = 1):
-    """Build the Autotuner over the persistent cache (lazy import)."""
-    from repro.tune import Autotuner, DEFAULT_CACHE_PATH, TuningCache
-
-    path = tune_cache or DEFAULT_CACHE_PATH
-    return Autotuner(TuningCache.load_or_empty(path), tune, cache_path=path,
-                     shards=shards)
-
-
 def _shard_context(shards: Optional[int]) -> Optional[dict]:
     """Resolve the per-device shard context for the search, or ``None``.
 
@@ -665,8 +617,6 @@ def _shard_context(shards: Optional[int]) -> Optional[dict]:
     block the shard_map executor streams (``repro.plan.sharded``).
     """
     if shards is not None:
-        if shards < 1:
-            raise ValueError(f"shards must be >= 1, got {shards}")
         if shards == 1:
             return None
         return {"n_shards": int(shards), "axes": [["data", int(shards)]]}
@@ -765,473 +715,246 @@ def _apply_fused_cost(tables, named, layer_paths, hw_cfg, tokens, tuner):
     return tables, report
 
 
-def _run_dse(
-    arch: str,
-    hw: str = "fpga_vu9p",
-    top_k: int = 4,
-    objective: str = "latency",
-    tokens: Optional[int] = None,
-    smoke: bool = False,
-    engine: str = "vectorized",
-    mode: str = "infer",
-    hw_search: str = "off",
-    hw_budget: Optional[int] = None,
-    tune: str = "off",
-    tune_cache: Optional[str] = None,
-    serve_gen: int = 128,
-    serve_slots: int = 8,
-    decode_tokens: Optional[int] = None,
-    search: str = "exhaustive",
-    search_budget: Optional[int] = None,
-    search_seed: int = 0,
-    rank_search: str = "off",
-    accuracy_budget: Optional[float] = None,
-    shards: Optional[int] = None,
-    fused_cost: bool = False,
-):
-    """Shared pipeline; returns (report, named_layers, DSEResult, hw_cfg,
-    tuner, calibration).
+def _run_dse(spec: SearchSpec):
+    """The frozen-decomposition pipeline of one ``infer`` or ``train``
+    leg; returns (report, named_layers, DSEResult, hw_cfg, tuner,
+    calibration).
 
-    ``shards`` activates the per-device shard context
-    (:func:`_shard_context`): every problem network — and therefore
-    every cost table, tiling, and tuning sweep — is built at the
-    per-shard token count the shard_map executor streams, and the report
-    gains a ``sharding`` section for plan provenance.
-
-    The returned hardware config is the one the plan should compile for:
+    Under a shard context (:func:`_shard_context`) every problem network
+    is built at the per-shard token count.  The returned hardware config is the one the plan should compile for:
     the co-searched winner under ``hw_search``, else the fixed target.
     The tuner is the live ``repro.tune.Autotuner`` when ``tune`` is on
     (``run_dse_plan`` hands it to the plan compiler for measured
     tilings, then persists its cache), else ``None``; the calibration is
     the fitted ``repro.tune.CostCorrection`` the search ran under
     (``run_dse_plan`` divides its scales back out of plan latencies).
-
-    ``search="guided"`` routes the argmin through
-    ``repro.search.guided_search`` — a budgeted explorer over the same
-    cost tables (latency / train-latency objectives; EDP and throughput
-    tables are pre-combined and stay exhaustive).  With ``hw_search``
-    it replaces the exhaustive outer architecture loop; without it the
-    single target is refined exactly (same result, guided provenance).
-
-    ``objective="throughput"`` optimizes serving tokens/s under a
-    sustained continuous-batching load: each layer's cost becomes
-    ``T_prefill(tokens) + (serve_gen / serve_slots) * T_decode``, where
-    the decode table replays the same candidate paths at
-    ``decode_tokens`` streamed tokens (default ``serve_slots`` — one
-    fixed-width decode step).  The report gains a ``serving`` section
-    with the phase decomposition of the winning configuration.
     """
-    hw_cfg = get_target(hw)
-    if objective not in OBJECTIVES:
-        raise KeyError(f"unknown objective {objective!r}; have {OBJECTIVES}")
-    if mode not in ("infer", "train"):
-        raise KeyError(f"unknown mode {mode!r}; have {MODES}")
-    if engine == "scalar" and objective in ("edp", "throughput"):
-        raise ValueError(
-            f"objective={objective} requires the vectorized engine")
-    if objective == "throughput":
-        if arch in VISION_ARCHS:
-            raise ValueError(
-                "objective=throughput models the serving prefill/decode "
-                "split of causal LMs; vision archs have no decode phase")
-        if serve_gen < 1 or serve_slots < 1:
-            raise ValueError("serve_gen and serve_slots must be >= 1")
-    if mode == "train":
-        _check_train_compatible(objective, engine)
-    if hw_search not in HW_SEARCH_MODES:
-        raise KeyError(
-            f"unknown hw_search {hw_search!r}; have {HW_SEARCH_MODES}")
-    if hw_search != "off":
-        if objective != "latency":
-            raise ValueError(
-                "--hw-search optimizes the latency (or train-latency) "
-                f"objective; --objective {objective} is fixed-architecture "
-                "only")
-        if engine == "scalar":
-            raise ValueError("--hw-search requires the vectorized engine")
-    if search not in SEARCH_MODES:
-        raise KeyError(f"unknown search {search!r}; have {SEARCH_MODES}")
-    if search == "guided":
-        if objective != "latency":
-            raise ValueError(
-                "--search guided explores the latency (or train-latency) "
-                f"objective; the pre-combined --objective {objective} "
-                "table stays on the exhaustive path")
-        if engine == "scalar":
-            raise ValueError("--search guided requires the vectorized "
-                             "engine")
-    if search_budget is not None and search != "guided":
-        raise ValueError("search_budget requires search='guided'")
-    _check_tune_compatible(tune, mode, objective, hw_search)
-    _check_fused_compatible(fused_cost, mode, objective, engine, hw_search,
-                            search, rank_search)
-    shard_ctx = _shard_context(shards)
-    if rank_search != "off":
-        _check_rank_compatible(rank_search, mode, objective, engine, tune)
-        if shard_ctx is not None:
-            raise ValueError(
-                "--rank-search re-derives networks per decomposition "
-                "candidate; composing it with the --shards context is not "
-                "supported yet")
-        return _run_rank_dse(
-            arch, hw, top_k, tokens, smoke, engine, hw_search, hw_budget,
-            search, search_budget, search_seed, accuracy_budget)
-    if accuracy_budget is not None:
-        raise ValueError("accuracy_budget requires rank_search='budget'")
-
-    named, tokens = dse_problems(arch, tokens, smoke)
+    hw_cfg = get_target(spec.hw)
+    train = spec.mode == "train"
+    all_parts = ALL_PARTITIONINGS
+    shard_ctx = _shard_context(spec.shards)
+    n_shards = 1 if shard_ctx is None else shard_ctx["n_shards"]
+    named, tokens = dse_problems(spec.arch, spec.tokens, spec.smoke)
     if shard_ctx is not None:
         # per-device problems: the searched tilings/tables must match the
         # (tokens / n_shards) block each device actually streams
-        from repro.core.cost_table import shard_streamed_tokens
-
         global_tokens = tokens
-        tokens = shard_streamed_tokens(tokens, shard_ctx["n_shards"])
-        named, _ = dse_problems(arch, tokens, smoke)
+        tokens = shard_streamed_tokens(tokens, n_shards)
+        named, _ = dse_problems(spec.arch, tokens, spec.smoke)
         shard_ctx = {**shard_ctx, "tokens_per_shard": tokens,
                      "global_tokens": global_tokens}
 
     # stage 1 — top-K path search, memoised over repeated layers
     t0 = time.perf_counter()
-    layer_paths = model_layer_paths(named, top_k)
-    path_search_s = time.perf_counter() - t0
-
-    # stage 2 — batched cost table (scalar engine kept for benchmarking)
-    all_parts = ALL_PARTITIONINGS
-    train_tables = None
+    layer_paths = model_layer_paths(named, spec.top_k)
     layer_backwards = None
-    hw_search_report = None
-    if mode == "train":
+    if train:
         from repro.core import memoised_layer_backwards
 
-        t0 = time.perf_counter()
         layer_backwards = memoised_layer_backwards(
-            [tn for _, tn in named], k=top_k)
-        bwd_search_s = time.perf_counter() - t0
-        path_search_s += bwd_search_s
+            [tn for _, tn in named], k=spec.top_k)
+    path_search_s = time.perf_counter() - t0
 
-    # stage 2b — measured calibration (repro.tune): measure the model's
+    # stage 2a — measured calibration (repro.tune): measure the model's
     # dominant GEMM shapes per dataflow on this machine, fit the learned
     # per-(shape-bucket, dataflow) correction from the cache, and rescale
     # the analytic table(s) before the argmin.  Runs before the search
     # stages because the co-search applies it per candidate (gap c).
-    tuner = None
-    tune_report = None
-    calibration = None
-    fused_report = None
-    if tune != "off" and mode == "train":
-        # ROADMAP gap (b): train-mode plans may serve measured tilings —
-        # forward ops through the usual measured sweep, backward ops from
-        # whatever the cache already holds (analytic fallback on miss) —
-        # but the train *search* stays analytic: composing the measured
-        # calibration with the fwd+bwd+update decomposition is open.
-        tuner = _make_tuner(tune, tune_cache,
-                            shards=(shard_ctx or {}).get("n_shards", 1))
-        tune_report = {
-            "mode": tune,
-            "cache": tuner.cache_path,
-            "device_kind": tuner.device_kind,
-            "interpret": tuner.interpret,
-            "n_calibration_shapes": 0,
-            "calibration": None,
-            "correction": None,
-            "note": "train search is analytic; measured tilings only",
-            "n_measured": tuner.n_measured,
-            "n_cache_hits": tuner.n_cache_hits,
-            "n_cache_entries": len(tuner.cache),
-            "measure_s": 0.0,
-        }
-    elif tune != "off":
-        from repro.tune import (
-            fit_cost_correction,
-            gemm_work_items,
-            measured_calibration,
-        )
+    # ROADMAP gap (b): train-mode plans may serve measured tilings —
+    # forward ops through the usual measured sweep, backward ops from
+    # whatever the cache already holds (analytic fallback on miss) — but
+    # the train *search* stays analytic: composing the measured
+    # calibration with the fwd+bwd+update decomposition is open.
+    tuner = tune_report = calibration = None
+    if spec.tune != "off":
+        from repro.tune import Autotuner, DEFAULT_CACHE_PATH, TuningCache
 
-        tuner = _make_tuner(tune, tune_cache,
-                            shards=(shard_ctx or {}).get("n_shards", 1))
+        path = spec.tune_cache or DEFAULT_CACHE_PATH
+        tuner = Autotuner(TuningCache.load_or_empty(path), spec.tune,
+                          cache_path=path, shards=n_shards)
         t0 = time.perf_counter()
-        shapes = gemm_work_items(layer_paths,
-                                 max_shapes=TUNE_CALIBRATION_SHAPES)
-        flat_calibration = measured_calibration(shapes, tuner, hw_cfg)
-        # the learned correction: per (shape bucket, dataflow) geomean
-        # ratios, falling back to the flat per-dataflow scales above on
-        # sparse buckets.  The fit is pinned to the calibration shape
-        # set so a warm cache holding extra sweep entries still fits the
-        # identical model (bit-identical re-emission is CI-asserted).
-        calibration = fit_cost_correction(
-            tuner.cache, hw_cfg, device_kind=tuner.device_kind,
-            interpret=tuner.interpret, shapes=shapes)
+        shapes, flat_calibration = [], None
+        if not train:
+            from repro.tune import (
+                fit_cost_correction,
+                gemm_work_items,
+                measured_calibration,
+            )
+
+            shapes = gemm_work_items(layer_paths,
+                                     max_shapes=TUNE_CALIBRATION_SHAPES)
+            flat_calibration = measured_calibration(shapes, tuner, hw_cfg)
+            # the learned correction: per (shape bucket, dataflow) geomean
+            # ratios, falling back to the flat per-dataflow scales above
+            # on sparse buckets.  The fit is pinned to the calibration
+            # shape set so a warm cache holding extra sweep entries still
+            # fits the identical model (bit-identical re-emission is
+            # CI-asserted).
+            calibration = fit_cost_correction(
+                tuner.cache, hw_cfg, device_kind=tuner.device_kind,
+                interpret=tuner.interpret, shapes=shapes)
         tune_report = {
-            "mode": tune,
+            "mode": spec.tune,
             "cache": tuner.cache_path,
             "device_kind": tuner.device_kind,
             "interpret": tuner.interpret,
             "n_calibration_shapes": len(shapes),
             "calibration": flat_calibration,
-            "correction": calibration.describe(),
+            "correction": (calibration.describe()
+                           if calibration is not None else None),
             "n_measured": tuner.n_measured,
             "n_cache_hits": tuner.n_cache_hits,
             "n_cache_entries": len(tuner.cache),
-            "measure_s": time.perf_counter() - t0,
+            "measure_s": 0.0 if train else time.perf_counter() - t0,
         }
+        if train:
+            tune_report["note"] = ("train search is analytic; measured "
+                                   "tilings only")
 
+    def guided(hw_space=None):
+        from repro.search import guided_search
+
+        return guided_search(
+            layer_paths, hw_cfg,
+            objective="train-latency" if train else "latency",
+            hw_space=hw_space, budget=spec.search_budget,
+            seed=spec.search_seed, layer_backwards=layer_backwards,
+            calibration=calibration)
+
+    # stages 2+3 — batched cost tables and the hierarchical global argmin
+    # over the chosen objective (under hw search: per candidate, inside
+    # the outer architecture loop)
     n_space = 1
-    if hw_search != "off":
-        # stage 2+3 joint: hw-batched tables + outer architecture loop
-        # (exhaustive), or the budgeted guided explorer (repro.search)
+    hw_search_report = fused_report = serving = None
+    if spec.hw_search != "off":
         from repro.core import build_cost_tables_hw, build_train_cost_tables_hw
 
-        space = ArchSpace(base=hw_cfg, mac_budget=hw_budget)
+        def build(hws):  # analytic tables, one per architecture
+            if train:
+                return build_train_cost_tables_hw(
+                    layer_paths, layer_backwards, hws, all_parts)
+            return build_cost_tables_hw(layer_paths, hws, all_parts)
+
+        space = ArchSpace(base=hw_cfg, mac_budget=spec.hw_budget)
         cands = space.candidates()
         n_space = len(cands)
-        if search == "guided":
-            from repro.search import guided_search
-
+        if spec.search == "guided":
             t0 = time.perf_counter()
-            res = guided_search(
-                layer_paths, hw_cfg,
-                objective=("train-latency" if mode == "train"
-                           else "latency"),
-                hw_space=cands, budget=search_budget, seed=search_seed,
-                layer_backwards=layer_backwards, calibration=calibration)
+            res = guided(cands)
             argmin_s = time.perf_counter() - t0
             # rebuild the winner's analytic tables for the report: the
             # per-layer latencies below must stay in analytic seconds
             # even when the argmin ran over a calibrated table
-            if mode == "train":
-                train_tables = build_train_cost_tables_hw(
-                    layer_paths, layer_backwards, (res.hw,), all_parts)[0]
-                tables = train_tables.fwd
-                table_build_s = train_tables.build_seconds
-            else:
-                tables = build_cost_tables_hw(layer_paths, (res.hw,),
-                                              all_parts)[0]
-                table_build_s = tables.build_seconds
-        elif mode == "train":
-            trains = build_train_cost_tables_hw(
-                layer_paths, layer_backwards, cands, all_parts)
-            table_build_s = trains[0].build_seconds
-            t0 = time.perf_counter()
-            res = global_search(layer_paths, objective="train-latency",
-                                hw_space=cands, hw_train_tables=trains)
-            argmin_s = time.perf_counter() - t0
-            win = cands.index(res.hw)
-            train_tables = trains[win]
-            tables = train_tables.fwd
+            tables = build((res.hw,))[0]
+            table_build_s = tables.build_seconds
         else:
-            per_hw = build_cost_tables_hw(layer_paths, cands, all_parts)
+            per_hw = build(cands)
             table_build_s = per_hw[0].build_seconds
             t0 = time.perf_counter()
-            res = global_search(layer_paths, hw_space=cands,
-                                hw_tables=[t.seconds for t in per_hw],
-                                calibration=calibration)
+            if train:
+                res = global_search(layer_paths, objective="train-latency",
+                                    hw_space=cands, hw_train_tables=per_hw)
+            else:
+                res = global_search(layer_paths, hw_space=cands,
+                                    hw_tables=[t.seconds for t in per_hw],
+                                    calibration=calibration)
             argmin_s = time.perf_counter() - t0
-            win = cands.index(res.hw)
-            tables = per_hw[win]
-        seconds_table = tables.seconds
+            tables = per_hw[cands.index(res.hw)]
         hw_search_report = _hw_search_report(space, res, hw_cfg, n_space)
-    elif mode == "train":
-        from repro.core import build_train_cost_tables
+    else:
+        if train:
+            from repro.core import build_train_cost_tables
 
-        train_tables = build_train_cost_tables(
-            layer_paths, layer_backwards, hw_cfg, all_parts)
-        tables = train_tables.fwd
-        seconds_table = tables.seconds
-        table_build_s = train_tables.build_seconds
-    elif engine == "scalar":
-        t0 = time.perf_counter()
-        seconds_table = build_cost_table(
-            layer_paths, hw_cfg, all_parts, engine="scalar"
-        )
-        tables = None
-        table_build_s = time.perf_counter() - t0
-        obj_table = seconds_table
-    decode_seconds = None
-    dec_tokens = decode_tokens if decode_tokens is not None else serve_slots
-    if shard_ctx is not None:
-        from repro.core.cost_table import shard_streamed_tokens
-
-        dec_tokens = shard_streamed_tokens(dec_tokens,
-                                           shard_ctx["n_shards"])
-    if hw_search == "off" and mode != "train" and engine != "scalar":
-        tables = build_cost_tables(layer_paths, hw_cfg, all_parts)
-        seconds_table = tables.seconds
-        table_build_s = tables.build_seconds
-        if fused_cost:
-            tables, fused_report = _apply_fused_cost(
-                tables, named, layer_paths, hw_cfg, tokens, tuner)
-            seconds_table = tables.seconds
-            table_build_s += fused_report["build_s"]
-        if objective == "edp":
-            obj_table = tables.edp(hw_cfg)
-        elif objective == "throughput":
-            # second cost table at decode shape: same contraction orders,
-            # activations replayed at dec_tokens streamed tokens so the
-            # (layer, path) keys line up across the phase tables
-            from repro.core.dse import combine_phase_tables, replay_paths
-
-            decode_named, _ = dse_problems(arch, dec_tokens, smoke)
-            decode_paths = replay_paths(
-                layer_paths, [tn for _, tn in decode_named])
-            decode_tables = build_cost_tables(decode_paths, hw_cfg, all_parts)
-            decode_seconds = decode_tables.seconds
-            table_build_s += decode_tables.build_seconds
-            # measured calibration applies per phase, at each phase's own
-            # GEMM shapes (ROADMAP serving follow-on (a)); the combined
-            # table is then final — stage 3 must not rescale it again
-            obj_table = combine_phase_tables(
-                seconds_table, decode_seconds,
-                w_decode=serve_gen / serve_slots,
-                calibration=calibration,
-                prefill_paths=layer_paths,
-                decode_paths=decode_paths)
+            tables = build_train_cost_tables(
+                layer_paths, layer_backwards, hw_cfg, all_parts)
+            table_build_s = tables.build_seconds
         else:
-            obj_table = seconds_table
+            tables = build_cost_tables(layer_paths, hw_cfg, all_parts)
+            table_build_s = tables.build_seconds
+            if spec.fused_cost:
+                tables, fused_report = _apply_fused_cost(
+                    tables, named, layer_paths, hw_cfg, tokens, tuner)
+                table_build_s += fused_report["build_s"]
+            obj_table = tables.seconds
+            if spec.objective == "edp":
+                obj_table = tables.edp(hw_cfg)
+            elif spec.objective == "throughput":
+                # second cost table at decode shape: same contraction
+                # orders, activations replayed at dec_tokens streamed
+                # tokens so the (layer, path) keys line up across the
+                # phase tables
+                from repro.core.dse import combine_phase_tables, replay_paths
 
-    # stage 3 — hierarchical global argmin over the chosen objective
-    # (already folded into the outer architecture loop under hw search)
-    if hw_search == "off":
+                dec_tokens = (spec.serve_slots if spec.decode_tokens is None
+                              else spec.decode_tokens)
+                if shard_ctx is not None:
+                    dec_tokens = shard_streamed_tokens(dec_tokens, n_shards)
+                decode_named, _ = dse_problems(spec.arch, dec_tokens,
+                                               spec.smoke)
+                decode_paths = replay_paths(
+                    layer_paths, [tn for _, tn in decode_named])
+                decode_tables = build_cost_tables(decode_paths, hw_cfg,
+                                                  all_parts)
+                table_build_s += decode_tables.build_seconds
+                # measured calibration applies per phase, at each phase's
+                # own GEMM shapes (ROADMAP serving follow-on (a)); the
+                # combined table is then final — stage 3 must not rescale
+                # it again
+                obj_table = combine_phase_tables(
+                    tables.seconds, decode_tables.seconds,
+                    w_decode=spec.serve_gen / spec.serve_slots,
+                    calibration=calibration,
+                    prefill_paths=layer_paths,
+                    decode_paths=decode_paths)
         t0 = time.perf_counter()
-        if search == "guided":
-            from repro.search import guided_search
-
-            res = guided_search(
-                layer_paths, hw_cfg,
-                objective=("train-latency" if mode == "train"
-                           else "latency"),
-                budget=search_budget, seed=search_seed,
-                layer_backwards=layer_backwards, calibration=calibration)
-        elif mode == "train":
+        if spec.search == "guided":
+            res = guided()
+        elif train:
             res = global_search(layer_paths, hw_cfg,
                                 objective="train-latency",
-                                train_tables=train_tables)
+                                train_tables=tables)
         else:
+            throughput = spec.objective == "throughput"
             res = global_search(
                 layer_paths, hw_cfg, table=obj_table,
                 # throughput tables arrive pre-calibrated per phase
-                calibration=(None if objective == "throughput"
-                             else calibration),
-                objective="throughput" if objective == "throughput"
-                else "latency")
+                calibration=None if throughput else calibration,
+                objective="throughput" if throughput else "latency")
         argmin_s = time.perf_counter() - t0
+    if train:
+        tables = tables.fwd  # the per-layer rows report forward cells
 
-    layers = []
-    total_latency = 0.0
-    for (name, _), choice in zip(named, res.choices):
-        key = (choice.layer, choice.path_index, choice.partitioning,
-               choice.dataflow)
-        # train mode: per-step cost = fwd + bwd + update; infer: fwd only
-        latency_s = choice.latency_s if mode == "train" else seconds_table[key]
-        total_latency += latency_s
-        entry = {
-            "name": name,
-            "path_index": choice.path_index,
-            "mac_optimal_path": choice.path_index == 0,
-            "macs": choice.path.macs,
-            "partitioning": list(choice.partitioning),
-            "dataflow": choice.dataflow.value,
-            "latency_s": latency_s,
-            # the argmin's objective value: == latency_s unless EDP or
-            # --tune (then in measured-rescaled units, see the tune
-            # section's calibration scales)
-            "objective": choice.latency_s,
-        }
-        if mode == "train":
-            entry["fwd_latency_s"] = choice.fwd_latency_s
-            entry["bwd_latency_s"] = choice.bwd_latency_s
-            entry["update_latency_s"] = choice.update_latency_s
-            entry["backward"] = [
-                {"wrt": ch.wrt, "path_index": ch.path_index,
-                 "latency_s": ch.latency_s}
-                for ch in choice.backward
-            ]
-        layers.append(entry)
-    report = {
-        "arch": arch,
-        "hw": hw,
-        # the architecture the numbers below describe: the co-searched
-        # winner under --hw-search, else the --hw target itself
-        "hw_chosen": res.hw.name if res.hw is not None else hw,
-        "hw_search": hw_search_report,
-        "tune": tune_report,
-        "fused_cost": fused_report,
-        "mode": mode,
-        "objective": "train-latency" if mode == "train" else objective,
-        "top_k": top_k,
-        "tokens": tokens,
-        "engine": engine,
-        "strategy": res.strategy,
-        "total_latency_s": total_latency,
-        "total_objective": res.total_latency_s,
-        "search": {
-            "mode": res.search,
-            "budget": search_budget,
-            "seed": search_seed if search == "guided" else None,
-            "evals": res.evals,
-            "found_at_eval": res.found_at_eval,
-            "exhaustive_evals": n_space * _table_cells(layer_paths,
-                                                      all_parts),
-        },
-        "sharding": shard_ctx,
-        "n_layers": len(layers),
-        "timings": {
-            "path_search_s": path_search_s,
-            "table_build_s": table_build_s,
-            "argmin_s": argmin_s,
-        },
-        "table": {
-            "n_cells": len(seconds_table),
-            "n_unique_gemm_evals": tables.n_unique_gemm_evals if tables else None,
-            "n_unique_layers": tables.n_unique_layers if tables else None,
-        },
-        "layers": layers,
-    }
-    if mode == "train":
-        report["total_fwd_latency_s"] = sum(
-            c.fwd_latency_s for c in res.choices)
-        report["total_bwd_latency_s"] = sum(
-            c.bwd_latency_s for c in res.choices)
-        report["total_update_latency_s"] = sum(
-            c.update_latency_s for c in res.choices)
-    if decode_seconds is not None:
+    if spec.objective == "throughput":
         # phase decomposition of the winning serving configuration:
         # total objective = prefill + (gen/slots) * decode per admission
-        keys = [(c.layer, c.path_index, c.partitioning, c.dataflow)
-                for c in res.choices]
-        report["serving"] = {
+        keys = [_cell(c) for c in res.choices]
+        serving = {
             "prefill_tokens": tokens,
             "decode_tokens": dec_tokens,
-            "gen_tokens": serve_gen,
-            "n_slots": serve_slots,
-            "decode_weight": serve_gen / serve_slots,
+            "gen_tokens": spec.serve_gen,
+            "n_slots": spec.serve_slots,
+            "decode_weight": spec.serve_gen / spec.serve_slots,
             # True when the combined table was measured-calibrated per
             # phase (--tune with --objective throughput); the analytic
             # phase split below stays in analytic seconds either way
             "calibrated": calibration is not None,
-            "total_prefill_s": sum(seconds_table[k] for k in keys),
-            "total_decode_step_s": sum(decode_seconds[k] for k in keys),
+            "total_prefill_s": sum(tables.seconds[k] for k in keys),
+            "total_decode_step_s": sum(decode_tables.seconds[k]
+                                       for k in keys),
             "total_combined_s": res.total_latency_s,
         }
+    report = _report(
+        spec, named, res, tables, layer_paths, tokens=tokens,
+        n_space=n_space, hw_search=hw_search_report, tune=tune_report,
+        fused_cost=fused_report, sharding=shard_ctx, serving=serving,
+        timings={"path_search_s": path_search_s,
+                 "table_build_s": table_build_s, "argmin_s": argmin_s})
     return (report, named, res,
             (res.hw if res.hw is not None else hw_cfg), tuner, calibration)
 
 
-def _run_rank_dse(
-    arch: str,
-    hw: str,
-    top_k: int,
-    tokens: Optional[int],
-    smoke: bool,
-    engine: str,
-    hw_search: str,
-    hw_budget: Optional[int],
-    search: str,
-    search_budget: Optional[int],
-    search_seed: int,
-    accuracy_budget: Optional[float],
-):
-    """The ``--rank-search budget`` pipeline (repro.rank).
+def _run_rank_dse(spec: SearchSpec):
+    """The ``rank_search="budget"`` pipeline (repro.rank).
 
     Evaluates every decomposition candidate through the same cost-table
     /argmin stack as :func:`_run_dse` and reports the chosen candidate's
@@ -1239,22 +962,22 @@ def _run_rank_dse(
     return contract as ``_run_dse`` — ``run_dse_plan`` compiles the
     chosen candidate's networks/choices into a (v4) plan.
     """
-    from repro.rank import rank_search as _rank_search
+    from repro.rank import rank_search
 
-    hw_cfg = get_target(hw)
-    hw_space = None
-    space = None
+    hw_cfg = get_target(spec.hw)
+    space = hw_space = None
     n_space = 1
-    if hw_search == "budget":
-        space = ArchSpace(base=hw_cfg, mac_budget=hw_budget)
+    if spec.hw_search == "budget":
+        space = ArchSpace(base=hw_cfg, mac_budget=spec.hw_budget)
         hw_space = space.candidates()
         n_space = len(hw_space)
 
     t0 = time.perf_counter()
-    rres = _rank_search(
-        arch, hw_cfg, top_k=top_k, tokens=tokens, smoke=smoke,
-        hw_space=hw_space, search=search, search_budget=search_budget,
-        search_seed=search_seed, accuracy_budget=accuracy_budget)
+    rres = rank_search(
+        spec.arch, hw_cfg, top_k=spec.top_k, tokens=spec.tokens,
+        smoke=spec.smoke, hw_space=hw_space, search=spec.search,
+        search_budget=spec.search_budget, search_seed=spec.search_seed,
+        accuracy_budget=spec.accuracy_budget)
     rank_search_s = time.perf_counter() - t0
 
     ce = rres.chosen_eval
@@ -1264,36 +987,15 @@ def _run_rank_dse(
     # rebuild the chosen candidate's analytic table for the per-layer
     # report (under --hw-search: on its winning architecture)
     t0 = time.perf_counter()
-    layer_paths = model_layer_paths(named, top_k)
+    layer_paths = model_layer_paths(named, spec.top_k)
     path_search_s = time.perf_counter() - t0
-    if hw_search == "budget":
+    if space is not None:
         from repro.core import build_cost_tables_hw
 
         tables = build_cost_tables_hw(layer_paths, (plan_hw,),
                                       ALL_PARTITIONINGS)[0]
     else:
         tables = build_cost_tables(layer_paths, hw_cfg, ALL_PARTITIONINGS)
-    seconds_table = tables.seconds
-    hw_search_report = (_hw_search_report(space, res, hw_cfg, n_space)
-                        if hw_search == "budget" else None)
-
-    layers = []
-    total_latency = 0.0
-    for (name, _), choice in zip(named, res.choices):
-        key = (choice.layer, choice.path_index, choice.partitioning,
-               choice.dataflow)
-        latency_s = seconds_table[key]
-        total_latency += latency_s
-        layers.append({
-            "name": name,
-            "path_index": choice.path_index,
-            "mac_optimal_path": choice.path_index == 0,
-            "macs": choice.path.macs,
-            "partitioning": list(choice.partitioning),
-            "dataflow": choice.dataflow.value,
-            "latency_s": latency_s,
-            "objective": choice.latency_s,
-        })
 
     def cand_row(i: int) -> dict:
         e = rres.evals[i]
@@ -1326,7 +1028,7 @@ def _run_rank_dse(
     ]
     rank_report = {
         "mode": "budget",
-        "accuracy_budget": accuracy_budget,
+        "accuracy_budget": spec.accuracy_budget,
         "param_budget_ratio": rres.param_budget_ratio,
         "n_candidates": len(rres.evals),
         "frontier": [rres.evals[i].candidate.name for i in rres.frontier],
@@ -1336,54 +1038,120 @@ def _run_rank_dse(
         "improvement_pct": rres.improvement_pct,
         # vision decompositions are structural (TT-conv) — their rank
         # rides in the networks, not in an installable plan
-        "plan_embeddable": arch not in VISION_ARCHS,
+        "plan_embeddable": spec.arch not in VISION_ARCHS,
         "rank_search_s": rank_search_s,
         "candidates": sorted(
             rows, key=lambda r: (r["total_latency_s"], r["name"])),
     }
+    report = _report(
+        spec, named, res, tables, layer_paths, tokens=rres.tokens,
+        # per-candidate table sizes differ; scale the chosen candidate's
+        # cell count by the candidate count
+        n_space=n_space * len(rres.evals),
+        evals=sum(e.res.evals for e in rres.evals),
+        hw_search=(_hw_search_report(space, res, hw_cfg, n_space)
+                   if space is not None else None),
+        rank_search=rank_report,
+        timings={"path_search_s": path_search_s,
+                 "table_build_s": tables.build_seconds,
+                 "argmin_s": rank_search_s,
+                 "rank_search_s": rank_search_s})
+    return report, named, res, plan_hw, None, None
 
+
+def _cell(choice) -> tuple:
+    """The cost-table key of one layer's choice."""
+    return (choice.layer, choice.path_index, choice.partitioning,
+            choice.dataflow)
+
+
+def _report(spec: SearchSpec, named, res, tables, layer_paths, *,
+            tokens: int, timings: dict, n_space: int = 1,
+            evals: Optional[int] = None, hw_search=None, tune=None,
+            fused_cost=None, sharding=None, rank_search=None,
+            serving=None) -> dict:
+    """The JSON report of one search leg: per-layer rows and totals.
+
+    ``tables`` are the (forward) analytic cost tables the per-layer
+    latencies are read from; ``n_space`` counts the tables an exhaustive
+    sweep would fill (architectures, times rank candidates), ``evals``
+    overrides the argmin's own evaluation count.  The ``rank_search``
+    and ``serving`` sections appear only when given.
+    """
+    train = spec.mode == "train"
+    layers = []
+    total_latency = 0.0  # summed in layer order, as plans record it
+    for (name, _), choice in zip(named, res.choices):
+        entry = {
+            "name": name,
+            "path_index": choice.path_index,
+            "mac_optimal_path": choice.path_index == 0,
+            "macs": choice.path.macs,
+            "partitioning": list(choice.partitioning),
+            "dataflow": choice.dataflow.value,
+            # train mode: per-step cost = fwd + bwd + update; infer: fwd only
+            "latency_s": (choice.latency_s if train
+                          else tables.seconds[_cell(choice)]),
+            # the argmin's objective value: == latency_s unless EDP or
+            # --tune (then in measured-rescaled units, see the tune
+            # section's calibration scales)
+            "objective": choice.latency_s,
+        }
+        if train:
+            entry["fwd_latency_s"] = choice.fwd_latency_s
+            entry["bwd_latency_s"] = choice.bwd_latency_s
+            entry["update_latency_s"] = choice.update_latency_s
+            entry["backward"] = [
+                {"wrt": ch.wrt, "path_index": ch.path_index,
+                 "latency_s": ch.latency_s}
+                for ch in choice.backward
+            ]
+        total_latency += entry["latency_s"]
+        layers.append(entry)
     report = {
-        "arch": arch,
-        "hw": hw,
-        "hw_chosen": res.hw.name if res.hw is not None else hw,
-        "hw_search": hw_search_report,
-        "tune": None,
-        "mode": "infer",
-        "objective": "latency",
-        "top_k": top_k,
-        "tokens": rres.tokens,
-        "engine": engine,
+        "arch": spec.arch,
+        "hw": spec.hw,
+        # the architecture the numbers below describe: the co-searched
+        # winner under --hw-search, else the --hw target itself
+        "hw_chosen": res.hw.name if res.hw is not None else spec.hw,
+        "hw_search": hw_search,
+        "tune": tune,
+        "fused_cost": fused_cost,
+        "mode": spec.mode,
+        "objective": "train-latency" if train else spec.objective,
+        "top_k": spec.top_k,
+        "tokens": tokens,
         "strategy": res.strategy,
         "total_latency_s": total_latency,
         "total_objective": res.total_latency_s,
         "search": {
             "mode": res.search,
-            "budget": search_budget,
-            "seed": search_seed if search == "guided" else None,
-            "evals": sum(e.res.evals for e in rres.evals),
+            "budget": spec.search_budget,
+            "seed": spec.search_seed if spec.search == "guided" else None,
+            "evals": res.evals if evals is None else evals,
             "found_at_eval": res.found_at_eval,
-            # per-candidate table sizes differ; scale the chosen
-            # candidate's cell count by the candidate count
-            "exhaustive_evals": (n_space * len(rres.evals)
-                                 * _table_cells(layer_paths,
-                                                ALL_PARTITIONINGS)),
+            "exhaustive_evals": n_space * _table_cells(layer_paths,
+                                                      ALL_PARTITIONINGS),
         },
-        "rank_search": rank_report,
+        "sharding": sharding,
         "n_layers": len(layers),
-        "timings": {
-            "path_search_s": path_search_s,
-            "table_build_s": tables.build_seconds,
-            "argmin_s": rank_search_s,
-            "rank_search_s": rank_search_s,
-        },
+        "timings": timings,
         "table": {
-            "n_cells": len(seconds_table),
+            "n_cells": len(tables.seconds),
             "n_unique_gemm_evals": tables.n_unique_gemm_evals,
             "n_unique_layers": tables.n_unique_layers,
         },
         "layers": layers,
     }
-    return report, named, res, plan_hw, None, None
+    if rank_search is not None:
+        report["rank_search"] = rank_search
+    if train:
+        for part in ("fwd", "bwd", "update"):
+            report[f"total_{part}_latency_s"] = sum(
+                getattr(c, f"{part}_latency_s") for c in res.choices)
+    if serving is not None:
+        report["serving"] = serving
+    return report
 
 
 # ---------------------------------------------------------------------------
@@ -1484,9 +1252,6 @@ def _build_parser() -> argparse.ArgumentParser:
                         "rules, else 1)")
     p.add_argument("--smoke", action="store_true",
                    help="use the config's reduced SMOKE variant")
-    p.add_argument("--engine", default="vectorized",
-                   choices=("vectorized", "scalar"),
-                   help="cost-table engine (scalar = per-cell oracle)")
     p.add_argument("--tune", default="off", choices=TUNE_MODES,
                    help="off: analytic search (default); cache/measure: "
                         "measure dominant GEMM shapes per dataflow on this "
@@ -1524,8 +1289,19 @@ def _build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _save_plan(plan, path: str) -> None:
+    plan.save(path)
+    backends = sorted({lp.backend for lp in plan.layers})
+    hw_note = (f", hardware {plan.hardware.name}"
+               if plan.hardware is not None else "")
+    print(f"wrote {plan.phase + ' ' if plan.phase else ''}plan {path} "
+          f"({len(plan.layers)} layer plans, backends {backends}{hw_note}"
+          f", tokens {plan.tokens}, tilings {plan.tilings})", file=sys.stderr)
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
-    args = _build_parser().parse_args(argv)
+    parser = _build_parser()
+    args = parser.parse_args(argv)
     if args.list_archs:
         for a in ("tt-lm-100m",) + tuple(ARCH_IDS) + VISION_ARCHS:
             print(a)
@@ -1534,136 +1310,58 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         for name in list_targets():
             print(name)
         return 0
-    if not args.arch:
-        _build_parser().error("--arch is required (see --list-archs)")
-    if args.plan_backend != "auto" and not (args.emit_plan
-                                            or args.emit_plan_pair):
-        _build_parser().error(
-            "--plan-backend requires --emit-plan or --emit-plan-pair")
-    if args.phase and not args.emit_plan:
-        _build_parser().error("--phase requires --emit-plan "
-                              "(--emit-plan-pair stamps both phases itself)")
-    if args.emit_plan_pair:
-        if args.emit_plan:
-            _build_parser().error(
-                "--emit-plan-pair and --emit-plan are mutually exclusive")
-        if args.objective == "throughput":
-            _build_parser().error(
-                "--objective throughput emits one compromise plan via "
-                "--emit-plan; --emit-plan-pair optimizes each phase "
-                "separately (pick one)")
-        if args.mode != "infer":
-            _build_parser().error(
-                "--emit-plan-pair compiles serving (inference) plans; "
-                f"--mode {args.mode} is not applicable")
-        if args.hw_search != "off":
-            _build_parser().error(
-                "--emit-plan-pair compiles a pair for one fixed --hw "
-                "target; co-searching a different architecture per phase "
-                "is unservable in one engine")
-    if args.hw_budget is not None and args.hw_search == "off":
-        _build_parser().error("--hw-budget requires --hw-search budget")
-    if args.search_budget is not None and args.search != "guided":
-        _build_parser().error("--search-budget requires --search guided")
-    if args.tune_cache is not None and args.tune == "off":
-        _build_parser().error("--tune-cache requires --tune cache|measure")
-    if args.accuracy_budget is not None and args.rank_search == "off":
-        _build_parser().error("--accuracy-budget requires --rank-search "
-                              "budget")
-    if args.rank_search != "off" and args.emit_plan_pair:
-        _build_parser().error(
-            "--rank-search with --emit-plan-pair would search a different "
-            "decomposition per phase; factorizations set parameter shapes, "
-            "so a serving pair must share one (use --emit-plan)")
+    # rules about the CLI's own flags; the search's rules live in SearchSpec
+    pair = args.emit_plan_pair
+    for broken, message in (
+        (not args.arch, "--arch is required (see --list-archs)"),
+        (args.plan_backend != "auto" and not (args.emit_plan or pair),
+         "--plan-backend requires --emit-plan or --emit-plan-pair"),
+        (args.phase and not args.emit_plan,
+         "--phase requires --emit-plan (--emit-plan-pair stamps both "
+         "phases itself)"),
+        (pair and args.emit_plan,
+         "--emit-plan-pair and --emit-plan are mutually exclusive"),
+        (pair and args.objective == "throughput",
+         "--objective throughput emits one compromise plan via "
+         "--emit-plan; --emit-plan-pair optimizes each phase separately "
+         "(pick one)"),
+        (pair and args.mode != "infer",
+         "--emit-plan-pair compiles serving (inference) plans; "
+         f"--mode {args.mode} is not applicable"),
+        (pair and args.hw_search != "off",
+         "--emit-plan-pair compiles a pair for one fixed --hw target; "
+         "co-searching a different architecture per phase is unservable "
+         "in one engine"),
+        (pair and args.rank_search != "off",
+         "--rank-search with --emit-plan-pair would search a different "
+         "decomposition per phase; factorizations set parameter shapes, "
+         "so a serving pair must share one (use --emit-plan)"),
+    ):
+        if broken:
+            parser.error(message)
     try:
-        if args.emit_plan_pair:
-            common = dict(
-                arch=args.arch, hw=args.hw, top_k=args.top_k,
-                objective=args.objective, smoke=args.smoke,
-                engine=args.engine, plan_backend=args.plan_backend,
-                mode="infer", tune=args.tune, tune_cache=args.tune_cache,
-                search=args.search, search_budget=args.search_budget,
-                search_seed=args.search_seed, shards=args.shards,
-                fused_cost=args.fused_cost,
-            )
-            dec_tokens = (args.decode_tokens if args.decode_tokens is not None
-                          else args.serve_slots)
-            report_p, plan_p = run_dse_plan(
-                tokens=args.tokens, phase="prefill", **common)
-            report_d, plan_d = run_dse_plan(
-                tokens=dec_tokens, phase="decode", **common)
-            for plan, path in ((plan_p, f"{args.emit_plan_pair}.prefill.json"),
-                               (plan_d, f"{args.emit_plan_pair}.decode.json")):
-                plan.save(path)
-                backends = sorted({lp.backend for lp in plan.layers})
-                print(f"wrote {plan.phase} plan {path} "
-                      f"({len(plan.layers)} layer plans, backends {backends}"
-                      f", tokens {plan.tokens}, tilings {plan.tilings})",
-                      file=sys.stderr)
-            report = {
-                "arch": args.arch, "hw": args.hw, "mode": "plan-pair",
-                "prefill": report_p, "decode": report_d,
-            }
+        spec = SearchSpec(**{f.name: getattr(args, f.name)
+                             for f in dataclasses.fields(SearchSpec)})
+    except (KeyError, ValueError) as e:
+        parser.error(e.args[0])
+    try:
+        if pair:
+            dec_tokens = (spec.serve_slots if spec.decode_tokens is None
+                          else spec.decode_tokens)
+            runs = {phase: run_dse_plan(spec, tokens=tokens, phase=phase,
+                                        plan_backend=args.plan_backend)
+                    for phase, tokens in (("prefill", spec.tokens),
+                                          ("decode", dec_tokens))}
+            for phase, (_, plan) in runs.items():
+                _save_plan(plan, f"{pair}.{phase}.json")
+            report = {"arch": spec.arch, "hw": spec.hw, "mode": "plan-pair",
+                      **{phase: r for phase, (r, _) in runs.items()}}
         elif args.emit_plan:
-            report, plan = run_dse_plan(
-                arch=args.arch,
-                hw=args.hw,
-                top_k=args.top_k,
-                objective=args.objective,
-                tokens=args.tokens,
-                smoke=args.smoke,
-                engine=args.engine,
-                plan_backend=args.plan_backend,
-                mode=args.mode,
-                hw_search=args.hw_search,
-                hw_budget=args.hw_budget,
-                tune=args.tune,
-                tune_cache=args.tune_cache,
-                serve_gen=args.serve_gen,
-                serve_slots=args.serve_slots,
-                decode_tokens=args.decode_tokens,
-                phase=args.phase or "",
-                search=args.search,
-                search_budget=args.search_budget,
-                search_seed=args.search_seed,
-                rank_search=args.rank_search,
-                accuracy_budget=args.accuracy_budget,
-                shards=args.shards,
-                fused_cost=args.fused_cost,
-            )
-            plan.save(args.emit_plan)
-            backends = sorted({lp.backend for lp in plan.layers})
-            hw_note = (f", hardware {plan.hardware.name}"
-                       if plan.hardware is not None else "")
-            print(f"wrote plan {args.emit_plan} "
-                  f"({len(plan.layers)} layer plans, backends {backends}"
-                  f"{hw_note}, tilings {plan.tilings})",
-                  file=sys.stderr)
+            report, plan = run_dse_plan(spec, plan_backend=args.plan_backend,
+                                        phase=args.phase or "")
+            _save_plan(plan, args.emit_plan)
         else:
-            report = run_dse(
-                arch=args.arch,
-                hw=args.hw,
-                top_k=args.top_k,
-                objective=args.objective,
-                tokens=args.tokens,
-                smoke=args.smoke,
-                engine=args.engine,
-                mode=args.mode,
-                hw_search=args.hw_search,
-                hw_budget=args.hw_budget,
-                tune=args.tune,
-                tune_cache=args.tune_cache,
-                serve_gen=args.serve_gen,
-                serve_slots=args.serve_slots,
-                decode_tokens=args.decode_tokens,
-                search=args.search,
-                search_budget=args.search_budget,
-                search_seed=args.search_seed,
-                rank_search=args.rank_search,
-                accuracy_budget=args.accuracy_budget,
-                shards=args.shards,
-                fused_cost=args.fused_cost,
-            )
+            report = run_dse(spec)
     except (KeyError, ValueError) as e:
         msg = e.args[0] if e.args else e
         print(f"error: {msg}", file=sys.stderr)

@@ -7,11 +7,11 @@ import sys
 
 import pytest
 
-from repro.dse_cli import main, model_dse_layers, run_dse
+from repro.dse_cli import SearchSpec, main, model_dse_layers, run_dse
 from repro.configs import get_config
 
 REQUIRED_KEYS = {
-    "arch", "hw", "objective", "top_k", "tokens", "engine", "strategy",
+    "arch", "hw", "objective", "top_k", "tokens", "strategy",
     "total_latency_s", "total_objective", "n_layers", "timings", "table",
     "layers",
 }
@@ -134,13 +134,75 @@ def test_hw_search_mode_both_flags_arch_divergence():
     assert hs["hw_divergent"] == (hs["infer_chosen"] != hs["train_chosen"])
 
 
-def test_hw_search_validation():
-    with pytest.raises(KeyError, match="hw_search"):
-        run_dse("tt-lm-100m", hw_search="exhaustive")
-    with pytest.raises(ValueError, match="edp"):
-        run_dse("tt-lm-100m", hw_search="budget", objective="edp")
-    with pytest.raises(ValueError, match="vectorized"):
-        run_dse("tt-lm-100m", hw_search="budget", engine="scalar")
+# one case per SearchSpec rule, in the order the rules are checked
+_REJECTED = [
+    pytest.param(dict(objective="power"), KeyError, "objective",
+                 id="objective"),
+    pytest.param(dict(mode="no-such-mode"), KeyError, "mode", id="mode"),
+    pytest.param(dict(arch="vit_ti4/cifar10", objective="throughput"),
+                 ValueError, "vision", id="throughput-vision"),
+    pytest.param(dict(objective="throughput", serve_slots=0), ValueError,
+                 "serve_gen and serve_slots", id="throughput-slots"),
+    pytest.param(dict(mode="train", objective="edp"), ValueError,
+                 "train-latency", id="train-objective"),
+    pytest.param(dict(hw_search="exhaustive"), KeyError, "hw_search",
+                 id="hw-search"),
+    pytest.param(dict(hw_search="budget", objective="edp"), ValueError,
+                 "edp", id="hw-search-objective"),
+    pytest.param(dict(search="random"), KeyError, "search", id="search"),
+    pytest.param(dict(search="guided", objective="edp"), ValueError,
+                 "guided", id="guided-objective"),
+    pytest.param(dict(search_budget=10), ValueError, "search_budget",
+                 id="search-budget"),
+    pytest.param(dict(tune="always"), KeyError, "tune", id="tune"),
+    # --mode train composes since the tiling lift (ROADMAP gap b); the
+    # ambiguous --mode both combination is what's rejected
+    pytest.param(dict(mode="both", tune="cache"), ValueError, "ambiguous",
+                 id="tune-both"),
+    pytest.param(dict(objective="edp", tune="cache"), ValueError,
+                 "analytic-only", id="tune-objective"),
+    pytest.param(dict(fused_cost=True, mode="train"), ValueError,
+                 "fused-cost", id="fused-train"),
+    pytest.param(dict(fused_cost=True, mode="both"), ValueError,
+                 "fused-cost", id="fused-both"),
+    pytest.param(dict(fused_cost=True, objective="throughput"), ValueError,
+                 "fused-cost", id="fused-objective"),
+    pytest.param(dict(fused_cost=True, hw_search="budget"), ValueError,
+                 "fused-cost", id="fused-hw-search"),
+    pytest.param(dict(fused_cost=True, search="guided"), ValueError,
+                 "fused-cost", id="fused-guided"),
+    pytest.param(dict(fused_cost=True, rank_search="budget"), ValueError,
+                 "fused-cost", id="fused-rank"),
+    pytest.param(dict(shards=0), ValueError, "shards", id="shards"),
+    pytest.param(dict(rank_search="exhaustive"), KeyError, "rank_search",
+                 id="rank-search"),
+    pytest.param(dict(rank_search="budget", mode="train"), ValueError,
+                 "rank", id="rank-train"),
+    pytest.param(dict(rank_search="budget", objective="edp"), ValueError,
+                 "rank", id="rank-objective"),
+    pytest.param(dict(rank_search="budget", tune="measure"), ValueError,
+                 "rank", id="rank-tune"),
+    pytest.param(dict(rank_search="budget", shards=4), ValueError, "rank",
+                 id="rank-shards"),
+    pytest.param(dict(accuracy_budget=0.5), ValueError, "accuracy_budget",
+                 id="accuracy-budget"),
+    pytest.param(dict(hw_budget=4096), ValueError, "hw_budget",
+                 id="hw-budget"),
+    pytest.param(dict(tune_cache="cache.json"), ValueError, "tune_cache",
+                 id="tune-cache"),
+]
+
+
+@pytest.mark.parametrize("kw, exc, match", _REJECTED)
+def test_search_spec_rejects(kw, exc, match):
+    """Each rule refuses its combination when the spec is built — before
+    any search work — and so through ``run_dse`` too."""
+    kw = {"arch": "tt-lm-100m", "top_k": 2, "tokens": 32, "smoke": True,
+          **kw}
+    with pytest.raises(exc, match=match):
+        SearchSpec(**kw)
+    with pytest.raises(exc, match=match):
+        run_dse(**kw)
 
 
 def test_model_dse_layers_covers_families():
@@ -189,11 +251,7 @@ def test_mode_and_backend_validation():
     from repro.dse_cli import run_dse_plan
 
     with pytest.raises(KeyError, match="mode"):
-        run_dse("tt-lm-100m", mode="no-such-mode")
-    with pytest.raises(ValueError, match="train-latency"):
-        run_dse("tt-lm-100m", mode="train", objective="edp")
-    with pytest.raises(ValueError, match="vectorized"):
-        run_dse("tt-lm-100m", mode="train", engine="scalar")
+        run_dse_plan("tt-lm-100m", smoke=True, mode="no-such-mode")
     # early validation — before any search work happens
     with pytest.raises(ValueError, match="backend"):
         run_dse_plan("tt-lm-100m", smoke=True, plan_backend="cuda")
@@ -226,3 +284,78 @@ def test_module_invocation_subprocess():
     assert proc.returncode == 0, proc.stderr
     report = json.loads(proc.stdout)
     assert report["arch"] == "tt-lm-100m"
+
+
+# --- the plans the benchmark's chip cells run ------------------------------
+
+_BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
+
+
+def _cell_layers(mlp: str, head_segments, block_tokens: int) -> dict:
+    """{layer: (backend, segments, tiling)}: the attention projections
+    stream, the MLP runs on ``mlp``, the head on tt_gemm."""
+    qo = (128, 128, 16, block_tokens)  # block_m, block_k, block_n, tokens
+    gu = (128, 16, 128, block_tokens)
+    return {
+        "attn.wq": ("streaming_tt", None, qo),
+        "attn.wo": ("streaming_tt", None, qo),
+        "mlp.wg": (mlp, None, gu),
+        "mlp.wu": (mlp, None, gu),
+        "mlp.wd": (mlp, None, qo),
+        "head": ("tt_gemm", head_segments, gu),
+    }
+
+
+_CHAT_HEAD = ((0, 1), (1, 2), (2, 5), (5, 6))
+
+
+@pytest.mark.parametrize("config, traffic, phase, layers", [
+    pytest.param("tt-lm-100m-bf16", "serve-chat", "prefill",
+                 _cell_layers("streaming_tt", _CHAT_HEAD, 256),
+                 id="serve-chat-prefill"),
+    pytest.param("tt-lm-100m-bf16", "serve-chat", "decode",
+                 _cell_layers("streaming_tt", _CHAT_HEAD, 256),
+                 id="serve-chat-decode"),
+    pytest.param("tt-lm-100m", "train-1k", "train",
+                 _cell_layers("streaming_tt", None, 256),
+                 id="train-1k"),
+    pytest.param("chatglm3-6b-tt", "serve-docs", "prefill",
+                 _cell_layers("tt_gemm",
+                              ((0, 1), (1, 2), (2, 3), (3, 5), (5, 6)),
+                              256), id="serve-docs-prefill"),
+    pytest.param("chatglm3-6b-tt", "serve-docs", "decode",
+                 _cell_layers("streaming_tt", _CHAT_HEAD, 128),
+                 id="serve-docs-decode"),
+])
+def test_bench_cell_plans(config, traffic, phase, layers):
+    """Each chip cell's plan, searched with the cell's own arguments as
+    the benchmark's set-up searches it: per-layer kernel backend, fused
+    segments and tiling.  A change here changes what the chip runs."""
+    from repro.dse_cli import run_dse_plan
+
+    with open(os.path.join(_BENCH, "configs", f"{config}.json")) as f:
+        arch = json.load(f)["arch"]
+    with open(os.path.join(_BENCH, "traffic", f"{traffic}.json")) as f:
+        tr = json.load(f)
+    if phase == "train":
+        _, plan = run_dse_plan(arch, hw=tr["plan"]["hw"], mode="train",
+                               smoke=False, tokens=tr["batch"] * tr["seq"])
+    else:
+        tokens = (tr["plan"]["prefill_tokens"] if phase == "prefill"
+                  else tr["n_slots"])
+        _, plan = run_dse_plan(arch, hw=tr["plan"]["hw"], phase=phase,
+                               smoke=False, tokens=tokens,
+                               serve_slots=tr["n_slots"],
+                               serve_gen=tr["plan"]["serve_gen"])
+    got = {lp.name: (lp.backend, lp.segments,
+                     tuple(lp.tiling.to_json().values()))
+           for lp in plan.layers}
+    assert got == layers
+    if phase == "train":
+        # the input gradient streams like the forward pass; every weight
+        # gradient (and the head's input gradient) runs on tt_gemm
+        for lp in plan.layers:
+            want = {b.wrt: "tt_gemm" for b in lp.backward}
+            if lp.backend == "streaming_tt":
+                want["dx"] = "streaming_tt"
+            assert {b.wrt: b.backend for b in lp.backward} == want

@@ -522,18 +522,6 @@ def test_run_dse_fused_cost_report():
     assert base["fused_cost"] is None
 
 
-def test_run_dse_fused_cost_rejects_incompatible_modes():
-    from repro.dse_cli import run_dse
-
-    for kw in ({"mode": "train"}, {"objective": "throughput"},
-               {"engine": "scalar"}, {"hw_search": "budget"},
-               {"search": "guided"}, {"rank_search": "budget"},
-               {"mode": "both"}):
-        with pytest.raises(ValueError):
-            run_dse("tt-lm-100m", smoke=True, tokens=64, fused_cost=True,
-                    **kw)
-
-
 # ---------------------------------------------------------------------------
 # compiler: emitted tt_gemm plans carry segments that validate
 # ---------------------------------------------------------------------------

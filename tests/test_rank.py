@@ -201,23 +201,6 @@ def test_run_dse_rank_search_report():
     assert report["total_latency_s"] == rs["chosen"]["total_latency_s"]
 
 
-def test_rank_search_flag_validation():
-    from repro.dse_cli import run_dse
-
-    for kwargs, msg in (
-        (dict(mode="train"), "rank"),
-        (dict(objective="edp"), "rank"),
-        (dict(engine="scalar"), "rank"),
-        (dict(tune="measure"), "rank"),
-    ):
-        with pytest.raises(ValueError, match=msg):
-            run_dse("tt-lm-100m", "fpga_vu9p", top_k=2, tokens=32,
-                    smoke=True, rank_search="budget", **kwargs)
-    with pytest.raises(ValueError, match="accuracy_budget"):
-        run_dse("tt-lm-100m", "fpga_vu9p", top_k=2, tokens=32,
-                smoke=True, accuracy_budget=0.5)
-
-
 def test_cli_rejects_rank_pair_and_budget_without_rank():
     from repro.dse_cli import main
 

@@ -146,13 +146,6 @@ def test_dse_report_carries_shard_context():
     assert run_dse(ARCH, smoke=True, top_k=2, tokens=64)["sharding"] is None
 
 
-def test_rank_search_rejects_shards():
-    from repro.dse_cli import run_dse
-
-    with pytest.raises(ValueError, match="rank"):
-        run_dse(ARCH, smoke=True, tokens=64, shards=4, rank_search="budget")
-
-
 # ---------------------------------------------------------------------------
 # the equivalence property (subprocess: forced 8-device host mesh)
 # ---------------------------------------------------------------------------

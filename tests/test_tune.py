@@ -513,17 +513,6 @@ def test_run_dse_tune_cache_reports_and_replays(tmp_path, monkeypatch):
     assert plan2.dumps() == plan.dumps()
 
 
-def test_run_dse_tune_rejects_unsupported_combos(tmp_path):
-    from repro.dse_cli import run_dse
-
-    # --mode train composes since the tiling lift (ROADMAP gap b); the
-    # ambiguous --mode both combination is what's rejected now
-    with pytest.raises(ValueError, match="ambiguous"):
-        run_dse("tt-lm-100m", smoke=True, mode="both", tune="cache")
-    with pytest.raises(ValueError, match="analytic-only"):
-        run_dse("tt-lm-100m", smoke=True, objective="edp", tune="cache")
-
-
 def test_run_dse_tune_train_mode_measured_tilings(tmp_path, monkeypatch):
     """ROADMAP gap (b) closed: train plans may carry measured tilings.
     The train *search* stays analytic (no calibration), but the emitted
