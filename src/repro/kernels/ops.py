@@ -20,6 +20,7 @@ from repro.core.tensor_network import TensorNetwork
 from . import tt_gemm as _tt_gemm
 from . import streaming_tt as _streaming
 from . import fused_path as _fused
+from . import flash_prefill as _flash
 
 
 def _default_interpret() -> bool:
@@ -127,6 +128,14 @@ def fused_segment(
         work, steps, block_tokens=block_tokens, block_m=block_m,
         block_k=block_k, block_n=block_n,
         out_dtype=out_dtype, interpret=interpret)
+
+
+def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
+                  interpret: bool | None = None) -> jax.Array:
+    """Causal prefill attention through :func:`flash_prefill.flash_prefill`,
+    ``interpret`` from the default backend."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _flash.flash_prefill(q, k, v, interpret=interpret)
 
 
 def clamp_block(block: int, dim: int, align: int = _tt_gemm.SUBLANE) -> int:

@@ -18,6 +18,7 @@ from .attention import (
     KVCache,
     attention_apply,
     attention_init,
+    flash_prefill_applies,
     init_kv_cache,
 )
 from .embedding import EmbeddingSpec, embedding_apply, embedding_init, head_apply
@@ -40,7 +41,7 @@ __all__ = [
     "installed_factorizations", "linear_apply", "linear_flops",
     "linear_init", "plan_context", "planned_layer", "planned_path_index",
     "AttentionSpec", "KVCache", "attention_apply", "attention_init",
-    "init_kv_cache",
+    "flash_prefill_applies", "init_kv_cache",
     "EmbeddingSpec", "embedding_apply", "embedding_init", "head_apply",
     "MLPSpec", "mlp_apply", "mlp_init",
     "MoESpec", "moe_apply", "moe_init",

@@ -3,7 +3,10 @@
 Training/prefill uses a q-chunked attention (lax.scan over query blocks)
 so the materialised score tensor is (B, H, q_block, S) rather than
 (B, H, S, S) — at 32k context the full score tensor would dominate the
-per-device memory budget.  Decode attends one new token against the cache.
+per-device memory budget.  A prefill whose shape
+:func:`flash_prefill_applies` takes runs the blocked causal Pallas
+kernel ``flash_prefill`` instead, whose scores never leave VMEM.  Decode
+attends one new token against the cache.
 
 All projections go through ``repro.nn.linear`` and are therefore
 tensorizable by the DSE.
@@ -18,8 +21,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+from repro.kernels.flash_prefill import BLOCK_K, LANE
 from repro.sharding import get_rules, shard
-from .linear import LinearSpec, TTConfig, linear_apply, linear_init
+from .linear import (LinearSpec, TTConfig, _single_device, linear_apply,
+                     linear_init)
 from .rope import apply_rope, rope_for
 
 _NEG_INF = -1e30
@@ -187,6 +193,20 @@ def _chunked_attention(
     return outs.reshape(b, n_chunks * chunk, h, dh)[:, :sq]
 
 
+def flash_prefill_applies(spec: AttentionSpec, s: int) -> bool:
+    """Whether a prompt of ``s`` tokens prefills through the Pallas
+    ``flash_prefill`` kernel rather than ``_chunked_attention``.
+
+    The kernel takes causal attention whose heads fill whole 128-lane
+    vregs, over at least one of its key blocks, on one device (a Pallas
+    call is not partitioned over a mesh).  A narrower head fills
+    half the lanes or less, and at the lengths it serves its float32
+    score squares are small: it stays on the chunked path.
+    """
+    return (spec.causal and spec.head_dim % LANE == 0 and s >= BLOCK_K
+            and _single_device())
+
+
 def _write_rows(stack: jax.Array, rows: jax.Array, layer, pos: jax.Array) -> jax.Array:
     """``rows`` (B, s, H_kv, Dh) written into layer ``layer`` of ``stack``
     (L, B, S_max, H_kv * Dh), nothing else touched.
@@ -310,10 +330,12 @@ def attention_apply(
             ks = _write_rows(ks, k, layer, idx)
             vs = _write_rows(vs, v, layer, idx)
             new_cache = KVCache(ks, vs) if stacked else KVCache(ks[0], vs[0])
-            if s > 1:
+            if s > 1 and flash_prefill_applies(spec, s):
                 # prefill-with-cache: the prompt's K/V is written at
                 # cache_pos; attend over the local (just-computed) K/V —
                 # identical numerics, no per-token cache round-trips
+                out = ops.flash_prefill(q, k, v)
+            elif s > 1:
                 out = _chunked_attention(q, k, v, spec.causal, spec.q_chunk)
             else:
                 out = _decode_attention(spec, q, ks, vs, layer, idx)

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import ModelConfig, api
-from repro.nn import plan_context
+from repro.models.blocks import attn_spec
+from repro.nn import flash_prefill_applies, plan_context
 from repro.plan import ExecutionPlan, execution_stream
 from repro.plan.compiler import check_plan_for_config
 from repro.runtime.spans import span
@@ -167,6 +168,8 @@ class ServeEngine:
         self.decode_plan = decode_plan
         self._plan_backend = plan_backend
         self._m = api(cfg)  # leaves any globally installed plan untouched
+        # the attention a prefill runs (RWKV has none)
+        self._attn = None if cfg.family == "rwkv" else attn_spec(cfg)
 
         self._prefill_fn = jax.jit(
             lambda p, toks, last: self._m.prefill(p, {"tokens": toks},
@@ -195,7 +198,9 @@ class ServeEngine:
 
         Returns ``(last_logits (V,) np.ndarray, batch-1 caches)`` where
         the logits are taken at the last *real* token of the
-        bucket-padded prompt.
+        bucket-padded prompt.  The ``serve.prefill`` span's ``flash`` is
+        1 where the padded prompt's attention runs the ``flash_prefill``
+        kernel (:func:`repro.nn.flash_prefill_applies`), else 0.
         """
         p = len(prompt)
         pp = self.padded_len(p)
@@ -204,7 +209,9 @@ class ServeEngine:
                 f"padded prompt length {pp} exceeds max_seq {self.max_seq}")
         toks = np.zeros((1, pp), np.int32)
         toks[0, :p] = np.asarray(prompt, np.int32)
-        with span("serve.prefill", tokens=pp, real=p):
+        flash = int(self._attn is not None
+                    and flash_prefill_applies(self._attn, pp))
+        with span("serve.prefill", tokens=pp, real=p, flash=flash):
             with span("serve.prefill.dispatch"), plan_context(
                     self.prefill_plan, force_backend=self._plan_backend):
                 with execution_stream("prefill"):

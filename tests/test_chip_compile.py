@@ -16,12 +16,14 @@ fused path segment; the serving engine's whole bfloat16 decode step at
 For chatglm3-6b at its published widths, whose TT modes hold the primes
 107 (d_ff 13696) and 127 (vocab 65024): the head of its ``tpu_v5e``
 prefill plan at 6144 tokens and decode plan at 48, and the engine's
-whole prefill of a 6144-token prompt and decode step at 48 lanes and
-``max_seq`` 6656 under those plans, which must fit one 16-GB chip
-beside each other.
+whole prefill of a 6144-token prompt, whose attention must run the
+``flash_prefill`` kernel, and decode step at 48 lanes and ``max_seq``
+6656 under those plans, which must fit one 16-GB chip beside each other;
+the ``flash_prefill`` kernel alone at chatglm3-6b's heads (32 over 2 KV
+heads of 128) at 1024 and 6144 tokens.
 Each Mosaic call must carry its kernel's name (``%tt_gemm.N``,
-``%streaming_tt.N``, ``%fused_path.N``): the profiler trace keys device
-ops by these instruction names.
+``%streaming_tt.N``, ``%fused_path.N``, ``%flash_prefill.N``): the
+profiler trace keys device ops by these instruction names.
 """
 
 import dataclasses
@@ -319,7 +321,9 @@ def test_chatglm_serving_fits_one_chip(one_chip, glm_plans, glm_geometry,
     prefill of a 6144-token prompt beside the 48-lane decode cache, and
     the decode step, each within 16 GB; the prefill's head makes one row
     of logits, never (1, 6144, 65024); every planned projection runs on
-    its plan's kernel, the MLP's with the mode 107 of d_ff 13696."""
+    its plan's kernel, the MLP's with the mode 107 of d_ff 13696; the
+    prefill's attention runs ``%flash_prefill.N``, the decode step's
+    does not."""
     from repro.configs import get_config
     from repro.models import api
     from repro.nn import plan_context
@@ -356,8 +360,22 @@ def test_chatglm_serving_fits_one_chip(one_chip, glm_plans, glm_geometry,
     ma = dec.memory_analysis()
     assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes) < limit
     assert f"{GLM_PROMPT},{cfg.vocab}]" not in pre.as_text()
-    for plan, compiled in ((glm_plans["prefill"], pre),
-                           (glm_plans["decode"], dec)):
+    for plan, compiled, attention in (
+            (glm_plans["prefill"], pre, {"flash_prefill"}),
+            (glm_plans["decode"], dec, set())):
         want = {lp.backend for lp in plan.layers
                 if lp.name != "head" and lp.backend != "jnp"}
-        assert want and _kernels(compiled) == want
+        assert want and _kernels(compiled) == want | attention
+
+
+@pytest.mark.parametrize("tokens", [1024, GLM_PROMPT])
+def test_flash_prefill_compiles(one_chip, tokens):
+    """The kernel at chatglm3-6b's heads in bfloat16 fits v5e's scoped
+    VMEM and keeps its name."""
+    from repro.kernels.flash_prefill import flash_prefill
+
+    sd = lambda n: jax.ShapeDtypeStruct((1, tokens, n, 128),  # noqa: E731
+                                        jnp.bfloat16, sharding=one_chip)
+    compiled = _compile(lambda q, k, v: flash_prefill(q, k, v),
+                        sd(32), sd(2), sd(2))
+    assert _kernels(compiled) == {"flash_prefill"}

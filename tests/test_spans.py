@@ -78,3 +78,28 @@ def test_the_ring_keeps_the_newest_and_counts_what_it_dropped():
 def test_bench_names_are_the_benchmarks():
     with pytest.raises(ValueError, match="bench"):
         Recorder().span("bench.decode")
+
+
+@pytest.mark.parametrize("head_dim,prompt,flash", [
+    (128, 1000, 1), (128, 890, 0), (64, 1000, 0)])
+def test_prefill_span_says_whether_flash_prefill_ran(head_dim, prompt, flash):
+    """``serve.prefill`` carries ``flash`` = 1 exactly where the padded
+    prompt's attention runs the ``flash_prefill`` kernel: 128-wide heads
+    over at least one key block (1000 tokens pad to 1024; 890 to 896,
+    under it), never 64-wide heads."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.runtime import spans
+    from repro.serve import ServeEngine
+
+    cfg = get_config("tt-lm-100m", smoke=True).with_(head_dim=head_dim)
+    eng = ServeEngine(cfg, api(cfg).init_params(jax.random.PRNGKey(0)),
+                      n_slots=1, max_seq=1024, prompt_bucket=128)
+    t0 = time.perf_counter()
+    eng.prefill_request(list(range(1, prompt + 1)))
+    pre = [s for s in spans.spans(t0, time.perf_counter())
+           if s.name == "serve.prefill"]
+    assert [s.attrs for s in pre] == [
+        {"tokens": -(-prompt // 128) * 128, "real": prompt, "flash": flash}]
